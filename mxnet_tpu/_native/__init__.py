@@ -6,12 +6,15 @@ TPU rebuild's native equivalents from ``native/*.cc`` on first use and
 exposes them over ctypes (the framework's C-ABI boundary, standing in for
 the reference's ``libmxnet.so`` C API surface).
 
-Build is a single g++ invocation cached by source mtimes — no cmake dance
-for two translation units.
+Build is a single g++ invocation — no cmake dance for two translation
+units.  The output is stale when the hash of the sources differs from the
+one recorded beside it at build time; mtimes say nothing after a copy or
+a checkout.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,35 +26,51 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__
 _SRC_DIR = os.path.join(_ROOT, "native")
 _SOURCES = ("recordio.cc", "image_pipeline.cc")
 _OUT = os.path.join(_SRC_DIR, "build", "libmxtpu_io.so")
+_STAMP = _OUT + ".sha256"
 
 
 class NativeBuildError(RuntimeError):
     pass
 
 
+def _source_hash() -> str:
+    h = hashlib.sha256()
+    for name in _SOURCES + ("recordio.h",):
+        with open(os.path.join(_SRC_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
 def _needs_build() -> bool:
-    if not os.path.exists(_OUT):
+    try:
+        with open(_STAMP) as f:
+            built_from = f.read().strip()
+    except OSError:
         return True
-    out_mtime = os.path.getmtime(_OUT)
-    for s in _SOURCES + ("recordio.h",):
-        if os.path.getmtime(os.path.join(_SRC_DIR, s)) > out_mtime:
-            return True
-    return False
+    return not os.path.exists(_OUT) or built_from != _source_hash()
 
 
 def _build() -> None:
     os.makedirs(os.path.dirname(_OUT), exist_ok=True)
+    src_hash = _source_hash()
+    tmp = "%s.%d.tmp" % (_OUT, os.getpid())
     cmd = [
         "g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-pthread",
         "-Wall", "-Wextra", "-Wno-unused-parameter",
     ] + [os.path.join(_SRC_DIR, s) for s in _SOURCES] + [
-        "-o", _OUT, "-ljpeg",
+        "-o", tmp, "-ljpeg",
     ]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise NativeBuildError(
             "native build failed:\n%s\n%s" % (" ".join(cmd), proc.stderr)
         )
+    # rename into place: a concurrent process never loads a half-written
+    # library, and the stamp is written only after the library it names
+    os.replace(tmp, _OUT)
+    with open(_STAMP + ".tmp", "w") as f:
+        f.write(src_hash + "\n")
+    os.replace(_STAMP + ".tmp", _STAMP)
 
 
 def lib() -> ctypes.CDLL:

@@ -35,11 +35,11 @@ def _mesh():
 
 
 def _shard_map(fn, mesh, n_in):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     return shard_map(fn, mesh=mesh, in_specs=(P(),) * n_in,
-                     out_specs=P(), check_rep=False)
+                     out_specs=P(), check_vma=False)
 
 
 def rank_dependent_traces() -> Dict[str, object]:

@@ -527,10 +527,3 @@ register("MXNET_IO_START_METHOD", "str", None,
          "next_raw contract (workers never touch jax, so forking a "
          "jax-initialized parent is safe), spawn otherwise.")
 
-# compile_cache.py — persistent XLA compilation cache
-register("MXNET_COMPILE_CACHE_DIR", "str", None,
-         "Persistent on-disk XLA compilation cache directory, wired "
-         "into FusedTrainStep/bulk-fit builds, serving AOT compiles "
-         "and bench: restarts skip the multi-hundred-program bind "
-         "cost (recompile_stats() shows the warm-start reduction).  "
-         "Unset disables.")

@@ -83,6 +83,7 @@ import multiprocessing as _mp
 import numpy as _np
 
 from .base import MXNetError
+from .context import host_only_children as _host_only_children
 from .io import DataBatch, DataIter, _instrumented_fetch
 
 __all__ = [
@@ -503,17 +504,20 @@ class ShardedDecodePool(DataIter):
                 for s in range(self._slots):
                     self._free_qs[w].put(s)
             self._procs = []
-            for w in range(self._nw):
-                p = ctx.Process(
-                    target=_decode_worker_main,
-                    args=(w, self._iter_fn, self._inner_parts(),
-                          self._inner_index(w), self._files[w],
-                          self._spec, self._free_qs[w],
-                          self._result_qs[w], self._ctrl_qs[w],
-                          os.getpid()),
-                    daemon=True, name="mxio-decode-%d" % w)
-                p.start()
-                self._procs.append(p)
+            # spawn workers import jax with the package; they are
+            # host-only by contract and must not open the parent's chip
+            with _host_only_children():
+                for w in range(self._nw):
+                    p = ctx.Process(
+                        target=_decode_worker_main,
+                        args=(w, self._iter_fn, self._inner_parts(),
+                              self._inner_index(w), self._files[w],
+                              self._spec, self._free_qs[w],
+                              self._result_qs[w], self._ctrl_qs[w],
+                              os.getpid()),
+                        daemon=True, name="mxio-decode-%d" % w)
+                    p.start()
+                    self._procs.append(p)
             self._epoch = 0
             self._rr = 0
             self._finished = [False] * self._nw

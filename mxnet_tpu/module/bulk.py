@@ -155,7 +155,7 @@ class BulkTrainLoop:
         import jax.numpy as jnp
         from jax import lax
 
-        # persistent XLA compilation cache (MXNET_COMPILE_CACHE_DIR):
+        # persistent XLA compilation cache (compile_cache.py):
         # the bulk scan is the big program a restarted fit re-pays
         from ..compile_cache import enable as _cc_enable
 
@@ -292,7 +292,7 @@ class BulkTrainLoop:
             return new_params, new_aux, new_leaves, outs
 
         if bucketed:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             from ..ops import nn as _nn_ops
@@ -310,7 +310,7 @@ class BulkTrainLoop:
                 _local_step, mesh=mesh,
                 in_specs=(P(), P(), P(), P("dp"), P(), P(), P()),
                 out_specs=(P(), P(), P(), P("dp")),
-                check_rep=False)
+                check_vma=False)
         else:
             step_fn = one_step
 
@@ -393,24 +393,27 @@ class BulkTrainLoop:
                     arrs.append(src._data if isinstance(src, NDArray)
                                 else jnp.asarray(src))
                 stacked.append(jnp.stack(arrs))
+            import jax as _jax
+
+            dev = ex._ctx.jax_device()
             if self._bucketed:
                 # batches arrive committed to one device; the shard_map
                 # scan wants them batch-sharded over dp (leading dim is
                 # the scan's K).  Skip the put when the stack already
                 # landed with that sharding (prefetched dp batches).
-                import jax as _jx
                 from jax.sharding import NamedSharding, PartitionSpec as _P
 
                 ksh = NamedSharding(self._mesh, _P(None, "dp"))
                 stacked = [s if getattr(s, "sharding", None) == ksh
-                           else _jx.device_put(s, ksh) for s in stacked]
+                           else _jax.device_put(s, ksh) for s in stacked]
+            else:
+                # host-built batches join parameters bound on the
+                # executor's context (a no-op when they are there)
+                stacked = [_jax.device_put(s, dev) for s in stacked]
             # COMMIT every carried buffer to the device before the first
             # dispatch: jit keys include placement, so uncommitted
             # first-call inputs vs committed (donated-output) later ones
             # would trace the huge program twice
-            import jax as _jax
-
-            dev = ex._ctx.jax_device()
             target = None
             if self._bucketed:
                 # shard_map needs every carried buffer replicated over

@@ -160,7 +160,7 @@ class Module(BaseModule):
         if self._num_device > 1:
             from ..parallel.dp import DataParallelRunner
 
-            self._dp = DataParallelRunner(self._exec, self._num_device)
+            self._dp = DataParallelRunner(self._exec, self._context_list)
             self._dp.set_input_names(self._data_names, self._label_names)
 
     # ------------------------------------------------------------------
@@ -279,6 +279,8 @@ class Module(BaseModule):
 
     def _feed(self, data_batch):
         """Copy a batch into the bound executor's argument buffers."""
+        import jax
+
         feed = {}
         for name, arr in zip(self._data_names, data_batch.data):
             feed[name] = arr
@@ -288,10 +290,19 @@ class Module(BaseModule):
         for k, v in feed.items():
             if k not in self._exec.arg_dict:
                 raise MXNetError("forward: unknown argument %r" % k)
+            dst = self._exec.arg_dict[k]
             if isinstance(v, NDArray):
-                self._exec.arg_dict[k]._data = v._data.astype(self._exec.arg_dict[k].dtype)
+                raw = v._data.astype(dst.dtype)
+                if self._dp is None:
+                    # the reference copies the batch INTO the bound
+                    # buffers, i.e. onto the executor's context; a batch
+                    # the iterator built on the host must not meet
+                    # parameters bound on a chip inside one program
+                    # (with several contexts _dp.place() shards it)
+                    raw = jax.device_put(raw, self._ctx.jax_device())
+                dst._data = raw
             else:
-                self._exec.arg_dict[k][:] = v
+                dst[:] = v
 
     def forward_backward(self, data_batch):
         """Fused fast path: one XLA program computes outputs + grads

@@ -438,7 +438,8 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
         # the activation where mean-then-E[(x-mean)²] forces a second
         # dependent pass over HBM.  BN is bandwidth- not compute-bound
         # on TPU (resnet50-bf16@32 measured: two-pass 2398 img/s,
-        # one-pass 2499, BN removed 3230 — ROUND5_NOTES); fp32
+        # one-pass 2499, BN removed 3230 — a round-5 builder's run, not
+        # a driver record); fp32
         # accumulation keeps the E[x²]−E[x]² cancellation benign.
         acc_t = jnp.promote_types(data.dtype, jnp.float32)
         xf = data.astype(acc_t)

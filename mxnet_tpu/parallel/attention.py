@@ -14,7 +14,9 @@ Two implementations, one contract:
     ``jax.checkpoint`` per block).
   * ``pallas_flash_attention`` — hand-tiled Pallas TPU kernel for the
     single-chip hot path (MXU-sized q/k tiles in VMEM, f32 accumulators).
-    Falls back to the scan formulation off-TPU.
+    A caller that asks for the kernel gets the kernel or an error: off-TPU
+    it runs only with an explicit ``interpret=True``, and shapes its
+    blocks do not divide raise.
 
 Layout: (batch, seq, heads, head_dim) — "BTHD" — matching the ring/Ulysses
 sharding over the seq axis.
@@ -26,6 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["attention_reference", "flash_attention", "pallas_flash_attention"]
 
@@ -165,32 +169,35 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
-try:  # pallas import is cheap but keep CPU-only envs working
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
-
 def pallas_flash_attention(q, k, v, causal=False, sm_scale=None,
-                           block_q=256, block_k=256, interpret=None):
-    """Tiled Pallas flash attention; falls back to the scan formulation on
-    non-TPU backends (pallas TPU kernels need the mosaic compiler)."""
+                           block_q=256, block_k=256, interpret=False):
+    """Tiled Pallas flash attention, lowered by Mosaic on a TPU backend.
+
+    There is no silent fallback: on any other backend the kernel runs
+    only under ``interpret=True`` (the Pallas interpreter — tests), and
+    a sequence the blocks do not divide, or a causal call with
+    ``Tq != Tk``, raises ``ValueError``.  ``flash_attention`` is the
+    formulation that takes any shape on any backend."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if sm_scale is None:
         sm_scale = D ** -0.5
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not _HAS_PALLAS or (not on_tpu and not interpret):
-        # mosaic kernels need the TPU compiler; off-TPU only the
-        # interpreter can run them
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    platform = jax.devices()[0].platform
+    if platform != "tpu" and not interpret:
+        raise RuntimeError(
+            "pallas_flash_attention needs the Mosaic TPU compiler and "
+            "this backend is %r; pass interpret=True to run the Pallas "
+            "interpreter, or call flash_attention" % platform)
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
-    if Tq % block_q or Tk % block_k or (causal and Tq != Tk):
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    if Tq % block_q or Tk % block_k:
+        raise ValueError(
+            "pallas_flash_attention: blocks (%d, %d) do not divide the "
+            "sequences (Tq=%d, Tk=%d)" % (block_q, block_k, Tq, Tk))
+    if causal and Tq != Tk:
+        raise ValueError(
+            "pallas_flash_attention: causal needs Tq == Tk, got %d and "
+            "%d" % (Tq, Tk))
 
     # fold batch & heads into the grid's first axis; blocks are 2-D (T, D)
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)

@@ -1,7 +1,7 @@
 """Gradient bucketing for backward-overlapped all-reduce (NCCL-DDP style).
 
 Round 5 measured the data-parallel gradient exchange compiling to ONE
-combined synchronous all-reduce (OVERLAP_MEASURED.json: n_async_pairs=0)
+combined synchronous all-reduce (MULTICHIP_r05.json: no async pairs)
 — a reduction that depends on EVERY gradient cannot start until backward
 finishes, so nothing can hide it and projected eff@256 stalls at ~0.85.
 The fix is the same one NCCL DDP and the reference's engine-priority

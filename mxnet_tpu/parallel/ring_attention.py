@@ -89,10 +89,10 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=False,
     the full output, computed ring-parallel over ``mesh[axis_name]``."""
     from jax.sharding import PartitionSpec as P
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     spec = P(None, axis_name, None, None)
     fn = functools.partial(ring_attention, axis_name=axis_name,
                            causal=causal, sm_scale=sm_scale)
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+                     out_specs=spec, check_vma=False)(q, k, v)

@@ -131,8 +131,8 @@ class ModelRuntime:
         from .. import diagnostics as _diag
         from ..compile_cache import enable as _cc_enable
 
-        # MXNET_COMPILE_CACHE_DIR: a restarted server loads its AOT
-        # executors from the persistent cache instead of re-binding
+        # a restarted server loads its AOT executors from the
+        # persistent compilation cache instead of re-binding
         # every (model, bucket) program
         _cc_enable()
 

@@ -246,21 +246,21 @@ class TransformerTrainStep:
             return new_p, new_m, loss, rows
 
         if sharded:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             mom_spec = [P("dp")] * len(plan) if zero1 else P()
             step = shard_map(
                 step_body, mesh=self.mesh,
                 in_specs=(P(), mom_spec, data_spec, data_spec),
                 out_specs=(P(), mom_spec, P()),
-                check_rep=False)
+                check_vma=False)
             if sdc_on:
                 step_sdc = shard_map(
                     step_body_sdc, mesh=self.mesh,
                     in_specs=(P(), mom_spec, data_spec, data_spec,
                               P()),
                     out_specs=(P(), mom_spec, P(), P()),
-                    check_rep=False)
+                    check_vma=False)
         else:
             step = step_body
 

@@ -132,3 +132,19 @@ def test_ring_long_sequence_memory():
     ref = attention_reference(q, k, v, causal=True)
     out = ring_attention_sharded(q, k, v, mesh, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+def test_pallas_raises_instead_of_falling_back():
+    """A caller that asked for the kernel never gets the scan silently:
+    off-TPU without interpret=True is an error, and so are shapes the
+    blocks do not divide."""
+    q, k, v = _qkv(B=1, T=16, H=2, D=8)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        pallas_flash_attention(q, k, v, causal=True, block_q=8, block_k=8)
+    q, k, v = _qkv(B=1, T=20, H=2, D=8)
+    with pytest.raises(ValueError, match="do not divide"):
+        pallas_flash_attention(q, k, v, block_q=8, block_k=8,
+                               interpret=True)
+    with pytest.raises(ValueError, match="causal needs"):
+        pallas_flash_attention(q[:, :8], k[:, :16], v[:, :16], causal=True,
+                               block_q=8, block_k=8, interpret=True)

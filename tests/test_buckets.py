@@ -123,7 +123,7 @@ def _reduce_on_mesh(grads_np, plan, impl="psum", mean=False,
     d contributes ``value * (d+1)`` per key (leading device axis
     sharded over dp)."""
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh((8,), ("dp",))
@@ -138,7 +138,7 @@ def _reduce_on_mesh(grads_np, plan, impl="psum", mean=False,
 
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P("dp"),), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)(args)
 
 

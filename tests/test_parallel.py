@@ -276,3 +276,82 @@ def test_run_steps_stacked_batches():
     assert losses.shape == (3,)
     l = losses.asnumpy()
     assert np.isfinite(l).all()
+
+
+# ---------------------------------------------------------------------
+# PR 21: no fallback hides the device
+# ---------------------------------------------------------------------
+def test_dryrun_raises_with_too_few_devices():
+    """Asking for more devices than the process has is an error — the
+    dryrun no longer re-executes itself on a virtual CPU mesh."""
+    import __graft_entry__ as ge
+
+    with pytest.raises(RuntimeError, match="this process has"):
+        ge.dryrun_multichip(current_device_count() + 1)
+
+
+class _FakeJax:
+    """Stands in for jax in context.py: a host backend next to an
+    accelerator backend, as on a machine with chips."""
+
+    class _Dev:
+        def __init__(self, platform, i):
+            self.platform, self.id = platform, i
+
+        def __repr__(self):
+            return "%s:%d" % (self.platform, self.id)
+
+    def __init__(self, n_chips):
+        self._host = [self._Dev("cpu", 0)]
+        self._chips = [self._Dev("tpu", i) for i in range(n_chips)]
+
+    def devices(self, backend=None):
+        return self._host if backend == "cpu" else self._chips
+
+
+def test_contexts_on_a_machine_with_both_backends(monkeypatch):
+    from mxnet_tpu import context
+
+    fake = _FakeJax(n_chips=2)
+    monkeypatch.setattr(context, "_jax", lambda: fake)
+    # cpu is the host, always; reference cpu(i) are views of one pool
+    assert mx.cpu(0).jax_device().platform == "cpu"
+    assert mx.cpu(3).jax_device() is mx.cpu(0).jax_device()
+    # tpu(i)/gpu(i) is chip i, and a chip that is not there raises
+    assert mx.tpu(1).jax_device() is fake.devices()[1]
+    assert mx.gpu(0).jax_device() is fake.devices()[0]
+    with pytest.raises(ValueError, match="2 device"):
+        mx.tpu(2).jax_device()
+    assert mx.context.num_tpus() == 2
+
+
+def test_data_parallel_runner_collapses_cpu_only(monkeypatch, caplog):
+    from mxnet_tpu import context
+    from mxnet_tpu.parallel.dp import DataParallelRunner
+
+    # reference scripts bind [cpu(0), cpu(1)] unconditionally: on a
+    # host pool of 8 devices, cpu(0) and cpu(8) are the same device
+    with caplog.at_level("WARNING"):
+        runner = DataParallelRunner(None, [mx.cpu(0), mx.cpu(8)])
+    assert runner.mesh.devices.size == 1
+    assert "collapsing" in caplog.text
+    assert DataParallelRunner(None, [mx.cpu(0), mx.cpu(1)]) \
+        .mesh.devices.size == 2
+    # naming one chip twice has no such reading
+    fake = _FakeJax(n_chips=2)
+    monkeypatch.setattr(context, "_jax", lambda: fake)
+    with pytest.raises(ValueError, match="1 distinct tpu"):
+        DataParallelRunner(None, [mx.tpu(0), mx.gpu(0)])
+    with pytest.raises(ValueError, match="2 device"):
+        DataParallelRunner(None, [mx.tpu(0), mx.tpu(5)])
+
+
+def test_waitall_propagates_a_failing_barrier(monkeypatch):
+    import jax
+
+    def boom():
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(jax, "effects_barrier", boom)
+    with pytest.raises(RuntimeError, match="device lost"):
+        nd.waitall()

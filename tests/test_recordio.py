@@ -296,3 +296,21 @@ def test_image_iter_grayscale_raw(tmp_path):
     for i in range(4):
         np.testing.assert_array_equal(data[i], np.full((1, 6, 6),
                                                        10.0 * (i + 1)))
+
+
+def test_native_library_staleness_is_a_source_hash(tmp_path, monkeypatch):
+    """mtimes say nothing after a copy or a checkout: the loader rebuilds
+    when the hash recorded beside the library differs from the
+    sources'."""
+    from mxnet_tpu import _native
+
+    _native.lib()  # built (or found current) for this checkout
+    assert not _native._needs_build()
+    with open(_native._STAMP) as f:
+        assert f.read().strip() == _native._source_hash()
+    stale = tmp_path / "stamp"
+    stale.write_text("0" * 64 + "\n")
+    monkeypatch.setattr(_native, "_STAMP", str(stale))
+    assert _native._needs_build()
+    monkeypatch.setattr(_native, "_STAMP", str(tmp_path / "missing"))
+    assert _native._needs_build()

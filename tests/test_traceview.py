@@ -13,9 +13,7 @@ attribution -> autotune feedback.
 * cross-rank phase-skew health naming the slow rank, with
   chaos-injected stalls labeled instead of misattributed;
 * mxlint MXL009 (direct ``jax.profiler`` use outside traceview/) and
-  ``MXNET_TRACE_*`` env-registry drift;
-* the regenerated OVERLAP_MEASURED.json v2 contract (device_timeline
-  measurement + legacy schedule-walk labeled ``source=simulated``).
+  ``MXNET_TRACE_*`` env-registry drift.
 """
 import json
 import os
@@ -331,33 +329,3 @@ def test_trace_knobs_registered_and_documented():
         assert reg[name].doc and len(reg[name].doc) > 10, name
         assert name in readme, "%s missing from README" % name
         assert name in env.describe()
-
-
-# ---------------------------------------------------------------------
-# OVERLAP_MEASURED.json v2: measurement labeled, simulation labeled
-# ---------------------------------------------------------------------
-def test_overlap_measured_v2_provenance_and_labels():
-    with open(os.path.join(ROOT, "OVERLAP_MEASURED.json")) as f:
-        blob = json.load(f)
-    assert blob["format"] == "mxnet-tpu-overlap-measured"
-    assert blob["version"] >= 2
-    # the legacy r5 schedule-walk numbers survive for byte accounting
-    # but are labeled as simulation, not measurement
-    assert blob["source"] == "simulated"
-    assert "schedule_walk" in blob
-    note = json.dumps(blob["schedule_walk"]).lower()
-    assert "walk" in note and "byte accounting" in note, note
-    # the device_timeline block is a real capture with provenance
-    dt = blob["device_timeline"]
-    assert dt["source"] == "trace"
-    assert dt["plan_match"] is True
-    assert dt["buckets"] and all("occupancy" in b for b in dt["buckets"])
-    assert dt["overlap_frac"] is not None
-    prov = blob["provenance"]
-    assert prov["platform"] and prov["workload"].startswith(
-        "FusedTrainStep")
-    assert "staleness" in blob and "device_timeline" in blob["staleness"]
-    # test_overlap.py's legacy contract stays intact
-    assert blob["overlap_measured"] is not None
-    assert 30e6 < blob["n_sync_allreduce_bytes"] + blob["async_bytes"] \
-        < 60e6
