@@ -1,0 +1,268 @@
+"""Driver: generation serving through ``ModelServer``.
+
+One process: ``GenerationRuntime`` + ``ModelServer.add_generator`` +
+``submit_generation(..., on_token=...)`` — the entry behind HTTP
+``:generate`` — through the continuous-batching engine, the paged
+cache and the compiled prefill and decode programs (the call shapes of
+``chip_smoke.py``'s serving leg, without the HTTP front end).  Load is
+open loop at the cell's fixed rate (``perfbench/loadgen.py``).
+
+After the window closes the driver waits for the first token of every
+request already sent, cancels what is still decoding, and keeps a
+sample of the requests the window FINISHED for the check: the
+reference runs once over each prompt with its served tokens, and the
+number compared is the widest gap by which a served token's logit lies
+below the reference's best at its position.
+"""
+from __future__ import annotations
+
+import importlib
+import random
+import time
+from typing import Dict, List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import flops, loadgen, weights
+
+MODEL = "bench_gen"
+FIRST_TOKEN_WAIT_S = 60.0
+
+
+class Driver:
+    def __init__(self, cell: Dict, config: Dict, seed: int, devices,
+                 spans):
+        self.cell, self.config, self.seed = cell, config, int(seed)
+        self.devices, self.spans = devices, spans
+        self.sample: List = []
+
+    # -- set-up -------------------------------------------------------
+    def setup(self) -> None:
+        from mxnet_tpu import diagnostics, serving
+        from mxnet_tpu.serving import reqtrace
+        from mxnet_tpu.transformer import TransformerConfig
+
+        diagnostics.reset_recompile_stats()
+        reqtrace.reset()
+        cfg, cell = self.config, self.cell
+        self.ref = importlib.import_module(
+            "perfbench.reference." + cfg["reference"])
+        self.specs = self.ref.leaves(cfg)
+        lm = TransformerConfig(
+            vocab_size=cfg["vocab_size"],
+            n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+            n_heads=cfg["num_attention_heads"],
+            d_ff=cfg["intermediate_size"], rope_base=cfg["rope_base"],
+            dtype=cfg["dtype"], param_dtype=cfg["param_dtype"],
+            eps=cfg["rms_norm_eps"])
+        with jax.default_device(self.devices[0]):
+            params = weights.make_all(self.seed, self.specs,
+                                      cfg["param_dtype"])
+            self.rt = serving.GenerationRuntime(
+                MODEL, params, lm, slots=cell["slots"],
+                block_tokens=cell["block_tokens"],
+                max_prompt=cell["prompt"]["max"],
+                max_context=cell["prompt"]["max"] + cell["output"]["max"],
+                max_new=cell["output"]["max"], prefill_batch=1)
+            del params
+        # outside ``default_device``: it is part of jit's cache key, and
+        # the engine's thread, which runs without it, would compile
+        # every plan cell a second time under traffic
+        self.srv = serving.ModelServer(
+            queue_max=cell["queue_max"],
+            default_deadline_ms=cell["deadline_ms"])
+        self.srv.add_generator(self.rt)       # compiles every plan cell
+
+    # -- the measured window -----------------------------------------
+    def window(self, seconds: float) -> Dict:
+        from mxnet_tpu.serving.errors import Rejected, ServeError
+
+        cell, srv = self.cell, self.srv
+        self.plan = plan = loadgen.schedule(cell, self.seed, seconds,
+                                            self.config["vocab_size"])
+        t0 = time.perf_counter()
+
+        def submit(req):
+            def on_token(tok, req=req):        # the engine's thread
+                if tok is not None:
+                    req.token_s.append(time.perf_counter() - t0)
+                    req.tokens.append(tok)
+
+            try:
+                req.handle = srv.submit_generation(
+                    MODEL, req.prompt, max_new=req.max_new,
+                    on_token=on_token, request_id="bench-%d" % req.index)
+            except Rejected as e:
+                req.outcome = "shed:%s" % e.reason
+
+        loadgen.offer(plan, submit, seconds, t0, self.spans)
+        elapsed = time.perf_counter() - t0
+        sent = [r for r in plan if r.handle is not None]
+        with self.spans("bench.drain"):
+            # a request sent just before the close still gets its first
+            # token: late is late, not missing
+            limit = time.perf_counter() + FIRST_TOKEN_WAIT_S
+            for r in sent:
+                while not r.token_s and not r.handle.done() \
+                        and time.perf_counter() < limit:
+                    time.sleep(0.002)
+            for r in sent:
+                if not r.handle.done():
+                    r.handle.cancel()
+            for r in sent:
+                try:
+                    r.handle.wait(30.0)
+                except ServeError:
+                    pass       # read from ``handle.error`` just below
+        for r in sent:
+            err = r.handle.error
+            if err is None or type(err).__name__ == "Cancelled":
+                r.outcome = "ok"
+            else:
+                r.outcome = "error:%s" % type(err).__name__
+        numbers = loadgen.summarize(plan, seconds,
+                                    seconds + FIRST_TOKEN_WAIT_S)
+        finished = [r for r in plan if r.outcome == "ok"
+                    and len(r.tokens) == r.max_new
+                    and r.token_s[-1] <= seconds]
+        self.sample = self._draw_sample(finished)
+        self.counters = self._count(plan, seconds, numbers, len(finished))
+        return {
+            "t_start": t0, "elapsed_s": elapsed,
+            "attempted": numbers["attempted"], "failed": numbers["failed"],
+            "metrics": {k: numbers[k] for k in
+                        ("serve_tokens_per_s", "tpot_p95_ms")},
+            "counters": self.counters,
+            "info": "%d requests due, %d failed, %d tokens and %d gaps in "
+                    "%.1f s; ttft p50 %.1f ms; generator late p90 %.2f ms; "
+                    "%d finished in the window" % (
+                        numbers["attempted"], numbers["failed"],
+                        numbers["tokens_in_window"], numbers["token_gaps"],
+                        seconds, numbers["ttft_p50_ms"],
+                        loadgen.percentile(numbers["late_ms"], 0.9)
+                        if numbers["late_ms"] else float("nan"),
+                        self.counters["finished_in_window"]),
+        }
+
+    def _draw_sample(self, done) -> List:
+        """Of the requests the window finished, a sample drawn from the
+        seed, the longest among them."""
+        if not done:
+            return []
+        longest = max(done, key=lambda r: len(r.prompt) + len(r.tokens))
+        rest = [r for r in done if r is not longest]
+        random.Random(self.seed).shuffle(rest)
+        picked = [longest] + rest[:self.cell["check_requests"] - 1]
+        return [(np.asarray(r.prompt), np.asarray(r.tokens, np.int32))
+                for r in picked]
+
+    def _count(self, plan, seconds, numbers, finished: int) -> Dict:
+        """Work done inside the window, from the tokens served."""
+        from mxnet_tpu.serving import reqtrace
+
+        cfg = self.config
+        prefilled = [len(r.prompt) for r in plan
+                     if r.token_s and r.token_s[0] <= seconds]
+        # a decode tick emits token i (i >= 1) of a request after
+        # reading the prompt and the i tokens before it
+        decode_reads = [len(r.prompt) + i for r in plan
+                        for i, t in enumerate(r.token_s)
+                        if i >= 1 and t <= seconds]
+        queue_ms = [1e3 * rec["phases"]["queue"]
+                    for rec in reqtrace.snapshot()["recent"]
+                    if "queue" in rec.get("phases", {})]
+        return {
+            "finished_in_window": finished,
+            "prefill_requests": len(prefilled),
+            "prefill_tokens": sum(prefilled),
+            "prefill_flops_each": [flops.prefill_flops(cfg, n)
+                                   for n in prefilled],
+            "prefill_flops": sum(flops.prefill_flops(cfg, n)
+                                 for n in prefilled),
+            "decode_tokens": len(decode_reads),
+            "decode_kv_token_reads": sum(decode_reads),
+            "decode_flops": sum(flops.transformer_forward_flops(cfg, 1, n)
+                                for n in decode_reads),
+            "queue_ms": queue_ms,
+            "late_ms": numbers["late_ms"],
+            "ttft_p90_ms": numbers["ttft_p90_ms"],
+        }
+
+    def release(self) -> None:
+        report = self.srv.drain(timeout_s=30)
+        if not report["drained"]:
+            # the engine's thread would keep the weights and the cache
+            raise RuntimeError("the server did not drain: %s" % report)
+        self.srv = self.rt = None
+        import gc
+
+        gc.collect()
+
+    # -- the check ----------------------------------------------------
+    def check(self) -> Dict:
+        value = self.token_gaps()["served"]
+        return {"token_gap": {"value": value,
+                              "limit": self.cell["limits"]["token_gap"]}}
+
+    def calibration(self, control: Dict, faults: bool, quantisers: Dict,
+                    rebuilt):
+        """``(what, numbers, extra)`` for ``perfbench.calibrate``."""
+        gaps = self.token_gaps(quantisers.get(control.get("reference")))
+        served = [t for _, tokens in self.sample for t in tokens]
+        extra = {"requests": len(self.sample), "tokens": len(served),
+                 "distinct_tokens": len(set(served)),
+                 "repeats_of_the_token_before": sum(
+                     1 for _, tokens in self.sample
+                     for a, b in zip(tokens, tokens[1:]) if a == b)}
+        yield "program", {"token_gap": gaps["served"]}, extra
+        if "reference" in control:
+            yield "control", {"token_gap": gaps["control"]}, extra
+
+    def token_gaps(self, quantise=None) -> Dict[str, float]:
+        """``served``: the widest gap, over the sample's served tokens,
+        between the reference's best logit at the token's position and
+        the served token's.  With ``quantise`` also ``control``: the
+        same for the token that the reference computed with that
+        quantiser puts first at each position."""
+        if not self.sample:
+            return {"served": float("nan"), "control": float("nan")}
+        cfg = self.config
+        context = self.cell["prompt"]["max"] + self.cell["output"]["max"]
+        p = weights.make_all(self.seed, self.specs, "float32")
+
+        def gaps(p, ids, first, count, served):
+            logits = self.ref.forward(p, ids, cfg)
+            at = first + jnp.arange(served.shape[0])
+            rows = logits[at]
+            live = jnp.arange(served.shape[0]) < count
+            best = jnp.max(rows, axis=-1)
+
+            def gap(tokens):
+                g = best - jnp.take_along_axis(rows, tokens[:, None],
+                                               1)[:, 0]
+                return jnp.max(jnp.where(live, g, 0.0))
+
+            out = {"served": gap(served)}
+            if quantise is not None:
+                low = self.ref.forward(p, ids, cfg, quantise)[at]
+                out["control"] = gap(jnp.argmax(low, axis=-1)
+                                     .astype(jnp.int32))
+            return out
+
+        run = jax.jit(gaps)
+        worst = {"served": 0.0, "control": 0.0}
+        n_out = self.cell["output"]["max"]
+        with jax.default_matmul_precision("highest"):
+            for prompt, tokens in self.sample:
+                ids = np.zeros((context,), np.int32)
+                ids[:len(prompt)] = prompt
+                ids[len(prompt):len(prompt) + len(tokens)] = tokens
+                served = np.zeros((n_out,), np.int32)
+                served[:len(tokens)] = tokens
+                got = run(p, ids, len(prompt) - 1, len(tokens), served)
+                for k, v in got.items():
+                    worst[k] = max(worst[k], float(v))
+        del p
+        return worst
