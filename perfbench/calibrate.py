@@ -9,8 +9,10 @@ control the cell names against the same reference (``control.program``:
 the program's own lower-precision path, given as configuration keys to
 override; ``control.reference``: the reference with that quantiser on
 its operands); with ``--faults`` a training cell's reference with half
-of each batch left out.  One JSON line a reading.  The benchmark's own runs never run
-this; ``PERF.md`` records what it read on the chip.
+of each batch left out, and with its state left unchanged.  One JSON
+line a reading; the file named by ``--out`` also gets both sides' norms
+leaf by leaf.  The benchmark's own runs never run this; ``PERF.md``
+records what it read on the chip.
 """
 from __future__ import annotations
 
@@ -51,10 +53,11 @@ def main(argv=None, *, manifest_path=None, data_root=None) -> int:
         line = dict({"cell": cell["name"], "seed": seed, "what": what,
                      "platform": devices[0].platform}, **numbers,
                     **(extra or {}))
-        text = json.dumps(line)
-        print(text, flush=True)
+        # both sides leaf by leaf go to the file alone
+        print(json.dumps({k: v for k, v in line.items()
+                          if k != "readings"}), flush=True)
         if out:
-            out.write(text + "\n")
+            out.write(json.dumps(line) + "\n")
             out.flush()
 
     def built(seed, cfg):

@@ -173,18 +173,20 @@ class Driver:
         queue_ms = [1e3 * rec["phases"]["queue"]
                     for rec in reqtrace.snapshot()["recent"]
                     if "queue" in rec.get("phases", {})]
+        prefill_flops = sum(flops.prefill_flops(cfg, n) for n in prefilled)
+        decode_flops = sum(flops.transformer_forward_flops(cfg, 1, n)
+                           for n in decode_reads)
         return {
             "finished_in_window": finished,
             "prefill_requests": len(prefilled),
             "prefill_tokens": sum(prefilled),
             "prefill_flops_each": [flops.prefill_flops(cfg, n)
                                    for n in prefilled],
-            "prefill_flops": sum(flops.prefill_flops(cfg, n)
-                                 for n in prefilled),
+            "prefill_flops": prefill_flops,
             "decode_tokens": len(decode_reads),
             "decode_kv_token_reads": sum(decode_reads),
-            "decode_flops": sum(flops.transformer_forward_flops(cfg, 1, n)
-                                for n in decode_reads),
+            "decode_flops": decode_flops,
+            "flops_done": prefill_flops + decode_flops,
             "queue_ms": queue_ms,
             "late_ms": numbers["late_ms"],
             "ttft_p90_ms": numbers["ttft_p90_ms"],

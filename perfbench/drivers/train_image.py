@@ -76,6 +76,9 @@ class Driver(TrainDriver):
     def leaf_specs(self):
         return self.specs
 
+    def leaf_kinds(self) -> Dict[str, str]:
+        return {n: kind for n, _, kind in self.ref.leaves(self.config)}
+
     def next_batch(self):
         batch = self.pool[self.cursor % len(self.pool)]
         self.cursor += 1

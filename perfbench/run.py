@@ -12,8 +12,9 @@ exits 2 with no result line: there is no CPU fallback.
 Nothing here names a cell, a configuration or a metric.  A cell is
 ``workloads/<cell>.json`` and its configuration's file, which names its
 driver (``drivers/<driver>.py``); a per-layer metric is
-``metrics/<metric>.py`` with one function ``read(ctx)`` that returns a
-number, or None where it finds nothing to read.
+``metrics/<metric>.py`` (or, for ``<quantity>.<split>``, the
+quantity's file) with one function ``read(ctx)`` that returns a number,
+or None where it finds nothing to read.
 """
 from __future__ import annotations
 
@@ -40,7 +41,9 @@ def _load_json(path: str) -> Dict:
 
 
 def _reader(name: str):
-    path = os.path.join(HERE, "metrics", name + ".py")
+    from .validate import reader_path
+
+    path = reader_path(name)
     spec = importlib.util.spec_from_file_location(
         "perfbench_metric_" + name.replace(".", "_").replace("-", "_"),
         path)

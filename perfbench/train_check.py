@@ -11,14 +11,17 @@ against the reference's norm of that leaf or of the median leaf,
 whichever is larger.  Leaves whose reference gradient is under a
 thousandth of the median leaf's (a bias in front of BatchNorm) move by
 round-off alone and are left out of the change.  The same gaps are also
-given for the MEDIAN leaf: where a cell's worst leaf is noise (see
-``PERF.md``), its file holds the median to a limit instead.  A cell
+given for the MEDIAN leaf, and, where the driver names each leaf's kind
+(a convolution's weight, a BatchNorm gamma), for the worst and the
+median leaf of every kind as ``change_gap.<kind>`` and
+``change_gap_median.<kind>``: where the worst leaf of all is noise (see
+``PERF.md``), a cell holds each kind to limits of its own.  A cell
 compares the numbers its ``limits`` name.
 """
 from __future__ import annotations
 
 import statistics
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 STEPS = 3
 DEAD_GRADIENT = 1e-3
@@ -33,26 +36,33 @@ def readings(losses: List[float], first_momenta: Dict, lr: float,
             "change_norms": {k: float(v) for k, v in changes.items()}}
 
 
-def _gaps(prog: Dict, ref: Dict, keep) -> List[float]:
+def _gaps(prog: Dict, ref: Dict, keep) -> Dict[str, float]:
     floor = statistics.median(ref.values())
-    return [abs(prog[k] - r) / max(r, floor) for k, r in ref.items()
-            if keep(k)]
+    return {k: abs(prog[k] - r) / max(r, floor) for k, r in ref.items()
+            if keep(k)}
 
 
-def numbers(prog: Dict, ref: Dict) -> Dict[str, float]:
-    """``{name: value}`` of every number compared."""
+def numbers(prog: Dict, ref: Dict,
+            kinds: Optional[Dict[str, str]] = None) -> Dict[str, float]:
+    """``{name: value}`` of every number that can be compared;
+    ``kinds`` is ``{leaf: kind}`` where the driver tells them apart."""
     g_floor = DEAD_GRADIENT * statistics.median(ref["grad_norms"].values())
     moving = {k for k, g in ref["grad_norms"].items() if g >= g_floor}
-    grad = _gaps(prog["grad_norms"], ref["grad_norms"], lambda k: True)
-    change = _gaps(prog["change_norms"], ref["change_norms"],
-                   moving.__contains__)
-    return {
-        "loss_gap": max(abs(p - r) / abs(r) for p, r in
-                        zip(prog["losses"], ref["losses"])),
-        "grad_gap": max(grad), "change_gap": max(change),
-        "grad_gap_median": statistics.median(grad),
-        "change_gap_median": statistics.median(change),
-    }
+    gaps = {"grad_gap": _gaps(prog["grad_norms"], ref["grad_norms"],
+                              lambda k: True),
+            "change_gap": _gaps(prog["change_norms"], ref["change_norms"],
+                                moving.__contains__)}
+    out = {"loss_gap": max(abs(p - r) / abs(r) for p, r in
+                           zip(prog["losses"], ref["losses"]))}
+    for what, by_leaf in gaps.items():
+        groups = {"": list(by_leaf.values())}
+        for leaf, gap in by_leaf.items():
+            if kinds:
+                groups.setdefault("." + kinds[leaf], []).append(gap)
+        for suffix, mine in groups.items():
+            out[what + suffix] = max(mine)
+            out[what + "_median" + suffix] = statistics.median(mine)
+    return out
 
 
 def judge(values: Dict[str, float], limits: Dict[str, float]) -> Dict:

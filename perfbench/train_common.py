@@ -16,7 +16,8 @@ from . import train_check, weights
 class TrainDriver:
     """Template.  Subclasses implement ``build``, ``next_batch``,
     ``call``, ``momenta``, ``params``, ``leaf_specs``, ``work`` and
-    ``reference_readings``; ``self.lr`` is the learning rate."""
+    ``reference_readings``; ``self.lr`` is the learning rate.
+    ``leaf_kinds`` may tell the kinds of leaf apart."""
 
     def __init__(self, cell: Dict, config: Dict, seed: int, devices,
                  spans):
@@ -24,6 +25,9 @@ class TrainDriver:
         self.devices, self.spans = devices, spans
         self.program_readings: Optional[Dict] = None
         self.first_batches: List = []
+
+    def leaf_kinds(self) -> Optional[Dict[str, str]]:
+        return None
 
     # -- set-up: build, then the first steps through the window's own
     # call and feed; they compile every program the window uses -------
@@ -67,7 +71,8 @@ class TrainDriver:
             "t_start": t0, "elapsed_s": elapsed, "attempted": steps,
             "failed": sum(1 for v in values if not math.isfinite(v)),
             "metrics": {"train_step_ms": 1e3 * elapsed / steps},
-            "counters": dict(work, steps=steps, last_loss=values[-1]),
+            "counters": dict(work, steps=steps, last_loss=values[-1],
+                             flops_done=work["flops_per_step"] * steps),
             "info": "%d steps in %.3f s: %.1f %s/s, last loss %.4f" % (
                 steps, elapsed, steps * work["samples_per_step"] / elapsed,
                 work["sample_unit"], values[-1]),
@@ -81,29 +86,34 @@ class TrainDriver:
     # -- the check ----------------------------------------------------
     def check(self) -> Dict:
         ref = self.reference_readings()
-        values = train_check.numbers(self.program_readings, ref)
+        values = train_check.numbers(self.program_readings, ref,
+                                     self.leaf_kinds())
         return train_check.judge(values, self.cell["limits"])
 
     def calibration(self, control: Dict, faults: bool, quantisers: Dict,
                     rebuilt: Callable):
         """``(what, numbers, extra)`` for ``perfbench.calibrate``."""
         ref = self.reference_readings()
-        yield "program", train_check.numbers(self.program_readings, ref), \
-            {"losses": self.program_readings["losses"],
-             "ref_losses": ref["losses"]}
+        kinds = self.leaf_kinds()
+
+        def against(side: Dict):
+            # ``readings`` holds both sides leaf by leaf, for a look
+            # that the numbers alone do not allow
+            return train_check.numbers(side, ref, kinds), \
+                {"readings": {"side": side, "reference": ref}}
+
+        yield ("program",) + against(self.program_readings)
         if "program" in control:
-            low = rebuilt(control["program"])
-            yield "control", train_check.numbers(low.program_readings,
-                                                 ref), {}
+            yield ("control",) + against(
+                rebuilt(control["program"]).program_readings)
         if "reference" in control:
-            yield "control", train_check.numbers(self.reference_readings(
-                quantise=quantisers[control["reference"]]), ref), {}
+            yield ("control",) + against(self.reference_readings(
+                quantise=quantisers[control["reference"]]))
         if faults:
-            yield "fault_half_batch", train_check.numbers(
-                self.reference_readings(rows=self.cell["batch"] // 2),
-                ref), {}
-            yield "fault_state_unchanged", train_check.numbers(
-                self.reference_readings(frozen=True), ref), {}
+            yield ("fault_half_batch",) + against(
+                self.reference_readings(rows=self.cell["batch"] // 2))
+            yield ("fault_state_unchanged",) + against(
+                self.reference_readings(frozen=True))
 
     def run_reference(self, make_step: Callable, to_batch: Callable,
                       quantise=None, rows: Optional[int] = None,

@@ -31,6 +31,17 @@ WIDTH = re.compile(r"(_dim|_rank|(hidden|intermediate|latent|state|head|ffn"
                    r"|^d_model|^d_ff)$")
 
 
+def reader_path(metric: str) -> str:
+    """The file that reads a per-layer metric: ``metrics/<name>.py``,
+    or, for a quantity split by the end-to-end metric it moves
+    (``mfu.train``, ``mfu.serve``), ``metrics/<name before the last
+    dot>.py`` where the split name has no file of its own."""
+    own = os.path.join(METRICS_DIR, metric + ".py")
+    if os.path.isfile(own) or "." not in metric:
+        return own
+    return os.path.join(METRICS_DIR, metric.rsplit(".", 1)[0] + ".py")
+
+
 def _line(text, what: str, faults: List[str]) -> None:
     if not isinstance(text, str) or not 1 <= len(text) <= 200 \
             or "\n" in text or "\t" in text:
@@ -219,7 +230,7 @@ def check(manifest: Dict, root: str, data_root: str) -> List[str]:
                 faults.append(what + ": cell %s does not report %s"
                               % (cell, moves))
         layered.update(mine)
-        reader = os.path.join(METRICS_DIR, "%s.py" % m.get("name"))
+        reader = reader_path(str(m.get("name")))
         if not os.path.isfile(reader):
             faults.append(what + ": no reader %s" % reader)
     for cell in cells:

@@ -23,9 +23,12 @@ def test_committed_manifest_is_sound(manifest):
 
 
 def test_toy_manifest_is_sound():
-    toy = os.path.join(ROOT, "tests", "perfbench", "toy")
-    with open(os.path.join(toy, "BENCHMARK.json")) as f:
-        assert validate.check(json.load(f), ROOT, toy) == []
+    import toy_manifest
+
+    toy = toy_manifest.build()
+    assert validate.check(toy, ROOT, toy_manifest.TOY) == []
+    # it covers every driver, the serving one too
+    assert len(toy["workloads"]) == 3
 
 
 def test_no_cell_config_or_metric_is_named_in_run_py(manifest):

@@ -9,14 +9,29 @@ import pytest
 
 from perfbench import run
 
-TOY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy")
+import toy_manifest
+
+TOY = toy_manifest.TOY
 KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
-def _run(capsys, cell, trace, seed=3000000019, require_chip=False):
+@pytest.fixture(scope="module")
+def manifest_path(tmp_path_factory):
+    return toy_manifest.write(tmp_path_factory.mktemp("toy"))
+
+
+@pytest.fixture
+def _run(capsys, manifest_path):
+    def call(cell, trace, seed=3000000019, require_chip=False):
+        return _call(capsys, manifest_path, cell, trace, seed,
+                     require_chip)
+    return call
+
+
+def _call(capsys, manifest_path, cell, trace, seed, require_chip):
     rc = run.main(["--workload", cell, "--seed", str(seed), "--seconds",
                    "0.5", "--trace", str(trace)],
-                  manifest_path=os.path.join(TOY, "BENCHMARK.json"),
+                  manifest_path=manifest_path,
                   data_root=TOY, require_chip=require_chip)
     captured = capsys.readouterr()
     return rc, captured.out.strip().splitlines(), captured.err
@@ -36,8 +51,8 @@ HOST_METRICS = {
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_untraced_run_prints_the_end_to_end_metrics(capsys, cell):
-    rc, out, err = _run(capsys, cell, 0)
+def test_untraced_run_prints_the_end_to_end_metrics(_run, cell):
+    rc, out, err = _run(cell, 0)
     assert rc == 0
     line = json.loads(out[-1])
     assert KEYS <= set(line) and list(line)[-1] == "compared"
@@ -52,8 +67,8 @@ def test_untraced_run_prints_the_end_to_end_metrics(capsys, cell):
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_traced_run_writes_no_cpu_number_under_a_device_name(capsys, cell):
-    rc, out, _ = _run(capsys, cell, 1, seed=11)
+def test_traced_run_writes_no_cpu_number_under_a_device_name(_run, cell):
+    rc, out, _ = _run(cell, 1, seed=11)
     assert rc == 0
     line = json.loads(out[-1])
     assert line["correct"] is True
@@ -62,8 +77,8 @@ def test_traced_run_writes_no_cpu_number_under_a_device_name(capsys, cell):
     assert line["breakdown"] == {"device_ops": [], "idle_gaps": []}
 
 
-def test_without_a_chip_there_is_no_result(capsys):
-    rc, out, err = _run(capsys, "toy_image", 0, require_chip=True)
+def test_without_a_chip_there_is_no_result(_run):
+    rc, out, err = _run("toy_image", 0, require_chip=True)
     assert rc == 2 and out == [] and "no CPU fallback" in err
 
 
@@ -113,10 +128,10 @@ def _token_altered(monkeypatch):
 @pytest.mark.parametrize("cell, fault", [
     (c, f) for c in TRAIN_CELLS for f in (_state_unchanged, _half_batch)
 ] + [("toy_serve", _token_altered)])
-def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch, cell,
+def test_a_broken_timed_path_is_not_correct(_run, monkeypatch, cell,
                                             fault):
     fault(monkeypatch)
-    rc, out, err = _run(capsys, cell, 0, seed=5)
+    rc, out, err = _run(cell, 0, seed=5)
     assert rc == 0
     line = json.loads(out[-1])
     assert line["correct"] is False
@@ -124,7 +139,7 @@ def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch, cell,
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_the_control_comes_out_not_correct(capsys, cell):
+def test_the_control_comes_out_not_correct(capsys, manifest_path, cell):
     """The cell's low-precision control, at toy size, through
     ``perfbench.calibrate``: the program's readings lie within the
     cell's limits and the control's pass at least one of them."""
@@ -132,8 +147,7 @@ def test_the_control_comes_out_not_correct(capsys, cell):
 
     rc = calibrate.main(["--workload", cell, "--seeds", "4", "--control",
                          "1"],
-                        manifest_path=os.path.join(TOY, "BENCHMARK.json"),
-                        data_root=TOY)
+                        manifest_path=manifest_path, data_root=TOY)
     assert rc == 0
     lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
              if ln.startswith("{")]
