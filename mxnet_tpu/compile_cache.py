@@ -15,7 +15,9 @@ Where the cache lives is decided OUTSIDE the program:
     this so a tier-1 run leaves nothing in the checkout.
 
 :func:`enable` zeroes the min-entry/min-compile-time thresholds so
-every program is eligible, and every compiled-path build site calls it
+every program is eligible, keys the cache on the programs' metadata
+too (so that a cached executable carries the scope names of the tree
+that asks for it), and every compiled-path build site calls it
 (``FusedTrainStep._build``, ``BulkTrainLoop._build``,
 ``TransformerTrainStep``, ``ModelRuntime.compile``,
 ``GenerationRuntime``).  A cache that cannot be enabled raises: a run
@@ -79,6 +81,14 @@ def enable() -> Optional[str]:
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
+        # jax leaves locations, and the named scopes that live in them,
+        # out of the cache key by default: an executable compiled by a
+        # tree without the scope names (ndarray.invoke, Block.__call__,
+        # transformer/model.py) would then be served to a tree with
+        # them, and every trace of it would read unnamed.  The price: a
+        # source edit that moves a traced line compiles anew.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         _enabled_dir = d
         _log.info("persistent XLA compilation cache: %s", d)
         return d

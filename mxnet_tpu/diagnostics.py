@@ -894,14 +894,19 @@ _recorded_steps: Dict[str, Tuple[Any, tuple, dict]] = {}
 def _arg_specs(args) -> tuple:
     """Args with every array leaf replaced by its ShapeDtypeStruct —
     enough to re-``lower`` the jitted function without holding (or
-    donating) live buffers."""
+    donating) live buffers.  A device array's spec carries its
+    sharding: ``lower`` then finds the lowering and the executable the
+    call itself made (jax caches both by it) and costs no second
+    compile, which is what ``traceview.program_scopes`` stands on."""
     import jax
 
     def spec(x):
         shape = getattr(x, "shape", None)
         dtype = getattr(x, "dtype", None)
         if shape is not None and dtype is not None:
-            return jax.ShapeDtypeStruct(tuple(shape), dtype)
+            return jax.ShapeDtypeStruct(
+                tuple(shape), dtype,
+                sharding=getattr(x, "sharding", None))
         return x
 
     return jax.tree_util.tree_map(spec, args)
@@ -944,12 +949,14 @@ def _avals_of(args) -> tuple:
 
 
 class _InstrumentedJit:
-    """Transparent wrapper around one jitted callable: detects the calls
-    that compiled (``_cache_size`` growth where available, first-seen
-    aval signature otherwise), times them, stamps ``compile`` trace
-    spans, feeds the recompile registry + metrics, and warns once per
-    name on shape/dtype churn.  Every other attribute (``lower``, …)
-    delegates to the wrapped function."""
+    """Transparent wrapper around one jitted callable: every call is a
+    ``mx.step.launch`` span (``profiler.span``: the ring, and any live
+    device trace); it detects the calls that compiled (``_cache_size``
+    growth where available, first-seen aval signature otherwise),
+    gives each an ``mx.compile`` span over the same interval, feeds
+    the recompile registry + metrics, and warns once per name on
+    shape/dtype churn.  Every other attribute (``lower``, …) delegates
+    to the wrapped function."""
 
     def __init__(self, name: str, fn, meta: Optional[dict] = None):
         self._name = name
@@ -979,9 +986,12 @@ class _InstrumentedJit:
             # per batch; hashing them every call is pure overhead)
             avals = _avals_of(args)
             fresh_sig = avals not in self._seen
-        t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
-        dur_ms = (time.perf_counter() - t0) * 1e3
+        from . import profiler as _profiler
+
+        with _profiler.span("mx.step.launch", cat="dispatch") as launch:
+            out = self._fn(*args, **kwargs)
+        t1 = time.perf_counter()
+        dur_ms = (t1 - launch.t0) * 1e3
         after = self._cache_size()
         if after is not None and before is not None:
             compiled = after > before
@@ -992,6 +1002,9 @@ class _InstrumentedJit:
         if compiled:
             if avals is None:
                 avals = _avals_of(args)  # pay the walk on compiles only
+            _profiler.record_interval("mx.compile", launch.t0, t1,
+                                      cat="compile",
+                                      args={"step": self._name})
             self._record_compile(avals, dur_ms)
             try:
                 specs = _arg_specs(args)
@@ -1018,17 +1031,6 @@ class _InstrumentedJit:
             count = st["count"]
             recent = st["avals"]
             warned = _recompile_warned.get(self._name, False)
-        try:
-            from . import profiler as _profiler
-
-            if _profiler.is_running():
-                now = _profiler._now_us()
-                _profiler.record_span("jit_compile::" + self._name,
-                                      now - dur_ms * 1e3, dur_ms * 1e3,
-                                      cat="compile",
-                                      args={"n_compiles": count})
-        except Exception:
-            pass
         try:
             metrics.counter("mxnet_jit_compiles_total",
                             help="XLA compilations of instrumented step "

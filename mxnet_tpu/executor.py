@@ -254,7 +254,8 @@ def build_graph_eval(symbol, collect_internals: bool = False,
             params["_training"] = training
         if op.rng:
             args = [jax.random.fold_in(rng_key, node_index[id(node)])] + args
-        out = op.fn(*args, **params)
+        with jax.named_scope(op.name):  # as ndarray.invoke names it
+            out = op.fn(*args, **params)
         outs = list(out) if isinstance(out, tuple) else [out]
         if op.nondiff:
             # the reference registers NO gradient for these ops

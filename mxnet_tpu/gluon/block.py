@@ -203,14 +203,19 @@ class Block:
         # declare a ``_remat_scope`` (resnet stages / residual units) get
         # their forward wrapped in jax.checkpoint when traced under a
         # CachedOp — eager/settle calls fall through untouched
-        scope = getattr(self, "_remat_scope", None)
-        if scope is not None:
-            from ..remat import checkpoint_block_call
+        import jax
 
-            out = checkpoint_block_call(self, scope, args)
-            if out is not NotImplemented:
-                return out
-        return self.forward(*args)
+        scope = getattr(self, "_remat_scope", None)
+        # the block's name on every operator under it, so that a device
+        # trace reads .../stage1/.../conv0/Convolution
+        with jax.named_scope(self.name):
+            if scope is not None:
+                from ..remat import checkpoint_block_call
+
+                out = checkpoint_block_call(self, scope, args)
+                if out is not NotImplemented:
+                    return out
+            return self.forward(*args)
 
     def forward(self, *args):
         raise NotImplementedError

@@ -571,9 +571,14 @@ def invoke(
             from ..remat import maybe_checkpoint
 
             fn = maybe_checkpoint(fn)
-        outs, vjp_fn = _jax().vjp(fn, *raw)
-    else:
-        outs = fn(*raw)
+    # the operator's name on everything it lowers to (HLO metadata
+    # only): what a device trace is read by, as the reference's
+    # profiler named engine operators (src/engine/profiler.cc)
+    with _jax().named_scope(op.name):
+        if recording:
+            outs, vjp_fn = _jax().vjp(fn, *raw)
+        else:
+            outs = fn(*raw)
 
     if _prof:
         if _prof_sync:  # block for true op duration (NaiveEngine-style)

@@ -429,15 +429,14 @@ def plan_meta(plan: Optional[Sequence[Bucket]],
 
 def stamp_profiler(plan: Sequence[Bucket], *, impl: Optional[str] = None,
                    store_type: str = "tpu") -> None:
-    """Stamp one comms span per bucket + cumulative byte counters
-    through the telemetry layer (profiler.py) at dispatch time, AND one
+    """Record one issued bucket schedule: cumulative byte counters
+    through the telemetry layer (profiler.py, /metrics) and one
     flight-recorder entry per bucket reduction (diagnostics.py), so the
-    bucketed schedule is visible in merged traces and the collective
-    seq stream covers every reduction a rank issued — the in-graph
-    reductions themselves execute inside XLA where host spans cannot
-    reach, so both record the issue schedule (bucket order, payload
-    bytes), not device occupancy.  Spans need a running profiler; the
-    flight entries don't.  Never raises."""
+    collective seq stream covers every reduction a rank issued.  The
+    reductions themselves execute inside XLA, where the ``mxbkt%03d``
+    scopes name them in a device trace: no host span is stamped for
+    them here.  The chrome counters need a running profiler; the flight
+    entries don't.  Never raises."""
     try:
         from .. import diagnostics as _diag
         from .. import profiler as _profiler
@@ -447,32 +446,17 @@ def stamp_profiler(plan: Sequence[Bucket], *, impl: Optional[str] = None,
         # the byte counter is independent of profiler/flight state
         # (same contract as the kvstore verb fast paths): scrapers see
         # bucket_reduce traffic whenever the registry is live
-        _diag.feed_kvstore_bytes("bucket_reduce",
-                                 sum(int(b.nbytes) for b in plan))
-        prof = _profiler.is_running()
-        flight = _diag.flight_enabled()
-        if not prof and not flight:
-            return
-        total = 0
-        for i, b in enumerate(plan):
-            if flight:
+        total = sum(int(b.nbytes) for b in plan)
+        _diag.feed_kvstore_bytes("bucket_reduce", total)
+        if _diag.flight_enabled():
+            for i, b in enumerate(plan):
                 with _diag.record_collective(
                         "bucket_reduce", keys=b.keys, bucket=i,
                         nbytes=int(b.nbytes), dtype=b.dtype,
                         args={"impl": impl, "type": store_type,
                               "in_graph": True}):
                     pass
-            if prof:
-                with _profiler.span("KVStore::AllReduceBucket",
-                                    cat="comms",
-                                    args={"bucket": i,
-                                          "bytes": int(b.nbytes),
-                                          "n_grads": len(b.keys),
-                                          "impl": impl, "type": store_type,
-                                          "in_graph": True}):
-                    pass
-            total += int(b.nbytes)
-        if prof:
+        if _profiler.is_running():
             _profiler.record_bytes("kvstore:bucket_allreduce_bytes", total)
             _profiler.record_bytes("kvstore:bucket_allreduce_count",
                                    len(plan))

@@ -335,8 +335,9 @@ def zero1_bucketed_update(grads, params, mom_shards, plan,
             flat_w = jnp.pad(flat_w, (0, pad))
         shard = flat_w.shape[0] // n
         wsh = lax.dynamic_slice(flat_w, (idx * shard,), (shard,))
-        w_new, m_new = _opt.fused_sgd_mom_flat(
-            wsh, gsh, mom_shards[bi], lr, momentum, wd)
+        with jax.named_scope("optimizer"):
+            w_new, m_new = _opt.fused_sgd_mom_flat(
+                wsh, gsh, mom_shards[bi], lr, momentum, wd)
         new_moms.append(m_new)
         with jax.named_scope("mxbkt%03d" % bi):
             full = lax.all_gather(w_new, axis_name, tiled=True)
@@ -449,6 +450,7 @@ class FusedTrainStep:
         self._bucketed = False
         self._bucket_plan = None
         self._built = False
+        self._step_no = 0  # optimizer steps dispatched: mx.step's number
 
     def _build(self, sample_data):
         """Finish deferred param shapes with one eager forward, then compile
@@ -617,7 +619,8 @@ class FusedTrainStep:
             # traffic) cast to the compute dtype INSIDE the program,
             # where XLA fuses the cast into the first conv
             if data.dtype != compute_dtype:
-                data = data.astype(compute_dtype)
+                with jax.named_scope("cast"):
+                    data = data.astype(compute_dtype)
             # fold the per-step counter inside the fused program: no
             # separate host-side fold_in dispatch per step
             key = jax.random.fold_in(key_root, ctr)
@@ -768,9 +771,10 @@ class FusedTrainStep:
 
                 diff_keys = [i for i in range(n_params)
                              if i not in aux_idx]
-                new_p, new_m = _opt.fused_sgd_mom_grouped(
-                    diff_keys, param_vals, grads, mom_vals,
-                    lr, mom_c, wd)
+                with jax.named_scope("optimizer"):
+                    new_p, new_m = _opt.fused_sgd_mom_grouped(
+                        diff_keys, param_vals, grads, mom_vals,
+                        lr, mom_c, wd)
                 new_params = [next(aux_iter) if i in aux_idx
                               else new_p[i] for i in range(n_params)]
                 new_moms = [mom_vals[i] if i in aux_idx else new_m[i]
@@ -784,9 +788,10 @@ class FusedTrainStep:
                     new_params.append(next(aux_iter))
                     new_moms.append(mom_vals[i])
                 else:
-                    g = grads[i] + wd * param_vals[i]
-                    m = mom_c * mom_vals[i] - lr * g
-                    new_params.append(param_vals[i] + m)
+                    with jax.named_scope("optimizer"):
+                        g = grads[i] + wd * param_vals[i]
+                        m = mom_c * mom_vals[i] - lr * g
+                        new_params.append(param_vals[i] + m)
                     new_moms.append(m)
             return new_params, new_moms, loss_val, logits
 
@@ -1037,9 +1042,9 @@ class FusedTrainStep:
         return self._bucket_tuning
 
     def _stamp_bucket_telemetry(self):
-        """Per-bucket comms spans + byte counters (PR-1 telemetry layer)
-        at dispatch time — the reductions execute inside XLA, so these
-        record the issue schedule."""
+        """Per-bucket flight-recorder entries + byte counters at
+        dispatch time: the issue schedule.  The reductions execute
+        inside XLA, where the ``mxbkt%03d`` scopes name them."""
         if self._bucketed:
             from . import buckets as _buckets
 
@@ -1055,6 +1060,27 @@ class FusedTrainStep:
         self._param_vt = [p.data()._vt for p in self._cells]
         self._placed = True
 
+    def _fresh_params(self):
+        """Last step's outputs as this step's inputs, unless someone
+        mutated a parameter cell in between (version token check — the
+        NDArray cell's write-versioning contract)."""
+        params = self._param_vals
+        for i, p in enumerate(self._cells):
+            cell = p.data()
+            if cell._vt is not self._param_vt[i]:
+                params[i] = cell._data
+        return params
+
+    def _refresh_key(self):
+        """Honor an ``mx.random.seed()`` called since build."""
+        from .. import random as _random
+
+        if self._key_gen != _random._generation:
+            self._key_root = _jax().device_put(_random._next_key(),
+                                               self._rep)
+            self._key_gen = _random._generation
+            self._key_ctr = 0
+
     def run_steps(self, data, label, steps=None):
         """Run K optimizer steps as ONE compiled program (lax.scan).
 
@@ -1064,8 +1090,17 @@ class FusedTrainStep:
         (K,).  Amortizes per-dispatch latency — the reference's bulk
         path (engine.set_bulk_size, MXNET_ENGINE_BULK_SIZE), TPU-style.
         """
+        from .. import profiler as _profiler
+
+        first = self._step_no + 1
+        with _profiler.span("mx.step", cat="dispatch", step=first):
+            losses, k = self._k_steps(data, label, steps)
+        self._step_no += k
+        return losses
+
+    def _k_steps(self, data, label, steps):
         jax = _jax()
-        import jax.numpy as jnp
+        from .. import profiler as _profiler
 
         if not self._built:
             d0 = data if isinstance(data, NDArray) else NDArray(data)
@@ -1074,44 +1109,37 @@ class FusedTrainStep:
             self._build(d0)
         if not self._placed:
             self._place_params()
-        raw_data = data._data if isinstance(data, NDArray) else data
-        raw_label = label._data if isinstance(label, NDArray) else label
-        if self._dtype is not None:
-            raw_data = raw_data.astype(self._dtype)
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        with _profiler.span("mx.step.feed", cat="dispatch"):
+            raw_data = data._data if isinstance(data, NDArray) else data
+            raw_label = label._data if isinstance(label, NDArray) \
+                else label
+            if self._dtype is not None:
+                raw_data = raw_data.astype(self._dtype)
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-        if steps is not None:
-            # same batch every step: close over ONE on-device copy
-            # instead of materializing K in HBM (donated to the program
-            # — _donate_safe_put never aliases the caller's buffer)
-            k = int(steps)
-            raw_data = _donate_safe_put(jax, raw_data, self._data_sh)
-            raw_label = _donate_safe_put(jax, raw_label, self._data_sh)
-            runner = self._multi_step_same.get(k)
-            if runner is None:
-                runner = self._multi_step_same_fn(k)
-                self._multi_step_same[k] = runner
-        else:
-            k = raw_data.shape[0]
-            kdata_sh = NamedSharding(self.mesh, P(None, "dp"))
-            raw_data = _donate_safe_put(jax, raw_data, kdata_sh)
-            raw_label = _donate_safe_put(jax, raw_label, kdata_sh)
-            runner = self._multi_step
-        params = self._param_vals
-        for i, p in enumerate(self._cells):
-            cell = p.data()
-            if cell._vt is not self._param_vt[i]:
-                params[i] = cell._data
-        from .. import random as _random
-
-        if self._key_gen != _random._generation:
-            self._key_root = jax.device_put(_random._next_key(), self._rep)
-            self._key_gen = _random._generation
-            self._key_ctr = 0
-        ctr0 = self._key_ctr + 1
-        self._key_ctr += k
-        from .. import profiler as _profiler
-
+            if steps is not None:
+                # same batch every step: close over ONE on-device copy
+                # instead of materializing K in HBM (donated to the
+                # program — _donate_safe_put never aliases the caller's
+                # buffer)
+                k = int(steps)
+                raw_data = _donate_safe_put(jax, raw_data, self._data_sh)
+                raw_label = _donate_safe_put(jax, raw_label,
+                                             self._data_sh)
+                runner = self._multi_step_same.get(k)
+                if runner is None:
+                    runner = self._multi_step_same_fn(k)
+                    self._multi_step_same[k] = runner
+            else:
+                k = raw_data.shape[0]
+                kdata_sh = NamedSharding(self.mesh, P(None, "dp"))
+                raw_data = _donate_safe_put(jax, raw_data, kdata_sh)
+                raw_label = _donate_safe_put(jax, raw_label, kdata_sh)
+                runner = self._multi_step
+            params = self._fresh_params()
+            self._refresh_key()
+            ctr0 = self._key_ctr + 1
+            self._key_ctr += k
         from .. import traceview as _traceview
 
         if _profiler.is_running():
@@ -1146,7 +1174,7 @@ class FusedTrainStep:
             token = object()
             cell._vt = token
             self._param_vt[i] = token
-        return NDArray.from_raw(losses)
+        return NDArray.from_raw(losses), k
 
     def lower_only(self, data, label):
         """AOT-lower the single-step program WITHOUT executing — shape
@@ -1183,35 +1211,31 @@ class FusedTrainStep:
 
     def __call__(self, data, label):
         """Run one optimizer step; returns (loss, logits) NDArrays."""
+        from .. import profiler as _profiler
+
+        self._step_no += 1
+        with _profiler.span("mx.step", cat="dispatch", step=self._step_no):
+            return self._one_step(data, label)
+
+    def _one_step(self, data, label):
         jax = _jax()
+        from .. import profiler as _profiler
 
         if not self._built:
             self._build(data if isinstance(data, NDArray) else NDArray(data))
         if not self._placed:
             self._place_params()
-        raw_data = data._data if isinstance(data, NDArray) else data
-        raw_label = label._data if isinstance(label, NDArray) else label
-        if self._dtype is not None:
-            raw_data = raw_data.astype(self._dtype)
-        raw_data = jax.device_put(raw_data, self._data_sh)
-        raw_label = jax.device_put(raw_label, self._data_sh)
-        # fast path: reuse last step's outputs as this step's inputs
-        # unless someone mutated a parameter cell in between (version
-        # token check — the NDArray cell's write-versioning contract)
-        params = self._param_vals
-        for i, p in enumerate(self._cells):
-            cell = p.data()
-            if cell._vt is not self._param_vt[i]:
-                params[i] = cell._data
-        from .. import random as _random
-
-        if self._key_gen != _random._generation:
-            # mx.random.seed() was called since build: honor it
-            self._key_root = jax.device_put(_random._next_key(),
-                                            self._rep)
-            self._key_gen = _random._generation
-            self._key_ctr = 0
-        self._key_ctr += 1
+        with _profiler.span("mx.step.feed", cat="dispatch"):
+            raw_data = data._data if isinstance(data, NDArray) else data
+            raw_label = label._data if isinstance(label, NDArray) \
+                else label
+            if self._dtype is not None:
+                raw_data = raw_data.astype(self._dtype)
+            raw_data = jax.device_put(raw_data, self._data_sh)
+            raw_label = jax.device_put(raw_label, self._data_sh)
+            params = self._fresh_params()
+            self._refresh_key()
+            self._key_ctr += 1
         from .. import traceview as _traceview
 
         if self._sdc:

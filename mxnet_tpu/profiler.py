@@ -37,17 +37,21 @@ subprocesses self-start tracing at import and dump at exit.
 from __future__ import annotations
 
 import atexit
+import collections
 import json
 import os
 import sys
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
+
+from .traceview import capture as _capture
 
 __all__ = ["set_config", "profiler_set_config", "set_state",
            "profiler_set_state", "dump", "dump_profile", "dumps",
            "summary", "pause", "resume", "is_running", "record_span",
            "record_counter", "record_marker", "record_bytes", "span",
+           "Span", "record_interval", "spans_between", "RING_SPANS",
            "Domain", "Counter", "Marker", "set_rank", "sample_memory"]
 
 # an RLock: the stamping helpers call each other (record_bytes ->
@@ -76,6 +80,29 @@ _tid_names: Dict[int, str] = {}
 _rank_override: Optional[Tuple[int, int]] = None
 # peak tracker for the live-buffer memory fallback (CPU backend)
 _mem_peak = 0
+
+
+class Span(NamedTuple):
+    """One finished span of the ring: ``t0``/``t1`` are
+    ``time.perf_counter()`` seconds, unshifted; ``thread`` is the small
+    id of :func:`_tid`; ``depth`` counts the spans open on that thread
+    when this one opened, so a span's parent is the enclosing span of
+    depth - 1 on the same thread."""
+    name: str
+    t0: float
+    t1: float
+    thread: int
+    depth: int
+    args: Optional[dict]
+
+
+#: spans the ring keeps; the oldest fall out (a 50 s window of the
+#: fastest loop here, a 10 ms decode tick, is under a tenth of it)
+RING_SPANS = 65536
+# every span, whether or not a profiling session runs: deque.append is
+# atomic, so writers on several threads need no lock
+_ring: "collections.deque[Span]" = collections.deque(maxlen=RING_SPANS)
+_nesting = threading.local()
 
 
 def is_running() -> bool:
@@ -219,6 +246,12 @@ def _dist_info() -> Tuple[int, int]:
 
 def _now_us() -> float:
     return (time.perf_counter_ns() - (_t0 or time.perf_counter_ns())) / 1e3
+
+
+def _session_us(t: float) -> float:
+    """A ``perf_counter()`` reading on the chrome dump's clock
+    (microseconds since the session's ``set_state('run')``)."""
+    return t * 1e6 - (_t0 or 0) / 1e3
 
 
 def _tid() -> int:
@@ -406,22 +439,62 @@ class Marker:
 
 
 class span:
-    """Context manager stamping a span around a python-side region."""
+    """Context manager around a python-side region.  Every span goes to
+    three places: the ring (:func:`spans_between`), on the clock of
+    ``time.perf_counter()``; whatever ``jax.profiler`` session is live
+    (the operator's, ``MXNET_TRACE_DIR``'s or a benchmark's), as a host
+    annotation of the same name through ``traceview.capture``; and the
+    chrome event list while ``mx.profiler`` runs.  ``step`` makes the
+    annotation a step marker carrying that step number."""
+
+    __slots__ = ("name", "cat", "args", "step", "t0", "depth", "_note")
 
     def __init__(self, name: str, cat: str = "operator",
-                 args: Optional[dict] = None):
+                 args: Optional[dict] = None, step: Optional[int] = None):
         self.name = name
         self.cat = cat
         self.args = args
+        self.step = step
 
     def __enter__(self):
-        self.start = _now_us()
+        self.depth = getattr(_nesting, "depth", 0)
+        _nesting.depth = self.depth + 1
+        if self.step is None:
+            self._note = _capture.annotation(self.name,
+                                             **(self.args or {}))
+        else:
+            self._note = _capture.step_annotation(self.name, self.step)
+        self._note.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        record_span(self.name, self.start, _now_us() - self.start,
-                    self.cat, args=self.args)
+        t1 = time.perf_counter()
+        self._note.__exit__(*exc)
+        _nesting.depth = self.depth
+        record_interval(self.name, self.t0, t1, self.cat, self.args,
+                        depth=self.depth)
         return False
+
+
+def record_interval(name: str, t0: float, t1: float,
+                    cat: str = "operator", args: Optional[dict] = None,
+                    depth: Optional[int] = None) -> None:
+    """A span whose two ``perf_counter()`` readings the caller already
+    holds (a compile is known for one only when the call returns): to
+    the ring and the chrome list, but into no device trace."""
+    if depth is None:
+        depth = getattr(_nesting, "depth", 0)
+    _ring.append(Span(name, t0, t1, _tid(), depth, args))
+    if _state == "run":
+        record_span(name, _session_us(t0), (t1 - t0) * 1e6, cat,
+                    args=args)
+
+
+def spans_between(t0: float, t1: float) -> List[Span]:
+    """The ring's spans that overlap ``[t0, t1]`` (``perf_counter()``
+    seconds), oldest first."""
+    return [s for s in list(_ring) if s.t1 >= t0 and s.t0 <= t1]
 
 
 # ---------------------------------------------------------------------------

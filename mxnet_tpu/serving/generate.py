@@ -40,6 +40,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from .. import profiler as _profiler
 from . import reqtrace as _reqtrace
 from .batching import Request
 from .bucket_ladder import bucket_for, ladder
@@ -562,10 +563,12 @@ class GenerationEngine:
                 plens[i] = p
                 tables[i] = self.kv.block_table(seqs[i], tb // bt)
             w = rt._prefill[(bb, tb)]
-            logits, pages = w(rt._params, tokens, plens, self.kv.pages,
-                              tables)
-            self.kv.pages = pages
-            first = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+            with _profiler.span("mx.prefill", cat="serving", args={
+                    "tokens": int(plens[:len(group)].sum())}):
+                logits, pages = w(rt._params, tokens, plens,
+                                  self.kv.pages, tables)
+                self.kv.pages = pages
+                first = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
         except Exception as e:
             err = e if isinstance(e, ExecutorFailure) else \
                 ExecutorFailure("prefill for %r failed: %r"
@@ -651,10 +654,12 @@ class GenerationEngine:
             tables[i] = self.kv.block_table(s.seq_id, lb // bt)
         try:
             w = rt._decode[(bb, lb)]
-            logits, pages = w(rt._params, tokens, positions,
-                              self.kv.pages, tables)
-            self.kv.pages = pages
-            nxt = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+            with _profiler.span("mx.tick", cat="serving", args={
+                    "live": len(riders), "slots": rt.slots}):
+                logits, pages = w(rt._params, tokens, positions,
+                                  self.kv.pages, tables)
+                self.kv.pages = pages
+                nxt = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
         except Exception as e:
             raise self._fail_riders(rep, ExecutorFailure(
                 "decode tick for %r (bucket %dx%d) failed: %r"

@@ -16,7 +16,9 @@ miniature trace fixture through the walker against golden attribution.
 """
 from .capture import (annotation, enabled, last_summary,  # noqa: F401
                       last_summary_path, reset, start_device_trace,
-                      step_window, stop_device_trace)
+                      step_annotation, step_window, stop_device_trace)
+from .scopes import (parse_hlo_scopes, program_scopes,  # noqa: F401
+                     scope_path)
 from .parse import (SUMMARY_FORMAT, SUMMARY_VERSION,  # noqa: F401
                     attribute, classify_op, find_trace_file,
                     is_traceview_summary, load_trace)
@@ -25,5 +27,7 @@ __all__ = [
     "SUMMARY_FORMAT", "SUMMARY_VERSION", "attribute", "classify_op",
     "find_trace_file", "is_traceview_summary", "load_trace",
     "annotation", "enabled", "last_summary", "last_summary_path",
-    "reset", "start_device_trace", "step_window", "stop_device_trace",
+    "reset", "start_device_trace", "step_annotation", "step_window",
+    "stop_device_trace", "parse_hlo_scopes", "program_scopes",
+    "scope_path",
 ]

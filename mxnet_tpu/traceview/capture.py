@@ -4,9 +4,11 @@ rejects direct use anywhere else in ``mxnet_tpu/``).
 Two layers:
 
   * thin wrappers (:func:`start_device_trace` / :func:`stop_device_trace`
-    / :func:`annotation`) — profiler.py's ``profile_xla`` path and the
-    step tracer below both route through these, so the repo has exactly
-    one module touching ``jax.profiler``;
+    / :func:`annotation` / :func:`step_annotation`) — profiler.py's
+    ``profile_xla`` path, every ``profiler.span`` (the ``mx.*`` spans
+    of the train steps and the generation engine) and the step tracer
+    below all route through these, so the repo has exactly one module
+    touching ``jax.profiler``;
   * the env-armed step tracer — ``MXNET_TRACE_DIR`` +
     ``MXNET_TRACE_STEPS`` record N steady-state dispatch windows of
     whatever workload dispatches first (FusedTrainStep /
@@ -33,11 +35,13 @@ from typing import Any, Optional
 _log = logging.getLogger("mxnet_tpu.traceview")
 
 __all__ = ["start_device_trace", "stop_device_trace", "annotation",
-           "step_window", "enabled", "last_summary", "last_summary_path",
+           "step_annotation", "step_window", "enabled", "last_summary", "last_summary_path",
            "reset"]
 
 #: armed dispatches skipped before the trace starts (compile absorber)
 WARMUP_DISPATCHES = 1
+
+_UNREAD = object()
 
 
 def start_device_trace(trace_dir: str) -> None:
@@ -54,12 +58,22 @@ def stop_device_trace() -> None:
     jax.profiler.stop_trace()
 
 
-def annotation(name: str):
+def annotation(name: str, **attrs):
     """Sanctioned ``jax.profiler.TraceAnnotation`` constructor — the
-    host-side marker the parser's step windows come from."""
+    host-side marker the parser's step windows come from, and what
+    puts every ``profiler.span`` into a live device trace (``attrs``
+    ride along as the event's arguments)."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+def step_annotation(name: str, step: int):
+    """Sanctioned ``jax.profiler.StepTraceAnnotation`` constructor —
+    the step marker xprof's step view groups device work by."""
+    import jax
+
+    return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
 
 
 class StepTracer:
@@ -75,15 +89,18 @@ class StepTracer:
         self._workload: Optional[str] = None
         self._summary: Optional[dict] = None
         self._summary_path: Optional[str] = None
+        self._cfg: Any = _UNREAD
 
-    # -- config (lazy: tests flip env between dispatches) --------------
+    # -- config: read once per tracer, on the first dispatch; whoever
+    # changes the environment afterwards re-arms with ``reset()`` ------
     def _config(self):
-        from .. import env as _env
+        if self._cfg is _UNREAD:
+            from .. import env as _env
 
-        d = _env.get_str("MXNET_TRACE_DIR")
-        if not d:
-            return None
-        return d, max(int(_env.get_int("MXNET_TRACE_STEPS") or 1), 1)
+            d = _env.get_str("MXNET_TRACE_DIR")
+            self._cfg = None if not d else (
+                d, max(int(_env.get_int("MXNET_TRACE_STEPS") or 1), 1))
+        return self._cfg
 
     def enabled(self) -> bool:
         if self._done:
@@ -93,9 +110,9 @@ class StepTracer:
     @contextlib.contextmanager
     def step_window(self, workload: str, k: int = 1):
         """Bracket ONE dispatch.  Yields None when the tracer is off
-        (the common path: one env lookup), else a window handle whose
-        ``.block(arrays)`` the caller invokes on the dispatch outputs
-        so device work lands inside the trace."""
+        (the common path: one attribute read), else a window handle
+        whose ``.block(arrays)`` the caller invokes on the dispatch
+        outputs so device work lands inside the trace."""
         cfg = None if self._done else self._config()
         if cfg is None:
             yield None

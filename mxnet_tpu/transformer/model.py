@@ -223,6 +223,7 @@ def apply(params: Dict, tokens, cfg: TransformerConfig, *,
     ``pos_offset`` is this shard's global position of token 0 (a traced
     scalar under shard_map: ``axis_index("sp") * T_local``); ``remat``
     overrides ``MXNET_REMAT_POLICY``."""
+    import jax
     import jax.numpy as jnp
 
     policy = remat_policy(remat)
@@ -230,36 +231,85 @@ def apply(params: Dict, tokens, cfg: TransformerConfig, *,
     B, t = tokens.shape
     positions = pos_offset + jnp.arange(t)
     embed = params["embed"]
-    h = embed.astype(compute)[tokens]
+    with jax.named_scope("embed"):
+        h = embed.astype(compute)[tokens]
 
     def attn_part(h, g, wqkv, wo):
-        a = _rmsnorm(h, g, cfg.eps)
-        qkv = a @ wqkv.astype(a.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
         shape = (B, t, cfg.n_heads, cfg.head_dim)
-        q = _rope(q.reshape(shape), positions, cfg.rope_base)
-        k = _rope(k.reshape(shape), positions, cfg.rope_base)
-        o = attn_fn(q, k, v.reshape(shape))
-        return o.reshape(B, t, cfg.d_model) @ wo.astype(o.dtype)
+        q, k, v = _qkv(h, g, wqkv, shape, positions, cfg)
+        with jax.named_scope("attn"):
+            o = attn_fn(q, k, v)
+        return _attn_out(o, wo, (B, t, cfg.d_model))
 
     def block(h, g_attn, wqkv, wo, g_mlp, w1, w2):
         h = h + checkpoint_scope(attn_part, policy, "attention")(
             h, g_attn, wqkv, wo)
-        m = _rmsnorm(h, g_mlp, cfg.eps)
-        m = jnp.dot(_gelu(m @ w1.astype(m.dtype)), w2.astype(m.dtype))
-        return h + m
+        return h + _mlp(h, g_mlp, w1, w2, cfg)
 
     block = checkpoint_scope(block, policy, "block")
     for i in range(cfg.n_layers):
         p = "blk%d." % i
-        h = block(h, params[p + "attn_norm"], params[p + "wqkv"],
-                  params[p + "wo"], params[p + "mlp_norm"],
-                  params[p + "w1"], params[p + "w2"])
-    h = _rmsnorm(h, params["final_norm"], cfg.eps)
-    # tied head; logits accumulate in f32 (f64 under the control
-    # methodology) regardless of the bf16 compute dtype
-    acc = jnp.promote_types(compute, jnp.float32)
-    return jnp.einsum("btd,vd->btv", h.astype(acc), embed.astype(acc))
+        with jax.named_scope("layer%02d" % i):
+            h = block(h, params[p + "attn_norm"], params[p + "wqkv"],
+                      params[p + "wo"], params[p + "mlp_norm"],
+                      params[p + "w1"], params[p + "w2"])
+    return _logits(_final_norm(h, params, cfg), params, cfg,
+                   "btd,vd->btv")
+
+
+# The scope vocabulary of the three forwards (HLO metadata only; what a
+# device trace's operations are classed by): ``embed``, ``norm``,
+# ``attn_proj`` (the qkv and output matmuls, rotary), ``attn`` (the
+# attention core alone), ``mlp``, ``head_loss`` (logits here, the loss
+# in the train step), inside one ``layer%02d`` a layer.
+def _qkv(h, g, wqkv, shape, positions, cfg):
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("norm"):
+        a = _rmsnorm(h, g, cfg.eps)
+    with jax.named_scope("attn_proj"):
+        qkv = a @ wqkv.astype(a.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = _rope(q.reshape(shape), positions, cfg.rope_base)
+        k = _rope(k.reshape(shape), positions, cfg.rope_base)
+        return q, k, v.reshape(shape)
+
+
+def _attn_out(o, wo, shape):
+    import jax
+
+    with jax.named_scope("attn_proj"):
+        return o.reshape(shape) @ wo.astype(o.dtype)
+
+
+def _mlp(h, g, w1, w2, cfg):
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("norm"):
+        m = _rmsnorm(h, g, cfg.eps)
+    with jax.named_scope("mlp"):
+        return jnp.dot(_gelu(m @ w1.astype(m.dtype)), w2.astype(m.dtype))
+
+
+def _final_norm(h, params, cfg):
+    import jax
+
+    with jax.named_scope("norm"):
+        return _rmsnorm(h, params["final_norm"], cfg.eps)
+
+
+def _logits(h, params, cfg, einsum):
+    """The tied head; logits accumulate in f32 (f64 under the control
+    methodology) regardless of the bf16 compute dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    acc = jnp.promote_types(jnp.dtype(cfg.dtype), jnp.float32)
+    with jax.named_scope("head_loss"):
+        return jnp.einsum(einsum, h.astype(acc),
+                          params["embed"].astype(acc))
 
 
 def lm_loss(logits, labels):
@@ -270,10 +320,11 @@ def lm_loss(logits, labels):
     import jax
     import jax.numpy as jnp
 
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(
-        logits, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
+    with jax.named_scope("head_loss"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(
+            logits, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        return jnp.mean(logz - gold)
 
 
 # ---------------------------------------------------------------------------
@@ -360,6 +411,7 @@ def apply_prefill(params, tokens, prompt_lens, cfg: TransformerConfig,
     real row), with each layer's roped K and raw V scattered into the
     paged cache so decode starts from a populated history.
     ``block_tables`` is (B, T // block_tokens)."""
+    import jax
     import jax.numpy as jnp
 
     compute = jnp.dtype(cfg.dtype)
@@ -369,35 +421,27 @@ def apply_prefill(params, tokens, prompt_lens, cfg: TransformerConfig,
     valid = pos2 < prompt_lens[:, None]
     causal = jnp.tril(jnp.ones((t, t), dtype=bool))
     mask = jnp.broadcast_to(causal[None], (b, t, t))
-    embed = params["embed"]
-    h = embed.astype(compute)[tokens]
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(compute)[tokens]
     new_pages = dict(pages)
+    shape = (b, t, cfg.n_heads, cfg.head_dim)
     for i in range(cfg.n_layers):
         p = "blk%d." % i
-        a = _rmsnorm(h, params[p + "attn_norm"], cfg.eps)
-        qkv = a @ params[p + "wqkv"].astype(a.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = (b, t, cfg.n_heads, cfg.head_dim)
-        q = _rope(q.reshape(shape), positions, cfg.rope_base)
-        k = _rope(k.reshape(shape), positions, cfg.rope_base)
-        v = v.reshape(shape)
-        for nm, val in (("k%d" % i, k), ("v%d" % i, v)):
-            new_pages[nm] = _scatter_tokens(
-                new_pages[nm], val, block_tables, pos2, block_tokens,
-                valid=valid)
-        o = _masked_attn(q, k, v, mask)
-        h = h + o.reshape(b, t, cfg.d_model) @ \
-            params[p + "wo"].astype(o.dtype)
-        m = _rmsnorm(h, params[p + "mlp_norm"], cfg.eps)
-        m = jnp.dot(_gelu(m @ params[p + "w1"].astype(m.dtype)),
-                    params[p + "w2"].astype(m.dtype))
-        h = h + m
-    h = _rmsnorm(h, params["final_norm"], cfg.eps)
+        with jax.named_scope("layer%02d" % i):
+            q, k, v = _qkv(h, params[p + "attn_norm"], params[p + "wqkv"],
+                           shape, positions, cfg)
+            for nm, val in (("k%d" % i, k), ("v%d" % i, v)):
+                new_pages[nm] = _scatter_tokens(
+                    new_pages[nm], val, block_tables, pos2, block_tokens,
+                    valid=valid)
+            with jax.named_scope("attn"):
+                o = _masked_attn(q, k, v, mask)
+            h = h + _attn_out(o, params[p + "wo"], (b, t, cfg.d_model))
+            h = h + _mlp(h, params[p + "mlp_norm"], params[p + "w1"],
+                         params[p + "w2"], cfg)
+    h = _final_norm(h, params, cfg)
     last = h[jnp.arange(b), jnp.clip(prompt_lens - 1, 0, t - 1)]
-    acc = jnp.promote_types(compute, jnp.float32)
-    logits = jnp.einsum("bd,vd->bv", last.astype(acc),
-                        embed.astype(acc))
-    return logits, new_pages
+    return _logits(last, params, cfg, "bd,vd->bv"), new_pages
 
 
 def apply_decode(params, tokens, positions, cfg: TransformerConfig, *,
@@ -410,6 +454,7 @@ def apply_decode(params, tokens, positions, cfg: TransformerConfig, *,
     attend under the inclusive length mask.  Inactive slots ride along
     with all-zero tables (every write lands in the garbage block) and
     their logits are sliced off by the engine."""
+    import jax
     import jax.numpy as jnp
 
     compute = jnp.dtype(cfg.dtype)
@@ -418,31 +463,23 @@ def apply_decode(params, tokens, positions, cfg: TransformerConfig, *,
     pos2 = positions[:, None]
     mask = (jnp.arange(span)[None, :] <= positions[:, None])[:, None, :]
     mask = jnp.broadcast_to(mask, (b, 1, span))
-    embed = params["embed"]
-    h = embed.astype(compute)[tokens][:, None, :]
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(compute)[tokens][:, None, :]
     new_pages = dict(pages)
+    shape = (b, 1, cfg.n_heads, cfg.head_dim)
     for i in range(cfg.n_layers):
         p = "blk%d." % i
-        a = _rmsnorm(h, params[p + "attn_norm"], cfg.eps)
-        qkv = a @ params[p + "wqkv"].astype(a.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = (b, 1, cfg.n_heads, cfg.head_dim)
-        q = _rope(q.reshape(shape), pos2, cfg.rope_base)
-        k = _rope(k.reshape(shape), pos2, cfg.rope_base)
-        v = v.reshape(shape)
-        for nm, val in (("k%d" % i, k), ("v%d" % i, v)):
-            new_pages[nm] = _scatter_tokens(
-                new_pages[nm], val, block_tables, pos2, block_tokens)
-        kc, vc = gather_kv(new_pages, block_tables, i)
-        o = _masked_attn(q, kc, vc, mask)
-        h = h + o.reshape(b, 1, cfg.d_model) @ \
-            params[p + "wo"].astype(o.dtype)
-        m = _rmsnorm(h, params[p + "mlp_norm"], cfg.eps)
-        m = jnp.dot(_gelu(m @ params[p + "w1"].astype(m.dtype)),
-                    params[p + "w2"].astype(m.dtype))
-        h = h + m
-    h = _rmsnorm(h, params["final_norm"], cfg.eps)
-    acc = jnp.promote_types(compute, jnp.float32)
-    logits = jnp.einsum("bd,vd->bv", h[:, 0].astype(acc),
-                        embed.astype(acc))
-    return logits, new_pages
+        with jax.named_scope("layer%02d" % i):
+            q, k, v = _qkv(h, params[p + "attn_norm"], params[p + "wqkv"],
+                           shape, pos2, cfg)
+            for nm, val in (("k%d" % i, k), ("v%d" % i, v)):
+                new_pages[nm] = _scatter_tokens(
+                    new_pages[nm], val, block_tables, pos2, block_tokens)
+            kc, vc = gather_kv(new_pages, block_tables, i)
+            with jax.named_scope("attn"):
+                o = _masked_attn(q, kc, vc, mask)
+            h = h + _attn_out(o, params[p + "wo"], (b, 1, cfg.d_model))
+            h = h + _mlp(h, params[p + "mlp_norm"], params[p + "w1"],
+                         params[p + "w2"], cfg)
+    h = _final_norm(h, params, cfg)
+    return _logits(h[:, 0], params, cfg, "bd,vd->bv"), new_pages

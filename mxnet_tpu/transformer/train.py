@@ -84,6 +84,7 @@ class TransformerTrainStep:
         self._bucket_bytes = bucket_bytes
         self._seed = int(seed)
         self._built = False
+        self._step_no = 0  # optimizer steps dispatched: mx.step's number
 
     # -- mesh geometry --------------------------------------------------
     @property
@@ -198,6 +199,7 @@ class TransformerTrainStep:
             if sharded:
                 loss = lax.pmean(loss, reduce_axes)
             if zero1:
+                # the shard update inside carries the optimizer scope
                 new_p, new_m = zero1_bucketed_update(
                     grads, params_d, moms, plan, "dp", n_dp,
                     lr=lr, momentum=mom_c, wd=wd, mean_n=n_total,
@@ -214,8 +216,9 @@ class TransformerTrainStep:
                     impl="psum" if sp_axis else None)
             # ONE multi-tensor op per dtype group (optimizer.py; the
             # same helper FusedTrainStep's replicated path runs)
-            new_p, new_m = _opt.fused_sgd_mom_grouped(
-                names, params_d, grads, moms, lr, mom_c, wd)
+            with jax.named_scope("optimizer"):
+                new_p, new_m = _opt.fused_sgd_mom_grouped(
+                    names, params_d, grads, moms, lr, mom_c, wd)
             return new_p, new_m, loss
 
         sdc_on, sdc_n = self._sdc, self._sdc_n
@@ -429,27 +432,29 @@ class TransformerTrainStep:
     def step(self, tokens, labels):
         """One optimizer step; returns the (scalar) loss as a jax
         array — not blocked on, so steps pipeline."""
-        if not self._built:
-            self._build()
-        tokens, labels = self._put_batch(tokens, labels)
+        from .. import profiler as _profiler
         from .. import traceview as _traceview
 
-        if self._sdc:
-            self._sdc_ctr += 1
+        self._step_no += 1
+        with _profiler.span("mx.step", cat="dispatch",
+                            step=self._step_no):
+            if not self._built:
+                self._build()
+            with _profiler.span("mx.step.feed", cat="dispatch"):
+                tokens, labels = self._put_batch(tokens, labels)
+            args = (self._params, self._moms, tokens, labels)
+            if self._sdc:
+                self._sdc_ctr += 1
+                args += (self._sdc_ctr,)
             with _traceview.step_window("TransformerTrainStep") as _tvw:
-                (self._params, self._moms, loss,
-                 self._last_sdc_rows) = self._step(
-                    self._params, self._moms, tokens, labels,
-                    self._sdc_ctr)
+                out = self._step(*args)
                 if _tvw is not None:
-                    _tvw.block(loss)
-        else:
-            with _traceview.step_window("TransformerTrainStep") as _tvw:
-                self._params, self._moms, loss = self._step(
-                    self._params, self._moms, tokens, labels)
-                if _tvw is not None:
-                    _tvw.block(loss)
-        self._stamp_telemetry()
+                    _tvw.block(out[2])
+            if self._sdc:
+                self._params, self._moms, loss, self._last_sdc_rows = out
+            else:
+                self._params, self._moms, loss = out
+            self._stamp_telemetry()
         return loss
 
     def sdc_rows(self, step: Optional[int] = None):
@@ -465,24 +470,29 @@ class TransformerTrainStep:
     def run_steps(self, tokens, labels, steps: int):
         """K same-batch steps as ONE compiled program; returns the
         per-step losses (K,)."""
-        if not self._built:
-            self._build()
-        tokens, labels = self._put_batch(tokens, labels)
-        k = int(steps)
-        runner = self._multi_same.get(k)
-        if runner is None:
-            runner = self._multi_same_fn(k)
-            self._multi_same[k] = runner
+        from .. import profiler as _profiler
         from .. import traceview as _traceview
 
-        with _traceview.step_window("TransformerTrainStep",
-                                    k=k) as _tvw:
-            self._params, self._moms, losses = runner(
-                self._params, self._moms, tokens, labels)
-            if _tvw is not None:
-                _tvw.block(losses)
-        for _ in range(k):
-            self._stamp_telemetry()
+        k = int(steps)
+        with _profiler.span("mx.step", cat="dispatch",
+                            step=self._step_no + 1):
+            if not self._built:
+                self._build()
+            with _profiler.span("mx.step.feed", cat="dispatch"):
+                tokens, labels = self._put_batch(tokens, labels)
+            runner = self._multi_same.get(k)
+            if runner is None:
+                runner = self._multi_same_fn(k)
+                self._multi_same[k] = runner
+            with _traceview.step_window("TransformerTrainStep",
+                                        k=k) as _tvw:
+                self._params, self._moms, losses = runner(
+                    self._params, self._moms, tokens, labels)
+                if _tvw is not None:
+                    _tvw.block(losses)
+            for _ in range(k):
+                self._stamp_telemetry()
+        self._step_no += k
         return losses
 
     # -- checkpoint state ----------------------------------------------

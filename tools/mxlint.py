@@ -61,8 +61,9 @@ the fact (recompile storms, config typos, hot-loop host syncs):
                                InputPipeline/ShardedDecodePool.
   MXL009 rogue-device-trace    direct ``jax.profiler.start_trace`` /
                                ``stop_trace`` / ``trace`` /
-                               ``TraceAnnotation`` outside
-                               mxnet_tpu/traceview/: the traceview
+                               ``TraceAnnotation`` /
+                               ``StepTraceAnnotation`` outside
+                               mxnet_tpu/traceview/capture.py: the
                                capture wrapper is the ONE sanctioned
                                XLA device-trace site — a second trace
                                session corrupts (or silently drops)
@@ -124,8 +125,8 @@ CODES = {
               "sites (the 83-87/137 taxonomy is load-bearing for the "
               "supervisor — exit through the named constants)",
     "MXL009": "direct jax.profiler trace call outside "
-              "mxnet_tpu/traceview/ (the one sanctioned device-trace "
-              "capture site)",
+              "mxnet_tpu/traceview/capture.py (the one sanctioned "
+              "device-trace capture site)",
     "MXL010": "wall-clock read in the serving tier (deadlines/"
               "durations are monotonic-clock by contract; "
               "time.monotonic() required — inline-disable only for "
@@ -144,7 +145,8 @@ SANCTIONED_EXIT_RE = re.compile(
     r"mxnet_tpu[/\\](diagnostics\.py$|elastic[/\\]|serving[/\\])")
 
 # the ONE sanctioned jax.profiler device-trace site (MXL009)
-SANCTIONED_TRACE_RE = re.compile(r"mxnet_tpu[/\\]traceview[/\\]")
+SANCTIONED_TRACE_RE = re.compile(
+    r"mxnet_tpu[/\\]traceview[/\\]capture\.py$")
 # jax.profiler attributes that open/annotate an XLA device trace
 TRACE_PROFILER_ATTRS = {"start_trace", "stop_trace", "trace",
                         "TraceAnnotation", "StepTraceAnnotation"}
@@ -454,11 +456,12 @@ class ModuleLinter:
     def _check_trace_call(self, node: ast.Call, fn_stack: List[str]
                           ) -> None:
         """MXL009: ``jax.profiler.start_trace/stop_trace/trace/
-        TraceAnnotation`` outside mxnet_tpu/traceview/.  The capture
-        wrapper there is the one sanctioned device-trace site — route
-        through ``traceview.capture`` (or ``traceview.step_window``)
-        so a second profiler session can never corrupt an armed
-        capture."""
+        TraceAnnotation/StepTraceAnnotation`` outside
+        mxnet_tpu/traceview/capture.py.  The wrappers there are the
+        one sanctioned device-trace site — route through
+        ``traceview.capture`` (``profiler.span`` does, for every
+        ``mx.*`` span) so a second profiler session can never corrupt
+        an armed capture."""
         if self.sanctioned_trace:
             return
         chain = _dotted(node.func)
@@ -468,7 +471,7 @@ class ModuleLinter:
             return
         self._add(node, "MXL009",
                   "%s: direct jax.profiler trace call outside "
-                  "mxnet_tpu/traceview/ — route through "
+                  "mxnet_tpu/traceview/capture.py — route through "
                   "traceview.capture (the one sanctioned device-trace "
                   "site)" % ".".join(chain),
                   ".".join(fn_stack) or "<module>")
