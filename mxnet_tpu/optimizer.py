@@ -544,14 +544,18 @@ class Test(Optimizer):
 
 
 # ---------------------------------------------------------------------------
-# Fused multi-tensor update (ROADMAP item 5): ONE elementwise update
-# over a flat concatenation of every parameter instead of a per-key op
-# per parameter.  These are jax-level building blocks consumed inside
-# compiled train steps (parallel/dp.py FusedTrainStep, the transformer
-# tier) — the per-key ``invoke`` path above stays for the Updater /
-# kvstore server-side-update heritage.  The math is elementwise and
-# dtype-preserving, so fused == per-key BITWISE (pinned in tests); the
-# ZeRO-1 sharded update runs the SAME op over each rank's shard.
+# SGD-with-momentum as jax-level building blocks for the compiled train
+# steps (parallel/dp.py FusedTrainStep, the transformer tier) — the
+# per-key ``invoke`` path above stays for the Updater / kvstore
+# server-side-update heritage.  ``fused_sgd_mom_flat`` is the ONE
+# elementwise formula, shape-agnostic and dtype-preserving: the one-chip
+# and replicated steps apply it leaf by leaf, each parameter in its own
+# shape (``fused_sgd_mom_grouped``: inside one XLA program there are no
+# launches to save, and on the TPU's tiled layouts a flat copy of a
+# weight is a relayout, not a view); ZeRO-1 applies it to each rank's
+# shard of a bucket's flat (``pack_flat`` / ``unpack_flat``, which the
+# collectives need).  Per leaf == over the packed flats BITWISE (pinned
+# in tests).
 # ---------------------------------------------------------------------------
 def pack_flat(arrays):
     """Concatenate arrays (homogeneous dtype) into one flat buffer."""
@@ -602,8 +606,8 @@ def unpack_flat_np(flat, shapes):
 
 
 def fused_sgd_mom_flat(flat_w, flat_g, flat_m, lr, momentum, wd):
-    """SGD-with-momentum over flat buffers: the one-op multi-tensor
-    update.  Identical elementwise math to the per-key path
+    """SGD-with-momentum over arrays of one shape, flat or not: the one
+    elementwise formula every compiled step runs
     (``g += wd*w; m = momentum*m - lr*g; w += m``); returns
     ``(new_w, new_m)``."""
     g = flat_g + wd * flat_w
@@ -612,26 +616,16 @@ def fused_sgd_mom_flat(flat_w, flat_g, flat_m, lr, momentum, wd):
 
 
 def fused_sgd_mom_grouped(keys, params, grads, moms, lr, momentum, wd):
-    """ONE fused update per dtype group over ``keys`` (ordered;
-    buckets never mix dtypes and neither may a concat): ``params`` /
-    ``grads`` / ``moms`` are indexables keyed by ``keys`` (dicts keyed
-    by name, or lists keyed by position — both train-step tiers use
-    this one helper, so their numerics can never diverge).  Returns
+    """:func:`fused_sgd_mom_flat` on every leaf of ``keys`` where it
+    lies, in its own shape and dtype: ``params`` / ``grads`` / ``moms``
+    are indexables keyed by ``keys`` (dicts keyed by name, or lists
+    keyed by position — both train-step tiers use this one helper, so
+    their numerics can never diverge).  Returns
     ``({key: new_param}, {key: new_mom})``."""
-    groups = {}
-    for k in keys:
-        groups.setdefault(str(params[k].dtype), []).append(k)
     new_p, new_m = {}, {}
-    for ks in groups.values():
-        refs = [params[k] for k in ks]
-        w, m = fused_sgd_mom_flat(
-            pack_flat(refs),
-            pack_flat([grads[k] for k in ks]),
-            pack_flat([moms[k] for k in ks]),
-            lr, momentum, wd)
-        for k, wv, mv in zip(ks, unpack_flat(w, refs),
-                             unpack_flat(m, refs)):
-            new_p[k], new_m[k] = wv, mv
+    for k in keys:
+        new_p[k], new_m[k] = fused_sgd_mom_flat(
+            params[k], grads[k], moms[k], lr, momentum, wd)
     return new_p, new_m
 
 

@@ -122,9 +122,9 @@ def replicate(arr, mesh):
 # rank ownership of a 1/dp shard of every gradient bucket's momenta:
 # the bucket's gradient arrives by REDUCE-SCATTER (each rank receives
 # only its shard of the sum — half the wire bytes of an all-reduce),
-# the momentum + parameter update runs on the shard (the fused
-# multi-tensor op from optimizer.py), and the updated parameter shard
-# is ALL-GATHERED back to the replicated layout.  Composes with the
+# the momentum + parameter update runs on the shard (optimizer.py
+# fused_sgd_mom_flat over the bucket's flat), and the updated parameter
+# shard is ALL-GATHERED back to the replicated layout.  Composes with the
 # bucketed reverse-layer-order schedule (parallel/buckets.py): bucket
 # k's all-gather has no data dependency on bucket k+1's scatter or
 # update, so XLA overlaps the gather with the next bucket's work.
@@ -408,6 +408,13 @@ class FusedTrainStep:
     GraphExecutor fast path (InitCachedOps + bulk segments + kvstore push),
     collapsed into a single jit.  Used by bench.py and dryrun_multichip.
 
+    The SGD-momentum update has ONE path on one chip and on the
+    replicated multi-chip path: ``optimizer.fused_sgd_mom_grouped``
+    under the ``optimizer`` scope, each parameter updated where it lies
+    in its own shape.  Parameters and momenta are donated, so each leaf
+    is updated in place.  Only ZeRO-1 packs flats, a bucket at a time
+    (``zero1_bucketed_update``: ``psum_scatter`` needs them).
+
     Parameters
     ----------
     block : initialized gluon HybridBlock
@@ -421,8 +428,8 @@ class FusedTrainStep:
 
     def __init__(self, block, loss_fn, mesh=None, learning_rate=0.05,
                  momentum=0.9, weight_decay=0.0, param_spec_fn=None,
-                 dtype=None, bucket_bytes=None, fused_update=True,
-                 zero_stage=None, accum_steps=None):
+                 dtype=None, bucket_bytes=None, zero_stage=None,
+                 accum_steps=None):
         jax = _jax()
         self.mesh = mesh if mesh is not None else make_mesh((1,), ("dp",),
                                                             jax.devices()[:1])
@@ -437,10 +444,6 @@ class FusedTrainStep:
         # None = MXNET_KVSTORE_BUCKET_BYTES (default 4 MiB), 0 = force
         # the monolithic SPMD reduction
         self._bucket_bytes = bucket_bytes
-        # one multi-tensor optimizer op over all params (optimizer.py
-        # fused_sgd_mom_flat) — False restores the per-key update loop
-        # (the numerics-pinning control; math is bitwise-identical)
-        self._fused_update = bool(fused_update)
         # ZeRO stage: None = MXNET_ZERO_STAGE; 1 shards momenta over dp
         self._zero_stage = zero_stage
         # microbatch gradient accumulation inside the compiled step:
@@ -593,6 +596,7 @@ class FusedTrainStep:
         # flight-recorder header: which reduction schedule this process
         # is issuing (diagnostics.py; --health cross-checks it per rank)
         from .. import diagnostics as _diag
+        from .. import optimizer as _opt
 
         plan_meta_v = _buckets.plan_meta(plan, cap,
                                          tuning=self._bucket_tuning) \
@@ -603,7 +607,6 @@ class FusedTrainStep:
             if self._bucketed and _buckets.impl_name() == "hierarchical" \
             else None
         zero1 = self._zero1
-        fused = self._fused_update
         if self._bucketed:
             _diag.set_bucket_plan(plan_meta_v, owner=id(self))
         else:
@@ -761,38 +764,19 @@ class FusedTrainStep:
                     n=n_dp, mean=True, local_n=hier_local_n,
                     flats=flats)
 
+            # the update runs leaf by leaf on the arrays the step was
+            # given (optimizer.py: no flat copy of parameters, gradients
+            # or momenta), so each donated leaf can alias its output
+            diff_keys = [i for i in range(n_params) if i not in aux_idx]
+            with jax.named_scope("optimizer"):
+                new_p, new_m = _opt.fused_sgd_mom_grouped(
+                    diff_keys, param_vals, grads, mom_vals,
+                    lr, mom_c, wd)
             aux_iter = iter(new_aux)
-            if fused:
-                # ONE multi-tensor update per dtype group over every
-                # trainable param (optimizer.py; elementwise-identical
-                # to the per-key loop, pinned bitwise in tests) instead
-                # of n_params separate update ops
-                from .. import optimizer as _opt
-
-                diff_keys = [i for i in range(n_params)
-                             if i not in aux_idx]
-                with jax.named_scope("optimizer"):
-                    new_p, new_m = _opt.fused_sgd_mom_grouped(
-                        diff_keys, param_vals, grads, mom_vals,
-                        lr, mom_c, wd)
-                new_params = [next(aux_iter) if i in aux_idx
-                              else new_p[i] for i in range(n_params)]
-                new_moms = [mom_vals[i] if i in aux_idx else new_m[i]
-                            for i in range(n_params)]
-                return new_params, new_moms, loss_val, logits
-
-            new_params = []
-            new_moms = []
-            for i in range(n_params):
-                if i in aux_idx:
-                    new_params.append(next(aux_iter))
-                    new_moms.append(mom_vals[i])
-                else:
-                    with jax.named_scope("optimizer"):
-                        g = grads[i] + wd * param_vals[i]
-                        m = mom_c * mom_vals[i] - lr * g
-                        new_params.append(param_vals[i] + m)
-                    new_moms.append(m)
+            new_params = [next(aux_iter) if i in aux_idx else new_p[i]
+                          for i in range(n_params)]
+            new_moms = [mom_vals[i] if i in aux_idx else new_m[i]
+                        for i in range(n_params)]
             return new_params, new_moms, loss_val, logits
 
         if self._bucketed:

@@ -10,8 +10,9 @@ the SAME bucket machinery as the conv workloads
 to the attention-dominated comm pattern too), and the optimizer update
 is either:
 
-  * replicated (ZeRO stage 0): bucketed all-reduce + ONE fused
-    multi-tensor update over all params (optimizer.py), or
+  * replicated (ZeRO stage 0, and one chip): bucketed all-reduce,
+    then the update leaf by leaf, each parameter where it lies
+    (optimizer.py ``fused_sgd_mom_grouped``), or
   * ZeRO-1 (``MXNET_ZERO_STAGE=1``): per-bucket reduce-scatter →
     fused update on this rank's momentum shard → param all-gather
     (parallel/dp.py ``zero1_bucketed_update``), so each dp rank holds
@@ -214,8 +215,8 @@ class TransformerTrainStep:
                     grads, plan, reduce_axes if sp_axis else "dp",
                     n=n_total, mean=True,
                     impl="psum" if sp_axis else None)
-            # ONE multi-tensor op per dtype group (optimizer.py; the
-            # same helper FusedTrainStep's replicated path runs)
+            # leaf by leaf, each parameter where it lies (optimizer.py;
+            # the same helper FusedTrainStep's replicated path runs)
             with jax.named_scope("optimizer"):
                 new_p, new_m = _opt.fused_sgd_mom_grouped(
                     names, params_d, grads, moms, lr, mom_c, wd)

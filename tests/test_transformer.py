@@ -216,37 +216,104 @@ def test_zero1_bf16_and_memory():
 
 
 def test_fused_multi_tensor_matches_per_key_bitwise():
-    """The fused one-op-over-all-params optimizer (optimizer.py
-    fused_sgd_mom_flat through FusedTrainStep) is BITWISE identical to
-    the per-key update loop — the ROADMAP item-5 numerics pin."""
-    _need_devices(2)
-    from mxnet_tpu import gluon, nd
+    """The update that runs leaf by leaf, each leaf in its own shape
+    and dtype (optimizer.fused_sgd_mom_grouped, what both train steps
+    call), is BITWISE the one formula over the packed flats of each
+    dtype (fused_sgd_mom_flat, what ZeRO-1 runs on its shards) — the
+    ROADMAP item-5 numerics pin."""
+    from mxnet_tpu import optimizer as opt
+
+    rng = np.random.RandomState(0)
+    shapes = {"conv": ((64, 64, 3, 3), "float32"),
+              "matrix": ((48, 96), "float32"),
+              "vector": ((96,), "float32"),
+              "half": ((32, 16), "bfloat16"),
+              "half_vec": ((7,), "bfloat16")}
+
+    def tree():
+        return {k: jnp.asarray(rng.randn(*shape), dtype)
+                for k, (shape, dtype) in shapes.items()}
+
+    params, grads, moms = tree(), tree(), tree()
+    keys = list(shapes)
+    hyper = dict(lr=0.05, momentum=0.9, wd=1e-4)
+    new_p, new_m = jax.jit(
+        lambda p, g, m: opt.fused_sgd_mom_grouped(keys, p, g, m, **hyper)
+    )(params, grads, moms)
+    assert set(new_p) == set(new_m) == set(keys)
+    for dtype in ("float32", "bfloat16"):
+        ks = [k for k in keys if shapes[k][1] == dtype]
+        flat_w, flat_m = jax.jit(
+            lambda p, g, m: opt.fused_sgd_mom_flat(
+                opt.pack_flat([p[k] for k in ks]),
+                opt.pack_flat([g[k] for k in ks]),
+                opt.pack_flat([m[k] for k in ks]), **hyper)
+        )(params, grads, moms)
+        refs = [params[k] for k in ks]
+        for k, w, m in zip(ks, opt.unpack_flat(flat_w, refs),
+                           opt.unpack_flat(flat_m, refs)):
+            assert new_p[k].dtype == w.dtype == jnp.dtype(dtype)
+            assert new_p[k].shape == shapes[k][0]
+            np.testing.assert_array_equal(np.asarray(new_p[k]),
+                                          np.asarray(w))
+            np.testing.assert_array_equal(np.asarray(new_m[k]),
+                                          np.asarray(m))
+
+
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_fused_train_step_matches_numpy_per_key_loop(n_dev):
+    """Three steps of a small FusedTrainStep (one chip, and the
+    replicated path behind the bucketed exchange) against a per-key
+    loop written out in numpy over the eager path's gradients."""
+    _need_devices(n_dev)
+    from mxnet_tpu import autograd, gluon, nd
     from mxnet_tpu.parallel.dp import FusedTrainStep
 
-    def run(fused):
+    lr, momentum, wd = 0.05, 0.9, 1e-4
+
+    def make():
         np.random.seed(0)
         mx.random.seed(0)
         net = gluon.nn.HybridSequential()
         net.add(gluon.nn.Dense(32, activation="relu"),
                 gluon.nn.Dense(16))
         net.initialize(mx.init.Xavier())
-        mesh = make_mesh((2,), ("dp",), jax.devices()[:2])
-        step = FusedTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                              mesh=mesh, learning_rate=0.05,
-                              momentum=0.9, weight_decay=1e-4,
-                              fused_update=fused)
-        X = nd.random.uniform(shape=(8, 12))
-        y = nd.array((np.arange(8) % 16).astype("float32"))
-        losses = [float(step(X, y)[0].asnumpy()) for _ in range(3)]
-        params = [p.data().asnumpy()
-                  for _, p in sorted(net.collect_params().items())]
-        return losses, params
+        return net
 
-    l_pk, p_pk = run(False)
-    l_f, p_f = run(True)
-    assert l_pk == l_f
-    for a, b in zip(p_pk, p_f):
-        np.testing.assert_array_equal(a, b)
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    X = nd.array(np.random.RandomState(1).rand(8, 12).astype("float32"))
+    y = nd.array((np.arange(8) % 16).astype("float32"))
+
+    net = make()
+    step = FusedTrainStep(net, loss_fn, learning_rate=lr,
+                          momentum=momentum, weight_decay=wd,
+                          mesh=make_mesh((n_dev,), ("dp",),
+                                         jax.devices()[:n_dev]))
+    losses = [float(step(X, y)[0].asnumpy()) for _ in range(3)]
+
+    twin = make()
+    twin(X)                                   # settle deferred shapes
+    cells = list(twin.collect_params().values())
+    moms = [np.zeros(p.shape, "float32") for p in cells]
+    theirs = []
+    for _ in range(3):
+        with autograd.record():
+            loss = loss_fn(twin(X), y).mean()
+        loss.backward()
+        theirs.append(float(loss.asnumpy()))
+        for i, p in enumerate(cells):
+            w = p.data().asnumpy()
+            g = p.grad().asnumpy() + np.float32(wd) * w
+            moms[i] = np.float32(momentum) * moms[i] - np.float32(lr) * g
+            p.set_data(nd.array(w + moms[i]))
+    np.testing.assert_allclose(losses, theirs, rtol=1e-6)
+    for p, q in zip(net.collect_params().values(), cells):
+        np.testing.assert_allclose(p.data().asnumpy(), q.data().asnumpy(),
+                                   rtol=1e-5, atol=1e-7)
+    # and the momenta the step carries are the loop's
+    for m, mine in zip(step._moms, moms):
+        np.testing.assert_allclose(np.asarray(m), mine, rtol=1e-5,
+                                   atol=1e-7)
 
 
 def test_fused_train_step_zero1_matches_replicated():
