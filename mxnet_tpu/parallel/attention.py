@@ -82,10 +82,12 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_size=512):
 
     Memory is O(T·D + block) instead of O(T²); the scan compiles to one
     fused XLA while-loop.  Equivalent to attention_reference to fp32
-    round-off (tested).
+    round-off (tested).  ``v`` may be of another width than ``q`` and
+    ``k`` (latent attention: 192-wide keys over 128-wide values); the
+    accumulator and the output take ``v``'s.
     """
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     if sm_scale is None:
         sm_scale = D ** -0.5
     blk = min(block_size, Tk)
@@ -95,7 +97,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_size=512):
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     k_blocks = k.reshape(B, n_blocks, blk, H, D).transpose(1, 0, 2, 3, 4)
-    v_blocks = v.reshape(B, n_blocks, blk, H, D).transpose(1, 0, 2, 3, 4)
+    v_blocks = v.reshape(B, n_blocks, blk, H, Dv).transpose(1, 0, 2, 3, 4)
 
     q_pos = jnp.arange(Tq) + (Tk - Tq)  # align causal diagonal when Tq<Tk
 
@@ -104,7 +106,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_size=512):
     zero_bhq = (q.sum(axis=3) * 0.0).transpose(0, 2, 1).astype(jnp.float32)
     m0 = zero_bhq + _NEG_INF
     l0 = zero_bhq
-    o0 = (q * 0.0).astype(jnp.float32)
+    o0 = (q * 0.0).astype(jnp.float32) if Dv == D else jnp.broadcast_to(
+        zero_bhq.transpose(0, 2, 1)[..., None], (B, Tq, H, Dv))
 
     def step(carry, blk_in):
         m, l, o = carry

@@ -8,14 +8,16 @@ chaos / flight-recorder stack applies unchanged.  See README
 "Transformer workload" and ROADMAP item 4.
 """
 from .data import LMTokenIter, make_corpus
-from .model import (ATTENTION_IMPLS, TransformerConfig, apply,
+from .model import (ATTENTION_IMPLS, RopeYarn, TransformerConfig, apply,
                     apply_decode, apply_prefill, attention_impl,
-                    dense_causal_attn, gather_kv, init_params, lm_loss,
-                    make_attn_fn, param_shapes)
+                    dense_causal_attn, frozen_names, gather_kv,
+                    init_params, lm_loss, loss_and_aux, make_attn_fn,
+                    param_shapes)
 from .train import TransformerTrainStep
 
 __all__ = [
-    "ATTENTION_IMPLS", "TransformerConfig", "TransformerTrainStep",
+    "ATTENTION_IMPLS", "RopeYarn", "TransformerConfig",
+    "TransformerTrainStep", "frozen_names", "loss_and_aux",
     "LMTokenIter", "make_corpus", "apply", "apply_decode",
     "apply_prefill", "attention_impl", "dense_causal_attn",
     "gather_kv", "init_params", "lm_loss", "make_attn_fn",

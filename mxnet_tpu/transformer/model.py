@@ -25,6 +25,36 @@ sharded update consume), not a gluon Block or a Module symbol: the
 forcing-function verdict on which layer carries imperative workloads is
 recorded in SURVEY.md §round-14.
 
+**What a block can spell** (``TransformerConfig``; the defaults are the
+dense block above, and a configuration that leaves them alone traces
+the same program as before they existed).  A layer is a MIXER and a
+FEED-FORWARD, chosen as data:
+
+  * mixer ``attn_kind``: ``mha`` (one fused ``wqkv``, full rotary) or
+    ``latent`` (queries through a ``q_lora_rank`` bottleneck, keys and
+    values out of one ``kv_lora_rank`` latent, ``qk_nope_head_dim`` +
+    ``qk_rope_head_dim`` wide queries and keys over ``v_head_dim`` wide
+    values, one rotary key shared by the heads, two inner RMSNorms,
+    optional YaRN frequencies and softmax scale: ``rope_yarn``);
+  * feed-forward per layer (``layer_kinds``): ``dense_ffn`` with
+    ``ffn_act`` ``gelu_tanh`` or ``swiglu``, or ``experts`` (blocks.py:
+    a float32 sigmoid router over all ``n_experts``, ``experts_per_token``
+    a token by score + bias, the ``held_experts`` computed here without
+    dropping an assignment, ``n_shared_experts`` shared);
+  * ``hc_mult`` residual streams a token, mixed around every sublayer
+    by three learned maps, the stream-to-stream one Sinkhorn-projected
+    (``hc_mult`` 1 is the plain residual);
+  * ``tied_head`` False gives the head a matrix of its own;
+  * ``mtp_layers`` multi-token modules (training only) that share the
+    embedding and the head and add ``mtp_loss_weight`` times their loss;
+    ``labels`` then carries ``mtp_layers`` more columns.
+
+Only :func:`apply` / :func:`loss_and_aux` spell these;
+:func:`apply_prefill` and :func:`apply_decode` keep the dense block and
+raise ``NotImplementedError`` for a configuration with any other kind
+(a latent paged cache is not written), as do the ring and ulysses
+attention impls for ``latent``.
+
 Rematerialization is per-block and policy-selectable
 (``MXNET_REMAT_POLICY`` = ``none`` | ``block`` | ``attention``,
 remat.py): ``block`` keeps only block-boundary residuals (the classic
@@ -40,17 +70,33 @@ from .. import env as _env
 from ..remat import checkpoint_scope, remat_policy
 
 __all__ = [
-    "TransformerConfig", "ATTENTION_IMPLS", "attention_impl",
+    "TransformerConfig", "RopeYarn", "ATTENTION_IMPLS", "attention_impl",
     "make_attn_fn", "param_shapes", "init_params", "apply", "lm_loss",
+    "loss_and_aux", "frozen_names",
     "dense_causal_attn", "gather_kv", "apply_prefill", "apply_decode",
 ]
 
 ATTENTION_IMPLS = ("flash", "ring", "ulysses")
 
 
+class RopeYarn(NamedTuple):
+    """YaRN rotary scaling, as a published ``rope_scaling`` gives it."""
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+LAYER_KINDS = ("dense_ffn", "experts")
+
+
 class TransformerConfig(NamedTuple):
     """Decoder-only LM dimensions + dtypes.  ``d_ff`` ``None`` means
-    the conventional ``4*d_model``."""
+    the conventional ``4*d_model``.  The fields after ``eps`` choose
+    what a block is made of (module docstring); their defaults are the
+    dense block."""
     vocab_size: int = 256
     n_layers: int = 2
     d_model: int = 64
@@ -60,10 +106,56 @@ class TransformerConfig(NamedTuple):
     dtype: str = "float32"        # compute (activation) dtype
     param_dtype: str = "float32"  # parameter storage dtype
     eps: float = 1e-6
+    attn_kind: str = "mha"               # | "latent"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[RopeYarn] = None
+    ffn_act: str = "gelu_tanh"           # | "swiglu"
+    tied_head: bool = True
+    layer_kinds: Optional[Tuple[str, ...]] = None   # None: all dense_ffn
+    n_experts: int = 0                   # the router's width
+    experts_per_token: int = 0
+    n_shared_experts: int = 0
+    expert_ff: int = 0                   # one expert's width
+    held_experts: Tuple[int, ...] = ()   # global ids computed here
+    routed_scaling: float = 1.0
+    router_bias_rate: float = 0.001
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The feed-forward kind of every layer."""
+        kinds = self.layer_kinds if self.layer_kinds is not None \
+            else ("dense_ffn",) * self.n_layers
+        if len(kinds) != self.n_layers or \
+                any(k not in LAYER_KINDS for k in kinds):
+            raise ValueError("layer_kinds %r: one of %s for each of the %d "
+                             "layers" % (kinds, LAYER_KINDS, self.n_layers))
+        return tuple(kinds)
+
+    @property
+    def has_experts(self) -> bool:
+        return "experts" in self.kinds or self.mtp_layers > 0
+
+    @property
+    def dense_block(self) -> bool:
+        """True for the block the generation forwards spell."""
+        return (self.attn_kind == "mha" and self.ffn_act == "gelu_tanh"
+                and self.tied_head and self.hc_mult == 1
+                and not self.mtp_layers
+                and all(k == "dense_ffn" for k in self.kinds))
 
     @property
     def ff_dim(self) -> int:
@@ -124,47 +216,133 @@ def make_attn_fn(impl: str, sp_axis: Optional[str] = None,
 # parameters: flat dict, FORWARD (layer) order — the bucket partitioner's
 # and the ZeRO-1 shard layout's input contract
 # ---------------------------------------------------------------------------
+def _mixer_shapes(cfg: TransformerConfig, p: str, dt: str):
+    D = cfg.d_model
+    if cfg.attn_kind == "mha":
+        return [(p + "attn_norm", (D,), dt),
+                (p + "wqkv", (D, 3 * D), dt),
+                (p + "wo", (D, D), dt)]
+    if cfg.attn_kind != "latent":
+        raise ValueError("attn_kind %r: mha or latent" % (cfg.attn_kind,))
+    H, qk = cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return [(p + "attn_norm", (D,), dt),
+            (p + "wq_a", (D, cfg.q_lora_rank), dt),
+            (p + "q_norm", (cfg.q_lora_rank,), dt),
+            (p + "wq_b", (cfg.q_lora_rank, H * qk), dt),
+            (p + "wkv_a", (D, cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt),
+            (p + "kv_norm", (cfg.kv_lora_rank,), dt),
+            (p + "wkv_b", (cfg.kv_lora_rank,
+                           H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dt),
+            (p + "wo", (H * cfg.v_head_dim, D), dt)]
+
+
+def _ffn_shapes(cfg: TransformerConfig, p: str, kind: str, dt: str):
+    D, F = cfg.d_model, cfg.ff_dim
+    out = [(p + "mlp_norm", (D,), dt)]
+    if kind == "experts":
+        G, Fe = len(cfg.held_experts), cfg.expert_ff
+        Fs = cfg.n_shared_experts * Fe
+        return out + [(p + "router", (D, cfg.n_experts), dt),
+                      (p + "router_bias", (cfg.n_experts,), "float32"),
+                      (p + "we_gate", (G, D, Fe), dt),
+                      (p + "we_up", (G, D, Fe), dt),
+                      (p + "we_down", (G, Fe, D), dt),
+                      (p + "ws_gate", (D, Fs), dt),
+                      (p + "ws_up", (D, Fs), dt),
+                      (p + "ws_down", (Fs, D), dt)]
+    if cfg.ffn_act == "gelu_tanh":
+        return out + [(p + "w1", (D, F), dt), (p + "w2", (F, D), dt)]
+    if cfg.ffn_act != "swiglu":
+        raise ValueError("ffn_act %r: gelu_tanh or swiglu" % (cfg.ffn_act,))
+    return out + [(p + "w_gate", (D, F), dt), (p + "w_up", (D, F), dt),
+                  (p + "w_down", (F, D), dt)]
+
+
+def _stream_shapes(cfg: TransformerConfig, p: str, which: str, dt: str):
+    n = cfg.hc_mult
+    if n == 1:
+        return []
+    q = "%shc_%s_" % (p, which)
+    return [(q + "w", (n * cfg.d_model, 2 * n + n * n), dt),
+            (q + "alpha", (3,), dt), (q + "b_pre", (n,), dt),
+            (q + "b_post", (n,), dt), (q + "b_res", (n, n), dt)]
+
+
+def _block_shapes(cfg: TransformerConfig, p: str, kind: str, dt: str):
+    return (_stream_shapes(cfg, p, "attn", dt) + _mixer_shapes(cfg, p, dt)
+            + _stream_shapes(cfg, p, "mlp", dt)
+            + _ffn_shapes(cfg, p, kind, dt))
+
+
 def param_shapes(cfg: TransformerConfig) -> List[Tuple[str, tuple, str]]:
-    """``(name, shape, dtype)`` for every trainable param in layer
+    """``(name, shape, dtype)`` for every leaf of the state in layer
     order — shapes only, no arrays: what ``scaling.grad_entries`` /
     the autotuner's leaf-granularity timing model consume to tune the
-    attention-dominated comm pattern without a compile."""
-    D, F, V = cfg.d_model, cfg.ff_dim, cfg.vocab_size
+    attention-dominated comm pattern without a compile.  Every leaf is
+    trained but those :func:`frozen_names` lists."""
+    D, V = cfg.d_model, cfg.vocab_size
     dt = cfg.param_dtype
     out = [("embed", (V, D), dt)]
-    for i in range(cfg.n_layers):
-        p = "blk%d." % i
-        out += [
-            (p + "attn_norm", (D,), dt),
-            (p + "wqkv", (D, 3 * D), dt),
-            (p + "wo", (D, D), dt),
-            (p + "mlp_norm", (D,), dt),
-            (p + "w1", (D, F), dt),
-            (p + "w2", (F, D), dt),
-        ]
+    for i, kind in enumerate(cfg.kinds):
+        out += _block_shapes(cfg, "blk%d." % i, kind, dt)
     out.append(("final_norm", (D,), dt))
+    if not cfg.tied_head:
+        out.append(("head", (V, D), dt))
+    for j in range(cfg.mtp_layers):
+        p = "mtp%d." % j
+        out += [(p + "hnorm", (D,), dt), (p + "enorm", (D,), dt),
+                (p + "eh_proj", (2 * D, D), dt)]
+        out += _block_shapes(cfg, p, "experts", dt)
+        out.append((p + "final_norm", (D,), dt))
     return out
 
 
+def frozen_names(cfg: TransformerConfig) -> List[str]:
+    """The leaves no gradient touches and the optimizer never sees: the
+    routers' selection biases, which the train step moves by their own
+    rule (``b += rate * sign(mean(count) - count)``)."""
+    return [n for n, _, _ in param_shapes(cfg)
+            if n.endswith("router_bias")]
+
+
+_RESIDUAL_WRITERS = ("wo", "w2", "w_down", "we_down", "ws_down")
+
+
 def init_params(key, cfg: TransformerConfig) -> Dict:
-    """Initialize the flat param dict: N(0, 0.02) matrices (wo/w2
-    scaled down by sqrt(2L) — the GPT-2 residual-stream convention),
-    unit norms.  Deterministic per (key, cfg)."""
+    """Initialize the flat param dict: N(0, 0.02) matrices (those that
+    write into the residual stream scaled down by sqrt(2L) — the GPT-2
+    convention), unit norms; zero selection biases; stream maps that
+    start at the plain residual (``H_res`` near the identity, ``H_pre``
+    1/n, ``H_post`` 1, small ``alpha``).  Deterministic per (key, cfg),
+    and a leaf's values depend on its position alone."""
     import jax
     import jax.numpy as jnp
 
-    if cfg.d_model % cfg.n_heads:
+    if cfg.attn_kind == "mha" and cfg.d_model % cfg.n_heads:
         raise ValueError("d_model %d must divide by n_heads %d"
                          % (cfg.d_model, cfg.n_heads))
     resid_scale = (2.0 * max(cfg.n_layers, 1)) ** -0.5
+    n = cfg.hc_mult
     params: Dict = {}
     for idx, (name, shape, dtype) in enumerate(param_shapes(cfg)):
         sub = jax.random.fold_in(key, idx)
         if name.endswith("norm"):
             params[name] = jnp.ones(shape, dtype)
             continue
+        if name.endswith(("router_bias", "b_post")):
+            params[name] = jnp.zeros(shape, dtype)
+            continue
+        if name.endswith("alpha"):
+            params[name] = jnp.full(shape, 0.01, dtype)
+            continue
+        if name.endswith("b_pre"):
+            params[name] = jnp.full(shape, -jnp.log(n - 1.0), dtype)
+            continue
+        if name.endswith("b_res"):
+            params[name] = (8.0 * jnp.eye(n)).astype(dtype)
+            continue
         scale = 0.02
-        if name.endswith(("wo", "w2")):
+        if name.endswith(_RESIDUAL_WRITERS):
             scale *= resid_scale
         params[name] = (scale * jax.random.normal(
             sub, shape, jnp.float32)).astype(dtype)
@@ -184,18 +362,21 @@ def _rmsnorm(x, gain, eps):
     return (xf * scale).astype(x.dtype) * gain.astype(x.dtype)
 
 
-def _rope(x, positions, base):
+def _rope(x, positions, base, freqs=None):
     """Rotary position embedding over (B, T, H, Dh) with GLOBAL
     ``positions`` — (T,) shared across the batch (training / sequence
     sharding: each shard passes its own global offsets, so rotation
     angles are placement-invariant) or (B, T) per-sequence (decode:
     every slot sits at its OWN cache cursor).  The (T,) path is
-    bit-for-bit the historical rotation."""
+    bit-for-bit the historical rotation.  ``freqs`` replaces the
+    ``base**(-i/half)`` frequencies (YaRN); the pairing stays first
+    half against second half."""
     import jax.numpy as jnp
 
     Dh = x.shape[-1]
     half = Dh // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freqs
     if ang.ndim == 2:                     # (T, half)
         cos = jnp.cos(ang)[None, :, None, :]  # (1, T, 1, half)
@@ -215,53 +396,198 @@ def _gelu(x):
     return jax.nn.gelu(x, approximate=True)
 
 
-def apply(params: Dict, tokens, cfg: TransformerConfig, *,
-          attn_fn, pos_offset=0, remat: Optional[str] = None):
-    """Forward pass: ``tokens`` (B, T_local) int -> logits
-    (B, T_local, vocab) float32 (tied embedding head).
+def _layer_params(params: Dict, prefix: str) -> Dict:
+    """One layer's leaves under their short names."""
+    return {k[len(prefix):]: v for k, v in params.items()
+            if k.startswith(prefix)}
 
-    ``pos_offset`` is this shard's global position of token 0 (a traced
-    scalar under shard_map: ``axis_index("sp") * T_local``); ``remat``
-    overrides ``MXNET_REMAT_POLICY``."""
+
+def _make_block(cfg: TransformerConfig, attn_fn, positions, policy, kind):
+    """``block(h, lp) -> (h, aux)`` for one layer kind: a mixer and a
+    feed-forward, each added to the residual (or, with ``hc_mult``
+    streams, mixed into them); ``aux`` is what an expert layer counted
+    (empty otherwise).  ``h`` is (B, T, D), or (B, T, n, D) streams."""
+    import jax
+
+    from . import blocks as _blocks
+
+    if cfg.attn_kind == "latent":
+        sm_scale = _blocks.latent_sm_scale(cfg)
+
+    def attn_part(h, lp):
+        B, t = h.shape[:2]
+        if cfg.attn_kind == "mha":
+            shape = (B, t, cfg.n_heads, cfg.head_dim)
+            q, k, v = _qkv(h, lp["attn_norm"], lp["wqkv"], shape,
+                           positions, cfg)
+            with jax.named_scope("attn"):
+                o = attn_fn(q, k, v)
+            return _attn_out(o, lp["wo"], (B, t, cfg.d_model))
+        with jax.named_scope("norm"):
+            a = _rmsnorm(h, lp["attn_norm"], cfg.eps)
+        with jax.named_scope("attn_proj"):
+            q, k, v = _blocks.latent_qkv(a, lp, positions, cfg, _rmsnorm,
+                                         _rope)
+        with jax.named_scope("attn"):
+            o = attn_fn(q, k, v, sm_scale=sm_scale)
+        return _attn_out(o, lp["wo"],
+                         (B, t, cfg.n_heads * cfg.v_head_dim))
+
+    def ffn_part(h, lp):
+        if kind == "dense_ffn" and cfg.ffn_act == "gelu_tanh":
+            return _mlp(h, lp["mlp_norm"], lp["w1"], lp["w2"], cfg), {}
+        with jax.named_scope("norm"):
+            m = _rmsnorm(h, lp["mlp_norm"], cfg.eps)
+        with jax.named_scope("mlp"):
+            if kind == "experts":
+                return _blocks.expert_ffn(m, lp, cfg)
+            return _blocks.gated_ffn(m, lp["w_gate"], lp["w_up"],
+                                     lp["w_down"]), {}
+
+    attn_ck = checkpoint_scope(attn_part, policy, "attention")
+
+    def block(h, lp):
+        if cfg.hc_mult == 1:
+            h = h + attn_ck(h, lp)
+            y, aux = ffn_part(h, lp)
+            return h + y, aux
+        h, _ = _blocks.hyper_residual(
+            h, lp, "attn", cfg, lambda x: (attn_ck(x, lp), None))
+        return _blocks.hyper_residual(
+            h, lp, "mlp", cfg, lambda x: ffn_part(x, lp))
+
+    return checkpoint_scope(block, policy, "block")
+
+
+def _to_streams(h, cfg: TransformerConfig):
+    """The model's input copied into every residual stream."""
+    import jax.numpy as jnp
+
+    if cfg.hc_mult == 1:
+        return h
+    return jnp.broadcast_to(h[:, :, None, :],
+                            h.shape[:2] + (cfg.hc_mult, h.shape[-1]))
+
+
+def _from_streams(h, cfg: TransformerConfig):
+    """The streams summed (float32 accumulation) into one output."""
+    import jax.numpy as jnp
+
+    if cfg.hc_mult == 1:
+        return h
+    return jnp.sum(h.astype(jnp.float32), axis=2).astype(h.dtype)
+
+
+def _trunk(params: Dict, tokens, cfg: TransformerConfig, *, attn_fn,
+           pos_offset, policy):
+    """Embedding and layers: the hidden state before the final norm
+    (streams summed) and each expert layer's counts, in layer order."""
     import jax
     import jax.numpy as jnp
 
-    policy = remat_policy(remat)
     compute = jnp.dtype(cfg.dtype)
-    B, t = tokens.shape
+    t = tokens.shape[1]
     positions = pos_offset + jnp.arange(t)
     embed = params["embed"]
     with jax.named_scope("embed"):
-        h = embed.astype(compute)[tokens]
-
-    def attn_part(h, g, wqkv, wo):
-        shape = (B, t, cfg.n_heads, cfg.head_dim)
-        q, k, v = _qkv(h, g, wqkv, shape, positions, cfg)
-        with jax.named_scope("attn"):
-            o = attn_fn(q, k, v)
-        return _attn_out(o, wo, (B, t, cfg.d_model))
-
-    def block(h, g_attn, wqkv, wo, g_mlp, w1, w2):
-        h = h + checkpoint_scope(attn_part, policy, "attention")(
-            h, g_attn, wqkv, wo)
-        return h + _mlp(h, g_mlp, w1, w2, cfg)
-
-    block = checkpoint_scope(block, policy, "block")
-    for i in range(cfg.n_layers):
+        h = _to_streams(embed.astype(compute)[tokens], cfg)
+    blocks = {kind: _make_block(cfg, attn_fn, positions, policy, kind)
+              for kind in set(cfg.kinds)}
+    auxes = []
+    for i, kind in enumerate(cfg.kinds):
         p = "blk%d." % i
         with jax.named_scope("layer%02d" % i):
-            h = block(h, params[p + "attn_norm"], params[p + "wqkv"],
-                      params[p + "wo"], params[p + "mlp_norm"],
-                      params[p + "w1"], params[p + "w2"])
+            h, aux = blocks[kind](h, _layer_params(params, p))
+        if aux:
+            auxes.append(aux)
+    with jax.named_scope("norm"):
+        h = _from_streams(h, cfg)
+    return h, auxes, positions
+
+
+def apply(params: Dict, tokens, cfg: TransformerConfig, *,
+          attn_fn, pos_offset=0, remat: Optional[str] = None):
+    """Forward pass: ``tokens`` (B, T_local) int -> logits
+    (B, T_local, vocab) float32.
+
+    ``pos_offset`` is this shard's global position of token 0 (a traced
+    scalar under shard_map: ``axis_index("sp") * T_local``); ``remat``
+    overrides ``MXNET_REMAT_POLICY``.  Any block kind; the multi-token
+    modules are :func:`loss_and_aux`'s."""
+    h, _, _ = _trunk(params, tokens, cfg, attn_fn=attn_fn,
+                     pos_offset=pos_offset, policy=remat_policy(remat))
     return _logits(_final_norm(h, params, cfg), params, cfg,
                    "btd,vd->btv")
 
 
+def loss_and_aux(params: Dict, tokens, labels, cfg: TransformerConfig, *,
+                 attn_fn, pos_offset=0, remat: Optional[str] = None):
+    """The training loss and what the expert layers counted.
+
+    ``tokens`` (B, T); ``labels`` (B, T + mtp_layers): column ``i`` is
+    the token after ``tokens[:, i]``, so the first ``T`` are the
+    next-token targets and multi-token module ``j`` embeds columns
+    ``j..j+T`` and predicts columns ``j+1..j+1+T``.  The loss is
+    ``lm_loss`` of the trunk plus ``mtp_loss_weight`` times each
+    module's.  ``aux`` is empty without expert layers, else
+    ``counts`` (L, n_experts) int32, ``choice`` (L, B*T, k) int32 and
+    ``dropped`` (L,) int32 over the expert layers in order, the
+    multi-token modules' last."""
+    import jax
+    import jax.numpy as jnp
+
+    policy = remat_policy(remat)
+    t = tokens.shape[1]
+    if labels.shape[1] != t + cfg.mtp_layers:
+        raise ValueError(
+            "labels have %d columns; %d tokens and %d multi-token "
+            "module(s) need %d" % (labels.shape[1], t, cfg.mtp_layers,
+                                   t + cfg.mtp_layers))
+    h, auxes, positions = _trunk(params, tokens, cfg, attn_fn=attn_fn,
+                                 pos_offset=pos_offset, policy=policy)
+    loss = lm_loss(_logits(_final_norm(h, params, cfg), params, cfg,
+                           "btd,vd->btv"),
+                   labels[:, :t] if cfg.mtp_layers else labels)
+    if cfg.mtp_layers:
+        compute = jnp.dtype(cfg.dtype)
+        block = _make_block(cfg, attn_fn, positions, policy, "experts")
+    for j in range(cfg.mtp_layers):
+        p = "mtp%d." % j
+        with jax.named_scope("mtp"):
+            with jax.named_scope("embed"):
+                e = params["embed"].astype(compute)[labels[:, j:j + t]]
+            with jax.named_scope("norm"):
+                both = jnp.concatenate(
+                    [_rmsnorm(h, params[p + "hnorm"], cfg.eps),
+                     _rmsnorm(e, params[p + "enorm"], cfg.eps)], -1)
+            with jax.named_scope("embed"):
+                x = _to_streams(
+                    both @ params[p + "eh_proj"].astype(compute), cfg)
+            # a layer of its own, numbered after the trunk's
+            with jax.named_scope("layer%02d" % (cfg.n_layers + j)):
+                x, aux = block(x, _layer_params(params, p))
+            auxes.append(aux)
+            with jax.named_scope("norm"):
+                h = _from_streams(x, cfg)
+                out = _rmsnorm(h, params[p + "final_norm"], cfg.eps)
+            loss = loss + cfg.mtp_loss_weight * lm_loss(
+                _logits(out, params, cfg, "btd,vd->btv"),
+                labels[:, j + 1:j + 1 + t])
+    # over the expert layers; no expert layer, nothing counted
+    return loss, {k: jnp.stack([a[k] for a in auxes])
+                  for k in (auxes[0] if auxes else ())}
+
+
 # The scope vocabulary of the three forwards (HLO metadata only; what a
 # device trace's operations are classed by): ``embed``, ``norm``,
-# ``attn_proj`` (the qkv and output matmuls, rotary), ``attn`` (the
-# attention core alone), ``mlp``, ``head_loss`` (logits here, the loss
-# in the train step), inside one ``layer%02d`` a layer.
+# ``attn_proj`` (the qkv and output matmuls, rotary; all of the latent
+# projections and their inner norms), ``attn`` (the attention core
+# alone), ``mlp`` (a dense feed-forward; inside it an expert layer's
+# ``moe_route``: scores, top-k, sort, gather, combine and the bias
+# rule, ``moe_expert``: the grouped products, ``moe_shared``),
+# ``head_loss`` (logits here, the loss in the train step), inside one
+# ``layer%02d`` a layer; ``mhc`` inside a layer is the stream maps,
+# Sinkhorn and mixing; ``mtp`` wraps a whole multi-token module.
 def _qkv(h, g, wqkv, shape, positions, cfg):
     import jax
     import jax.numpy as jnp
@@ -301,15 +627,15 @@ def _final_norm(h, params, cfg):
 
 
 def _logits(h, params, cfg, einsum):
-    """The tied head; logits accumulate in f32 (f64 under the control
+    """The head (the embedding, where tied); logits accumulate in f32 (f64 under the control
     methodology) regardless of the bf16 compute dtype."""
     import jax
     import jax.numpy as jnp
 
     acc = jnp.promote_types(jnp.dtype(cfg.dtype), jnp.float32)
+    head = params["embed"] if cfg.tied_head else params["head"]
     with jax.named_scope("head_loss"):
-        return jnp.einsum(einsum, h.astype(acc),
-                          params["embed"].astype(acc))
+        return jnp.einsum(einsum, h.astype(acc), head.astype(acc))
 
 
 def lm_loss(logits, labels):
@@ -414,6 +740,12 @@ def apply_prefill(params, tokens, prompt_lens, cfg: TransformerConfig,
     import jax
     import jax.numpy as jnp
 
+    if not cfg.dense_block:
+        raise NotImplementedError(
+            "generation spells the dense block only (mha, gelu_tanh, "
+            "tied head, one residual stream, no experts, no multi-token "
+            "module); a latent paged cache with its prefill and decode "
+            "is not written")
     compute = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
     positions = jnp.arange(t)
@@ -457,6 +789,12 @@ def apply_decode(params, tokens, positions, cfg: TransformerConfig, *,
     import jax
     import jax.numpy as jnp
 
+    if not cfg.dense_block:
+        raise NotImplementedError(
+            "generation spells the dense block only (mha, gelu_tanh, "
+            "tied head, one residual stream, no experts, no multi-token "
+            "module); a latent paged cache with its prefill and decode "
+            "is not written")
     compute = jnp.dtype(cfg.dtype)
     b = tokens.shape[0]
     span = block_tables.shape[1] * int(block_tokens)

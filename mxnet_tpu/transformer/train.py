@@ -18,6 +18,14 @@ is either:
     (parallel/dp.py ``zero1_bucketed_update``), so each dp rank holds
     1/dp of the optimizer state.
 
+The step trains whatever block ``TransformerConfig`` spells (model.py):
+for a configuration with expert layers the routers' selection biases
+live in the parameter dict (so they are saved and restored with it) but
+take no gradient, have no momentum and never reach the optimizer: the
+step moves them by their own rule from the assignment counts, which it
+returns beside the loss (``routing_counters``).  Such a configuration
+trains on ``dp`` meshes; ``sp`` and the generation forwards raise.
+
 ``fit`` rides the existing robustness stack unchanged: elastic
 checkpoint shards (checkpoint.py manifest — the sharded momenta travel
 in ``optimizer_states``), chaos kill/delay hooks at the same loop
@@ -58,6 +66,10 @@ class TransformerTrainStep:
         ``MXNET_ZERO_STAGE`` (None = read the env knob at build).
     bucket_bytes : pins the gradient bucket cap (bypasses autotune);
         None resolves MXNET_AUTOTUNE_PLAN/_DIR then the env default.
+    params : the state to start from, as ``{name: device array}`` over
+        ``param_shapes(cfg)`` (the step takes them over: its first
+        call donates them); None initialises from ``seed``.  With it
+        set-up holds the parameters once, not beside a seeded copy.
     """
 
     def __init__(self, cfg: TransformerConfig, mesh=None,
@@ -66,7 +78,8 @@ class TransformerTrainStep:
                  attn_impl: Optional[str] = None,
                  remat: Optional[str] = None,
                  zero_stage: Optional[int] = None,
-                 bucket_bytes: Optional[int] = None, seed: int = 0):
+                 bucket_bytes: Optional[int] = None, seed: int = 0,
+                 params: Optional[Dict] = None):
         jax = _jax()
         from ..parallel.mesh import make_mesh
 
@@ -84,6 +97,9 @@ class TransformerTrainStep:
         self._zero_stage = zero_stage
         self._bucket_bytes = bucket_bytes
         self._seed = int(seed)
+        self._given_params = params
+        # what the expert layers of the newest steps counted, unread
+        self._aux_log: List[Dict] = []
         self._built = False
         self._step_no = 0  # optimizer steps dispatched: mx.step's number
 
@@ -124,8 +140,24 @@ class TransformerTrainStep:
                 "ulysses attention shards heads over sp: n_heads %d "
                 "must divide by sp axis size %d" % (cfg.n_heads, n_sp))
 
-        key = jax.random.PRNGKey(self._seed)
-        params = _model.init_params(key, cfg)
+        if sp_axis and (cfg.attn_kind != "mha" or cfg.mtp_layers):
+            raise NotImplementedError(
+                "latent attention and multi-token modules are not "
+                "sequence-sharded; build the step over a dp-only mesh")
+        params, self._given_params = self._given_params, None
+        if params is None:
+            params = _model.init_params(jax.random.PRNGKey(self._seed),
+                                        cfg)
+        else:
+            want = [(n, tuple(sh)) for n, sh, _ in
+                    _model.param_shapes(cfg)]
+            got = [(k, tuple(v.shape)) for k, v in params.items()]
+            if got != want:
+                raise ValueError(
+                    "params given are not param_shapes(cfg): first "
+                    "difference %s" % (next(
+                        (a, b) for a, b in zip(got + [None], want + [None])
+                        if a != b),))
         rep = NamedSharding(self.mesh, P())
         data_spec = P("dp", "sp") if sp_axis else P("dp")
         data_sh = NamedSharding(self.mesh, data_spec)
@@ -133,12 +165,17 @@ class TransformerTrainStep:
         self._params = {k: jax.device_put(v, rep)
                         for k, v in params.items()}
         self._names = list(self._params)
+        frozen = _model.frozen_names(cfg)
+        # the leaves the optimizer sees; the rest are the routers'
+        # selection biases, in expert-layer order
+        self._trained = [k for k in self._names if k not in frozen]
+        del params
 
         # gradient bucket plan over the param leaves (layer order) —
         # the autotuner's resolution precedence applies, so a tuned
         # plan for THIS exchange's fingerprint supplies the caps
-        entries = [(k, tuple(v.shape), str(v.dtype))
-                   for k, v in self._params.items()]
+        entries = [(k, tuple(self._params[k].shape),
+                    str(self._params[k].dtype)) for k in self._trained]
         cap = self._bucket_bytes if self._bucket_bytes is not None \
             else _buckets.bucket_cap_bytes()
         if cap == 0:
@@ -179,11 +216,12 @@ class TransformerTrainStep:
 
         lr, mom_c, wd = self._lr, self._momentum, self._wd
         zero1 = self._zero1
-        names = self._names
         policy = self._policy
         reduce_axes = ("dp", "sp") if sp_axis else ("dp",)
 
         from .. import optimizer as _opt
+
+        trained, bias_rate = self._trained, cfg.router_bias_rate
 
         def step_body(params_d, moms, tokens, labels):
             t_local = tokens.shape[1]
@@ -191,42 +229,55 @@ class TransformerTrainStep:
                 else 0
 
             def pure_loss(p):
-                logits = _model.apply(p, tokens, cfg, attn_fn=attn_fn,
-                                      pos_offset=pos_offset,
-                                      remat=policy)
-                return _model.lm_loss(logits, labels)
+                return _model.loss_and_aux(
+                    dict(params_d, **p), tokens, labels, cfg,
+                    attn_fn=attn_fn, pos_offset=pos_offset, remat=policy)
 
-            loss, grads = jax.value_and_grad(pure_loss)(params_d)
+            (loss, aux), grads = jax.value_and_grad(
+                pure_loss, has_aux=True)({k: params_d[k] for k in trained})
             if sharded:
                 loss = lax.pmean(loss, reduce_axes)
+                # the counts of every replica's tokens; which expert
+                # each token chose stays with its own replica
+                aux = {k: lax.psum(aux[k], reduce_axes)
+                       for k in ("counts", "dropped") if k in aux}
             if zero1:
                 # the shard update inside carries the optimizer scope
                 new_p, new_m = zero1_bucketed_update(
                     grads, params_d, moms, plan, "dp", n_dp,
                     lr=lr, momentum=mom_c, wd=wd, mean_n=n_total,
                     sp_axis=sp_axis)
-                return new_p, new_m, loss
-            if sharded:
-                # the replicated exchange: bucketed all-reduce over
-                # every model-replica axis (psum accepts the tuple;
-                # ring/hierarchical impls are dp-only, so force psum
-                # when an sp axis is present)
-                grads = _buckets.bucketed_reduce(
-                    grads, plan, reduce_axes if sp_axis else "dp",
-                    n=n_total, mean=True,
-                    impl="psum" if sp_axis else None)
-            # leaf by leaf, each parameter where it lies (optimizer.py;
-            # the same helper FusedTrainStep's replicated path runs)
-            with jax.named_scope("optimizer"):
-                new_p, new_m = _opt.fused_sgd_mom_grouped(
-                    names, params_d, grads, moms, lr, mom_c, wd)
-            return new_p, new_m, loss
+            else:
+                if sharded:
+                    # the replicated exchange: bucketed all-reduce over
+                    # every model-replica axis (psum accepts the tuple;
+                    # ring/hierarchical impls are dp-only, so force
+                    # psum when an sp axis is present)
+                    grads = _buckets.bucketed_reduce(
+                        grads, plan, reduce_axes if sp_axis else "dp",
+                        n=n_total, mean=True,
+                        impl="psum" if sp_axis else None)
+                # leaf by leaf, each parameter where it lies
+                # (optimizer.py; the same helper FusedTrainStep's
+                # replicated path runs)
+                with jax.named_scope("optimizer"):
+                    new_p, new_m = _opt.fused_sgd_mom_grouped(
+                        trained, params_d, grads, moms, lr, mom_c, wd)
+            if frozen:
+                # an expert that got more than its share of this step's
+                # assignments is picked a little less readily next step
+                with jax.named_scope("mlp"), jax.named_scope("moe_route"):
+                    for name, c in zip(frozen, aux["counts"]):
+                        c = c.astype(jnp.float32)
+                        new_p[name] = params_d[name] + bias_rate * \
+                            jnp.sign(jnp.mean(c) - c)
+            return new_p, new_m, loss, aux
 
         sdc_on, sdc_n = self._sdc, self._sdc_n
 
         def step_body_sdc(params_d, moms, tokens, labels, ctr):
-            new_p, new_m, loss = step_body(params_d, moms, tokens,
-                                           labels)
+            new_p, new_m, loss, aux = step_body(params_d, moms, tokens,
+                                                labels)
             from .. import sdc as _sdcmod
 
             groups = []
@@ -247,7 +298,7 @@ class TransformerTrainStep:
             fp = lax.cond(ctr % sdc_n == 0, _fps,
                           lambda: jnp.zeros((len(plan),), jnp.uint32))
             rows = lax.all_gather(fp, "dp")
-            return new_p, new_m, loss, rows
+            return new_p, new_m, loss, aux, rows
 
         if sharded:
             from jax import shard_map
@@ -256,14 +307,14 @@ class TransformerTrainStep:
             step = shard_map(
                 step_body, mesh=self.mesh,
                 in_specs=(P(), mom_spec, data_spec, data_spec),
-                out_specs=(P(), mom_spec, P()),
+                out_specs=(P(), mom_spec, P(), P()),
                 check_vma=False)
             if sdc_on:
                 step_sdc = shard_map(
                     step_body_sdc, mesh=self.mesh,
                     in_specs=(P(), mom_spec, data_spec, data_spec,
                               P()),
-                    out_specs=(P(), mom_spec, P(), P()),
+                    out_specs=(P(), mom_spec, P(), P(), P()),
                     check_vma=False)
         else:
             step = step_body
@@ -274,9 +325,9 @@ class TransformerTrainStep:
                           for m in zero1_momentum_buffers(plan, n_dp)]
             mom_sh = [NamedSharding(self.mesh, P("dp"))] * len(plan)
         else:
-            self._moms = {k: jax.device_put(jnp.zeros_like(v), rep)
-                          for k, v in self._params.items()}
-            mom_sh = {k: rep for k in self._params}
+            self._moms = {k: jax.device_put(
+                jnp.zeros_like(self._params[k]), rep) for k in trained}
+            mom_sh = {k: rep for k in trained}
         self._mom_sh = mom_sh
 
         step_meta = {"compute_dtype": str(jnp.dtype(cfg.dtype)),
@@ -286,8 +337,10 @@ class TransformerTrainStep:
         # bench scan below keeps the plain program — per-step cadence
         # needs per-step dispatch
         p_sh = {k: rep for k in self._params}
+        # the last output is what the expert layers counted (an empty
+        # dict for a configuration without any)
         step_fn, in_sh, out_sh = step, (p_sh, mom_sh, data_sh,
-                                        data_sh), (p_sh, mom_sh, rep)
+                                        data_sh), (p_sh, mom_sh, rep, rep)
         if sdc_on:
             step_fn, in_sh, out_sh = (step_sdc, in_sh + (rep,),
                                       out_sh + (rep,))
@@ -304,7 +357,7 @@ class TransformerTrainStep:
             def fn(params_d, moms, tokens, labels):
                 def body(carry, _):
                     p, m = carry
-                    p2, m2, loss = step(p, m, tokens, labels)
+                    p2, m2, loss, _ = step(p, m, tokens, labels)
                     return (p2, m2), loss
 
                 (p2, m2), losses = lax.scan(
@@ -452,11 +505,51 @@ class TransformerTrainStep:
                 if _tvw is not None:
                     _tvw.block(out[2])
             if self._sdc:
-                self._params, self._moms, loss, self._last_sdc_rows = out
+                (self._params, self._moms, loss, aux,
+                 self._last_sdc_rows) = out
             else:
-                self._params, self._moms, loss = out
+                self._params, self._moms, loss, aux = out
+            if aux:
+                self._aux_log.append(aux)
+                del self._aux_log[:-4096]
             self._stamp_telemetry()
         return loss
+
+    def routing_counters(self) -> Optional[Dict]:
+        """What the expert layers counted over the steps since the last
+        call (one read of the small arrays each step returned beside its
+        loss: call it outside a timed window), also stamped as profiler
+        counters ``moe.*``; None where no step with expert layers ran.
+
+        ``assignments_total`` and ``assignments_here`` (to every expert
+        and to those held here), ``dropped`` (assignments to a held
+        expert that were not computed: 0), ``load_max_over_mean`` (the
+        busiest held expert's assignments over the mean held expert's,
+        the mean over those steps and layers), ``steps``, and ``choice`` and
+        ``counts`` of the FIRST of those steps ((L, N, k) and
+        (L, n_experts)), for a comparison with a reference."""
+        import numpy as np
+
+        from .. import profiler as _profiler
+
+        log, self._aux_log = _jax().device_get(self._aux_log), []
+        if not log:
+            return None
+        counts = np.stack([a["counts"] for a in log])
+        held = counts[..., list(self.cfg.held_experts)]
+        out = {
+            "steps": len(log),
+            "assignments_total": int(counts.sum()),
+            "assignments_here": int(held.sum()),
+            "dropped": int(sum(a["dropped"].sum() for a in log)),
+            "load_max_over_mean": float(np.mean(
+                held.max(axis=-1) / np.maximum(held.mean(axis=-1), 1e-30))),
+        }
+        for k, v in out.items():
+            if k != "steps":
+                _profiler.record_counter("moe." + k, v)
+        return dict(out, counts=log[0]["counts"],
+                    choice=log[0].get("choice"))
 
     def sdc_rows(self, step: Optional[int] = None):
         """The newest gathered fingerprint matrix ((n_dp, n_buckets)
@@ -523,9 +616,16 @@ class TransformerTrainStep:
 
     def load_state(self, payload: dict) -> None:
         """Restore params + momenta from a checkpoint payload
-        (``checkpoint.load_checkpoint``'s dict)."""
+        (``checkpoint.load_checkpoint``'s dict).  Host arrays are
+        copied to the device; a ``jax.Array`` already there is taken
+        over as it is (no second copy; the next step donates it)."""
         jax = _jax()
         import numpy as np
+
+        def place(x):
+            if not isinstance(x, jax.Array):
+                x = np.asarray(x)
+            return jax.device_put(x, self._rep)
 
         if not self._built:
             self._build()
@@ -534,9 +634,8 @@ class TransformerTrainStep:
         if missing:
             raise KeyError("checkpoint payload is missing transformer "
                            "params: %s" % missing[:4])
-        self._params = {k: jax.device_put(np.asarray(params[k]),
-                                          self._rep)
-                        for k in self._names}
+        self._params = None   # the seeded copy goes before the new one
+        self._params = {k: place(params[k]) for k in self._names}
         blob = payload.get("optimizer_states")
         if not blob:
             return
@@ -596,24 +695,24 @@ class TransformerTrainStep:
             self._moms = [jax.device_put(m, sh)
                           for m, sh in zip(flats, self._mom_sh)]
         elif saved_stage == 0 and not self._zero1:
-            missing = [k for k in self._names if k not in moms]
+            missing = [k for k in self._trained if k not in moms]
             if missing:
                 raise KeyError("checkpoint momenta missing params: %s"
                                % missing[:4])
             self._moms = {k: jax.device_put(np.asarray(moms[k]),
                                             self._rep)
-                          for k in self._names}
+                          for k in self._trained}
         elif saved_stage == 1:
             # sharded → replicated (e.g. dp=2 stage-1 resuming at
             # dp=1, where stage 1 degenerates to replicated)
-            shapes = {k: tuple(v.shape)
-                      for k, v in self._params.items()}
+            shapes = {k: tuple(self._params[k].shape)
+                      for k in self._trained}
             trimmed = zero1_restage_flats([np.asarray(m) for m in moms],
                                           plan, 1)
             tree = zero1_flats_to_tree(trimmed, plan, shapes)
             self._moms = {k: jax.device_put(np.asarray(tree[k]),
                                             self._rep)
-                          for k in self._names}
+                          for k in self._trained}
         else:
             # replicated → sharded (dp=1 checkpoint resuming at dp>1
             # with MXNET_ZERO_STAGE=1)

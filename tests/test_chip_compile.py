@@ -1,0 +1,98 @@
+"""The new kernels of the training path compiled at real widths for a
+v5e that is described, not attached (the ``on-chip-measurement``
+guide's third rehearsal): what the chip's compiler would refuse shows
+here, at no chip time.  Nothing runs, so nothing here is a time.
+
+The topology is described inside a fixture of this file alone, after a
+test has started: only one process may hold the TPU's library, and this
+worker keeps it until it exits."""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # noqa: BLE001
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_cache():
+    """A compile for a described chip is written to the persistent
+    cache and cannot be read back without a chip: keep it off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _expert_cfg():
+    from mxnet_tpu.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=16384, n_layers=1, d_model=3584, n_heads=32,
+        dtype="bfloat16", layer_kinds=("experts",), n_experts=64,
+        experts_per_token=4, n_shared_experts=1, expert_ff=1024,
+        held_experts=tuple(range(8)), routed_scaling=2.0)
+
+
+def test_expert_layer_compiles_to_a_grouped_kernel(one_chip, no_cache):
+    """4,096 tokens through the expert layer, forward and backward, at
+    the published widths: the products over the held experts become the
+    chip's grouped-matmul kernel (a custom call), never a dense product
+    for every expert."""
+    from mxnet_tpu.transformer import blocks
+
+    cfg = _expert_cfg()
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lp = {"router": spec((3584, 64)), "router_bias": spec((64,)),
+          "we_gate": spec((8, 3584, 1024)), "we_up": spec((8, 3584, 1024)),
+          "we_down": spec((8, 1024, 3584)), "ws_gate": spec((3584, 1024)),
+          "ws_up": spec((3584, 1024)), "ws_down": spec((1024, 3584))}
+    x = spec((1, 4096, 3584), jnp.bfloat16)
+
+    def loss(x, lp):
+        y, aux = blocks.expert_ffn(x, lp, cfg)
+        return jnp.sum(y.astype(jnp.float32)), aux["counts"]
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True),
+                       ).lower(x, lp).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4e9
+
+
+def test_flash_attention_compiles_with_narrower_values(one_chip, no_cache):
+    from mxnet_tpu.parallel.attention import flash_attention
+
+    def spec(width):
+        return jax.ShapeDtypeStruct((1, 4096, 32, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       sm_scale=0.1).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec(192), spec(192), spec(128)).compile()
+    out = compiled.output_shardings
+    assert len(jax.tree_util.tree_leaves(out)) == 3
