@@ -67,7 +67,7 @@ REHEARSAL = dict(
     d_model=64, n_heads=4, vocab=256, seq=64, n_layers=1,
     lm_batch=4, lm_steps=6, lm_lr=0.05,
     gen_slots=2, gen_block=16, gen_prompt=16, gen_context=32, gen_new=6,
-    attn=(1, 32, 2, 8), attn_block=16,
+    attn=(1, 256, 2, 8), attn_block=128,
 )
 ALL_LEGS = ("resnet", "transformer", "serving", "pallas", "multichip")
 

@@ -6,17 +6,26 @@ rebuild makes attention first-class because it is what modern long-context
 workloads shard (ring attention / Ulysses in parallel/ring_attention.py and
 parallel/sequence.py build on this file).
 
-Two implementations, one contract:
+One algorithm (online-softmax flash attention), two lowerings, one entry:
 
-  * ``flash_attention`` — blockwise online-softmax attention expressed with
-    ``lax.scan`` over KV blocks.  O(T) memory, compiles to a fused XLA loop
-    on any backend, differentiable via scan's native VJP (rematerialised by
-    ``jax.checkpoint`` per block).
-  * ``pallas_flash_attention`` — hand-tiled Pallas TPU kernel for the
-    single-chip hot path (MXU-sized q/k tiles in VMEM, f32 accumulators).
-    A caller that asks for the kernel gets the kernel or an error: off-TPU
+  * ``flash_attention`` takes any shape on any backend.  Where the program
+    is LOWERED FOR A TPU (``lax.platform_dependent``: a compile for a
+    described chip counts, the process's default backend does not) and
+    the operands allow (``_kernel_tile``: both sequences a multiple of a
+    128-wide tile and equal when causal, head widths of 64 to 256), it
+    runs as tiled kernels under one custom VJP: the forward keeps ``o``
+    and the log-sum-exp beside ``q``, ``k``, ``v`` and nothing else, the
+    backward recomputes the probabilities tile by tile, and key tiles
+    wholly above the causal diagonal are never visited.  Everywhere else
+    it is a ``lax.scan`` over key blocks, differentiable by the scan's
+    native VJP (each block rematerialised by ``jax.checkpoint``, the
+    carries of every step kept).  ``site_tally()`` counts the call sites
+    traced each way.
+  * ``pallas_flash_attention`` is the kernel lowering alone: the kernels
+    jax ships (``pallas.ops.tpu.splash_attention``), differentiable.  A
+    caller that asks for the kernel gets the kernel or an error: off-TPU
     it runs only with an explicit ``interpret=True``, and shapes its
-    blocks do not divide raise.
+    tiles do not take raise.
 
 Layout: (batch, seq, heads, head_dim) — "BTHD" — matching the ring/Ulysses
 sharding over the seq axis.
@@ -28,12 +37,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["attention_reference", "flash_attention", "pallas_flash_attention"]
+__all__ = ["attention_reference", "flash_attention", "pallas_flash_attention",
+           "site_tally"]
 
 _NEG_INF = -1e30
+# the kernels' tiles, queries and keys alike: the largest that divides both
+# sequences is the one a shape runs at (one choice a shape, nothing tuned
+# at set-up)
+_TILES = (1024, 512, 256, 128)
+# call sites of flash_attention traced so far, by the lowering their
+# shapes allow
+_sites = {"kernel": 0, "scan": 0}
 
 
 def attention_reference(q, k, v, causal=False, sm_scale=None):
@@ -77,8 +92,9 @@ def _finalize(m, l, o, dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_size"))
-def flash_attention(q, k, v, causal=False, sm_scale=None, block_size=512):
-    """Blockwise online-softmax attention via lax.scan over KV blocks.
+def _scan_attention(q, k, v, causal=False, sm_scale=None, block_size=512):
+    """Blockwise online-softmax attention via lax.scan over KV blocks: the
+    lowering every backend and every shape takes.
 
     Memory is O(T·D + block) instead of O(T²); the scan compiles to one
     fused XLA while-loop.  Equivalent to attention_reference to fp32
@@ -127,64 +143,125 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_size=512):
     return _finalize(m, l, o, q.dtype)
 
 
+def site_tally(since=None):
+    """How many calls of ``flash_attention`` have been traced so far (or
+    since an earlier tally), by what their operands allow: ``kernel``
+    sites run the tiled kernels wherever the program is lowered for a TPU
+    (and the scan where it is lowered for anything else), ``scan`` sites
+    run the scan everywhere.  A block traced once and applied at every
+    layer is one site.  ``TransformerTrainStep`` takes the difference
+    around its trace, for ``attn.kernel_sites`` and ``attn.scan_sites``."""
+    return {how: n - (since[how] if since else 0)
+            for how, n in _sites.items()}
+
+
+def _kernel_tile(q, k, v, causal):
+    """The tile the kernels run these operands at, or None where they do
+    not take them; every condition is one a compile for the chip, or a
+    trace, refused."""
+    Tq, Tk = q.shape[1], k.shape[1]
+    takes = (
+        (Tq == Tk or not causal)
+        # 64, 128, 192, 256: wider heads overflow VMEM at the largest tile
+        and all(w % 64 == 0 and w <= 256 for w in (q.shape[-1], v.shape[-1]))
+        and q.dtype == k.dtype == v.dtype
+        and q.dtype in (jnp.bfloat16, jnp.float32)
+        # Mosaic does not lower the kernels' loop indices at 64 bits (the
+        # chip runs jax's default; the tests run x64, so they scan)
+        and not jax.config.jax_enable_x64
+        # the kernels' outputs declare no variance over the axes of a
+        # shard_map whose check_vma is on
+        and not any(getattr(jax.typeof(x), "vma", None) for x in (q, k, v)))
+    if not takes:
+        return None
+    return next((t for t in _TILES if Tq % t == 0 and Tk % t == 0), None)
+
+
+def flash_attention(q, k, v, causal=False, sm_scale=None, block_size=512):
+    """Online-softmax attention over (B, T, H, D) activations, never
+    materialising the (T, T) scores.  Equivalent to attention_reference to
+    fp32 round-off (tested).  ``v`` may be of another width than ``q`` and
+    ``k`` (latent attention: 192-wide keys over 128-wide values); the
+    output takes ``v``'s.
+
+    Lowered for a TPU at shapes the tiles take (``_kernel_tile``) it is
+    the kernels of ``pallas_flash_attention``; otherwise the scan over
+    key blocks of ``block_size``.  The choice is made from what the
+    program can observe, the lowering platform and the shapes, and by
+    nothing else."""
+    tile = _kernel_tile(q, k, v, causal)
+    _sites["scan" if tile is None else "kernel"] += 1
+    if tile is None:
+        return _scan_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                               block_size=block_size)
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    return _dispatch(q, k, v, sm_scale, causal=causal, block_size=block_size,
+                     tile=tile)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_size", "tile"))
+def _dispatch(q, k, v, sm_scale, causal, block_size, tile):
+    return lax.platform_dependent(
+        q, k, v, sm_scale,
+        tpu=functools.partial(_kernel_attention, causal=causal,
+                              block_q=tile, block_k=tile),
+        default=lambda q, k, v, sm_scale: _scan_attention(
+            q, k, v, causal=causal, sm_scale=sm_scale,
+            block_size=block_size))
+
+
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel (single chip hot path)
+# the tiled kernels (jax's splash attention), forward and backward
 # ---------------------------------------------------------------------------
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  causal, sm_scale, block_k):
-    """Grid: (batch*heads, q_blocks, k_blocks).  Blocks live in VMEM;
-    f32 running max / denom / accumulator in scratch."""
-    kb = pl.program_id(2)
-    nk = pl.num_programs(2)
+@functools.lru_cache(maxsize=64)
+def _splash_kernel(H, Tq, Tk, causal, block_q, block_k, interpret):
+    """One head-batched kernel object a shape: the block-level mask tables
+    (which key tiles a query tile visits) are made once, as constants."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash, splash_attention_mask as masks)
 
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    mask = (masks.CausalMask if causal else masks.FullMask)((Tq, Tk))
+    # one backward kernel gives dK, dV and dQ from one recomputation of
+    # the probabilities
+    sizes = splash.BlockSizes(
+        block_q=block_q, block_kv=block_k, block_kv_compute=block_k,
+        block_q_dkv=block_q, block_kv_dkv=block_k,
+        block_kv_dkv_compute=block_k, use_fused_bwd_kernel=True)
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(
+            masks.MultiHeadMask([mask] * H), block_sizes=sizes,
+            head_shards=1, q_seq_shards=1, interpret=interpret)
 
-    q = q_ref[0]  # (block_q, d)
-    k = k_ref[0]  # (block_k, d)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
 
-    if causal:
-        qb = pl.program_id(1)
-        q_idx = qb * q.shape[0] + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        k_idx = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(q_idx >= k_idx, s, _NEG_INF)
-
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[:] = l_ref[:] * corr + p.sum(axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[:] = m_new
-
-    @pl.when(kb == nk - 1)
-    def _done():
-        denom = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+def _kernel_attention(q, k, v, sm_scale, *, causal, block_q, block_k,
+                      interpret=False):
+    """BTHD in, BTHD out, through kernels that take (H, T, D) a row of the
+    batch and no scale: the scale goes onto ``q``, multiplied in float32
+    and rounded once to ``q``'s dtype (the scores themselves stay float32
+    from the product to the softmax; the scan rounds them to bf16)."""
+    kernel = _splash_kernel(q.shape[2], q.shape[1], k.shape[1], bool(causal),
+                            block_q, block_k, bool(interpret))
+    q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
+    out = jax.vmap(kernel)(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)
 
 
 def pallas_flash_attention(q, k, v, causal=False, sm_scale=None,
                            block_q=256, block_k=256, interpret=False):
-    """Tiled Pallas flash attention, lowered by Mosaic on a TPU backend.
+    """Tiled flash attention, lowered by Mosaic on a TPU backend, with a
+    backward of its own (the flash backward from ``o`` and the
+    log-sum-exp; masked tiles skipped in both directions).
 
-    There is no silent fallback: on any other backend the kernel runs
+    There is no silent fallback: on any other backend the kernels run
     only under ``interpret=True`` (the Pallas interpreter — tests), and
-    a sequence the blocks do not divide, or a causal call with
-    ``Tq != Tk``, raises ``ValueError``.  ``flash_attention`` is the
-    formulation that takes any shape on any backend."""
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    blocks that are not a multiple of 128 or do not divide the
+    sequences, or a causal call with ``Tq != Tk``, raise ``ValueError``.
+    ``flash_attention`` is the entry that takes any shape on any backend
+    and comes here when it can."""
+    Tq, Tk = q.shape[1], k.shape[1]
     if sm_scale is None:
-        sm_scale = D ** -0.5
+        sm_scale = q.shape[-1] ** -0.5
     platform = jax.devices()[0].platform
     if platform != "tpu" and not interpret:
         raise RuntimeError(
@@ -193,38 +270,15 @@ def pallas_flash_attention(q, k, v, causal=False, sm_scale=None,
             "interpreter, or call flash_attention" % platform)
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
-    if Tq % block_q or Tk % block_k:
+    if Tq % block_q or Tk % block_k or block_q % 128 or block_k % 128:
         raise ValueError(
             "pallas_flash_attention: blocks (%d, %d) do not divide the "
-            "sequences (Tq=%d, Tk=%d)" % (block_q, block_k, Tq, Tk))
+            "sequences (Tq=%d, Tk=%d) in multiples of 128"
+            % (block_q, block_k, Tq, Tk))
     if causal and Tq != Tk:
         raise ValueError(
             "pallas_flash_attention: causal needs Tq == Tk, got %d and "
             "%d" % (Tq, Tk))
-
-    # fold batch & heads into the grid's first axis; blocks are 2-D (T, D)
-    qr = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-
-    grid = (B * H, Tq // block_q, Tk // block_k)
-    kernel = functools.partial(_flash_kernel, causal=causal,
-                               sm_scale=sm_scale, block_k=block_k)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        interpret=bool(interpret),
-    )(qr, kr, vr)
-    return out.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
+    return _kernel_attention(q, k, v, sm_scale, causal=causal,
+                             block_q=block_q, block_k=block_k,
+                             interpret=interpret)

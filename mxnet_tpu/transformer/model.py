@@ -7,8 +7,11 @@ tied embedding head) whose attention is PLUGGABLE between the
 single-chip fused kernel and the two sequence-parallel formulations
 that already exist in ``parallel/`` but had no end-to-end workload:
 
-  * ``flash``   — parallel/attention.py blockwise online-softmax scan
-                  (single chip / no sp axis);
+  * ``flash``   — parallel/attention.py online-softmax flash attention
+                  (single chip / no sp axis): tiled kernels with a
+                  backward of their own where the program is lowered
+                  for a TPU and the shapes allow, the blockwise scan
+                  everywhere else;
   * ``ring``    — parallel/ring_attention.py KV-rotation over the mesh's
                   ``sp`` axis (contexts that don't fit one chip);
   * ``ulysses`` — parallel/sequence.py all-to-all head resharding

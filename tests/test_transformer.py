@@ -686,3 +686,37 @@ def test_loss_learns_bigram_structure():
     s = TransformerTrainStep(_cfg(), seed=0, learning_rate=0.05)
     losses = s.fit(_iter(num_sequences=64, batch_size=8), 12)
     assert losses[-1] < math.log(64) - 0.2, losses
+
+
+@pytest.mark.parametrize("seq_len,tiles_fit", [(128, True), (48, False)])
+def test_step_stamps_how_its_attention_lowers(seq_len, tiles_fit):
+    """``attn.kernel_sites`` / ``attn.scan_sites``: the calls of
+    flash_attention the traced step holds (one block, traced once, is
+    one site however many layers apply it), by how they lower on the
+    step's devices.  On the CPU every site is a scan site, also one
+    whose shapes the kernels would take on a TPU."""
+    from mxnet_tpu import profiler
+    from mxnet_tpu.parallel import attention
+
+    cfg = _cfg(d_model=128, n_heads=2)          # 64-wide heads
+    s = TransformerTrainStep(cfg, seed=0, attn_impl="flash", remat="block")
+    rng = np.random.RandomState(0)
+    tokens = rng.randint(0, 64, (2, seq_len + 1)).astype("int32")
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    try:
+        with jax.enable_x64(False):             # as the chip runs
+            assert (attention._kernel_tile(
+                *(jnp.zeros((1, seq_len, 2, 64)),) * 3, causal=True)
+                is not None) == tiles_fit
+            for _ in range(2):
+                s.step(mx.nd.array(tokens[:, :-1]),
+                       mx.nd.array(tokens[:, 1:]))
+    finally:
+        profiler.set_state("stop")
+    assert s._attn_sites == {"kernel": 0, "scan": 1}
+    stamped = profiler.summary()["counters"]["counter"]
+    profiler.dumps(reset=True)
+    assert stamped["attn.scan_sites"]["max"] == 1
+    assert stamped["attn.scan_sites"]["count"] == 2     # every step
+    assert stamped["attn.kernel_sites"]["max"] == 0
