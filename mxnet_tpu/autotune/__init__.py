@@ -10,7 +10,7 @@ the 4 MiB ``MXNET_KVSTORE_BUCKET_BYTES`` guess.
 The pipeline:
 
   1. **extract** (``timing.py``) — flight-recorder dumps /
-     ``merge_traces --bucket-timings`` exports / SCALING reports /
+     ``merge_traces --bucket-timings`` exports / traceview summaries /
      raw gradient leaves → one replayable :class:`TimingModel`
      (payload units in issue order + measured step time + measured
      wire bandwidth where real durations exist);
@@ -25,7 +25,7 @@ The pipeline:
      time via ``MXNET_AUTOTUNE_PLAN`` (explicit file) or
      ``MXNET_AUTOTUNE_DIR`` (fingerprint-matched cache), and the
      chosen caps ride the plan_meta stamp into flight-recorder
-     headers, BENCH and SCALING artifacts.
+     headers.
 
 CLI: ``python -m mxnet_tpu.autotune --self-test | --tune <dump> |
 --apply`` (see ``__main__.py``).
@@ -36,11 +36,11 @@ from . import plan, search, timing
 from .plan import load_plan, resolve_caps, save_plan
 from .search import tune
 from .timing import TimingModel, from_bucket_timings, from_flight_dump, \
-    from_leaf_bytes, from_scaling_json, load_any
+    from_leaf_bytes, load_any
 
 __all__ = [
     "timing", "search", "plan",
     "TimingModel", "from_flight_dump", "from_bucket_timings",
-    "from_scaling_json", "from_leaf_bytes", "load_any",
+    "from_leaf_bytes", "load_any",
     "tune", "save_plan", "load_plan", "resolve_caps",
 ]

@@ -7,9 +7,9 @@ Modes:
   --tune PATH            extract a timing model from PATH (a
                          flightrecorder_rank{K}.json dump, a
                          merge_traces --bucket-timings export, or a
-                         SCALING_r*.json report) and search the cap
+                         traceview summary) and search the cap
                          ladder.  Flight inputs need --step-time
-                         (SCALING reports carry it).
+                         (a traceview summary carries it).
   --apply                with --tune: persist the winning plan (to
                          --out, else into MXNET_AUTOTUNE_DIR under its
                          fingerprinted name) and print the env line
@@ -179,17 +179,15 @@ def self_test() -> int:
                 else:
                     os.environ[k] = v
 
-        # -- CLI --tune on a synthetic SCALING report
-        scaling_path = os.path.join(d, "SCALING_test.json")
-        with open(scaling_path, "w") as f:
-            json.dump({"projection_bucket_pipeline": {"bfloat16": {
-                "bucket_bytes": [4 * MIB] * 12,
-                "step_time_s": 0.0138}}}, f)
+        # -- CLI --tune on the synthetic flight dump
+        dump_path = os.path.join(d, "flightrecorder_rank0.json")
+        with open(dump_path, "w") as f:
+            json.dump(dump, f)
         out_path = os.path.join(d, "tuned.json")
-        rc = main(["--tune", scaling_path, "--apply", "--out", out_path,
-                   "--json"])
+        rc = main(["--tune", dump_path, "--step-time", "0.0138",
+                   "--apply", "--out", out_path, "--json"])
         ok(rc == 0 and os.path.exists(out_path),
-           "--tune SCALING json --apply writes the plan")
+           "--tune flight dump --apply writes the plan")
         applied = _plan.load_plan(out_path)
         ok(applied["score"]["chips"] == 256, "applied plan scored @256")
 
@@ -202,8 +200,7 @@ def _run_tune(args) -> int:
     from . import search as _search
     from . import timing as _timing
 
-    model = _timing.load_any(args.tune, step_time_s=args.step_time,
-                             dtype=args.dtype)
+    model = _timing.load_any(args.tune, step_time_s=args.step_time)
     tuned = _search.tune(model, chips=args.chips,
                          step_time_s=args.step_time,
                          ici_GBps=args.ici_gbps)
@@ -247,7 +244,7 @@ def main(argv=None) -> int:
                     help="synthetic end-to-end check (tier-1 CI)")
     ap.add_argument("--tune", metavar="PATH",
                     help="flight dump / --bucket-timings export / "
-                         "SCALING report to tune from")
+                         "traceview summary to tune from")
     ap.add_argument("--apply", action="store_true",
                     help="persist the tuned plan (with --tune)")
     ap.add_argument("--out", default=None,
@@ -260,9 +257,6 @@ def main(argv=None) -> int:
                     help="target chip count the sweep scores at")
     ap.add_argument("--ici-gbps", type=float, default=None,
                     help="override the wire bandwidth assumption")
-    ap.add_argument("--dtype", default=None,
-                    help="which dtype block to read from a SCALING "
-                         "report (default: bfloat16 if present)")
     ap.add_argument("--json", action="store_true",
                     help="emit the full plan JSON on stdout")
     args = ap.parse_args(argv)

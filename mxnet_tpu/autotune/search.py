@@ -157,7 +157,7 @@ def tune(model: TimingModel, *, chips: int = 256,
         raise ValueError(
             "no step time: the overlap model pivots on the measured "
             "single-chip step time — pass step_time_s/--step-time, or "
-            "tune from a SCALING report (which carries it)")
+            "tune from a traceview summary (which carries it)")
     from_trace = (model.source or {}).get("kind") == "trace"
     bw = ici_GBps if ici_GBps is not None else \
         (model.measured_GBps or DEFAULT_ICI_GBPS)

@@ -3,9 +3,10 @@
 The flight recorder (diagnostics.py) already records every bucket
 reduction a rank issued — seq / bucket / bytes / dtype / enqueue_ts /
 complete_ts — and stamps the bucket plan (buckets.plan_meta) into the
-dump header.  This module turns those dumps (or a SCALING report, or a
-model's raw gradient leaves) into ONE normalized object the cap search
-(search.py) can replay through ``scaling.simulate_bucketed_overlap``:
+dump header.  This module turns those dumps (or a device-trace
+summary, or a model's raw gradient leaves) into ONE normalized object
+the cap search (search.py) can replay through
+``scaling.simulate_bucketed_overlap``:
 
   * ``units``         — the reduction payload in ISSUE order (bucket 0 /
                         deepest layers first), either per-gradient
@@ -15,7 +16,7 @@ model's raw gradient leaves) into ONE normalized object the cap search
                         — virtual repartitioning, split/merge of the
                         recorded atoms);
   * ``step_time_s``   — the measured single-chip step time the overlap
-                        model pivots on (SCALING/BENCH carry it; raw
+                        model pivots on (a trace summary carries it;
                         flight dumps don't, so the CLI requires
                         ``--step-time`` for those);
   * ``measured_GBps`` — effective wire bandwidth derived from entries
@@ -37,7 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
     "TimingModel", "from_flight_dump", "from_bucket_timings",
-    "from_scaling_json", "from_leaf_bytes", "from_trace", "load_any",
+    "from_leaf_bytes", "from_trace", "load_any",
 ]
 
 #: durations shorter than this are issue-stamp overhead, not wire time
@@ -200,30 +201,11 @@ def from_bucket_timings(payload: dict, path: Optional[str] = None,
                 "n_ranks": len(ranks)})
 
 
-def from_scaling_json(payload: dict, path: Optional[str] = None,
-                      dtype: Optional[str] = None) -> TimingModel:
-    """Extract from a SCALING_r* report: the
-    ``projection_bucket_pipeline`` block carries both the measured
-    bucket plan (``bucket_bytes``) and the benched step time."""
-    block = payload.get("projection_bucket_pipeline") or {}
-    if dtype is None:
-        dtype = "bfloat16" if "bfloat16" in block else "float32"
-    sub = block.get(dtype)
-    if not isinstance(sub, dict) or not sub.get("bucket_bytes"):
-        raise ValueError(
-            "SCALING report%s has no projection_bucket_pipeline[%r] "
-            "bucket_bytes block" % (" %r" % path if path else "", dtype))
-    return TimingModel(
-        [(int(b), dtype) for b in sub["bucket_bytes"]], "bucket",
-        step_time_s=sub.get("step_time_s"),
-        source={"kind": "scaling", "path": path, "dtype": dtype})
-
-
 def from_leaf_bytes(leaf_bytes: Sequence[int], dtype: str = "float32",
                     step_time_s: Optional[float] = None,
                     source: Optional[dict] = None) -> TimingModel:
     """Exact-granularity model from per-gradient leaf byte sizes in
-    LAYER (forward) order — e.g. ``scaling.resnet50_grad_leaf_bytes``.
+    LAYER (forward) order — e.g. ``scaling.grad_leaf_bytes``.
     Units flip to issue order (reverse layer order), matching what
     buckets.partition will do when the tuned caps are applied."""
     units = [(int(b), dtype) for b in reversed(list(leaf_bytes))]
@@ -290,11 +272,11 @@ def from_trace(payload: dict, path: Optional[str] = None,
     return model
 
 
-def load_any(path: str, step_time_s: Optional[float] = None,
-             dtype: Optional[str] = None) -> TimingModel:
+def load_any(path: str,
+             step_time_s: Optional[float] = None) -> TimingModel:
     """Content-sniffing loader for the CLI's ``--tune`` input: a flight
-    dump, a ``--bucket-timings`` export, a SCALING report, or a
-    traceview device-timeline summary."""
+    dump, a ``--bucket-timings`` export, or a traceview
+    device-timeline summary."""
     with open(path) as f:
         payload = json.load(f)
     if isinstance(payload, dict):
@@ -307,9 +289,6 @@ def load_any(path: str, step_time_s: Optional[float] = None,
         if payload.get("format") == "mxnet-tpu-traceview-summary":
             return from_trace(payload, path=path,
                               step_time_s=step_time_s)
-        if "projection_bucket_pipeline" in payload:
-            return from_scaling_json(payload, path=path, dtype=dtype)
     raise ValueError(
         "%r is not a flight-recorder dump, a merge_traces "
-        "--bucket-timings export, a SCALING report, or a traceview "
-        "summary" % path)
+        "--bucket-timings export, or a traceview summary" % path)

@@ -854,10 +854,21 @@ def profiler_dump(finished: int) -> None:
     profiler.dump(finished=bool(finished))
 
 
+_bulk_prev = None
+
+
 def engine_set_bulk_size(size: int) -> int:
+    """MXEngineSetBulkSize.  A C int cannot carry what
+    ``engine.set_bulk_size`` returns (the previous size AND whether it
+    had been asked for), so the last one is kept here and handed back
+    when C restores the value it was given."""
+    global _bulk_prev
     from . import engine
 
-    return engine.set_bulk_size(int(size))
+    restoring = _bulk_prev is not None and int(size) == _bulk_prev
+    _bulk_prev = engine.set_bulk_size(_bulk_prev if restoring
+                                      else int(size))
+    return int(_bulk_prev)
 
 
 def get_version() -> int:

@@ -39,7 +39,7 @@ logs close.  Three cooperating pieces:
   * **Step-metrics registry** — a small gauge/counter/histogram
     time-series registry (:data:`metrics`) fed by ``fit()`` and
     ``Speedometer``: step_time, samples/s, loss, allocator peak,
-    recompiles, kvstore/io bytes.  ``dump_json()`` for bench.py,
+    recompiles, kvstore/io bytes.  ``dump_json()`` for a harness,
     ``to_prom()`` Prometheus text exposition for external scrapers,
     ``MXNET_METRICS_FILE`` (+ ``MXNET_METRICS_INTERVAL_S``) for a
     periodically flushed exposition file.

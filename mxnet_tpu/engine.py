@@ -43,6 +43,17 @@ def _consult_env() -> None:
         _bulk_explicit = False
 
 
+class _PreviousBulkSize(int):
+    """What :func:`set_bulk_size` returns: the previous size, which
+    also remembers whether that size had been asked for.  Handing it
+    back — the reference's ``prev = set_bulk_size(k); ...;
+    set_bulk_size(prev)``, and :func:`bulk` — then restores the opt-in
+    state with the number, instead of leaving the default 15 EXPLICIT
+    and every later ``Module.fit`` of the process in bulk mode."""
+
+    explicit = True
+
+
 def set_bulk_size(size: int) -> int:
     """Set the bulk-execution segment limit; returns the previous value
     (ref: engine.py:26).  Per-op fusion is XLA's job; the value is
@@ -50,9 +61,10 @@ def set_bulk_size(size: int) -> int:
     module/bulk.py) once this has been called."""
     global _bulk_size, _bulk_explicit
     _consult_env()
-    prev = _bulk_size
+    prev = _PreviousBulkSize(_bulk_size)
+    prev.explicit = _bulk_explicit
     _bulk_size = int(size)
-    _bulk_explicit = True
+    _bulk_explicit = getattr(size, "explicit", True)
     return prev
 
 

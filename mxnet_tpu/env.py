@@ -227,14 +227,6 @@ register("MXNET_ZERO_STAGE", "int", 0,
          "rank (default); 1 = ZeRO-1 (each dp rank owns a 1/dp shard "
          "of every bucket's momenta; grads reduce-scatter, the update "
          "runs on the shard, params all-gather).")
-register("MXNET_BENCH_TRANSFORMER", "str", None,
-         "Transformer bench row dims as 'k=v,k=v' over layers/d_model/"
-         "heads/seq/batch/ff/vocab (bench.bench_transformer); unset "
-         "uses the budget-sized defaults.")
-register("MXNET_BENCH_RECOMMENDER", "str", None,
-         "Recommender bench row dims as 'k=v,k=v' over fields/vocab/"
-         "dim/batch/steps/shards (bench.bench_recommender); unset uses "
-         "the budget-sized defaults.")
 
 # profiler.py — trace autostart (worker subprocess contract)
 register("MXNET_PROFILER_AUTOSTART", "bool", False,

@@ -1,8 +1,8 @@
 """mx.io_pipeline — sharded multi-process decode pool + double-buffered
 async device prefetch: the input pipeline that keeps up with the chip.
 
-BENCH r04 measured single-core decode at ~1100 img/s against ~2330
-img/s/chip compute and could only *project* the on-host number — the
+One decoding core does not keep up with a chip (how far it falls
+short has not been measured on one: ROADMAP W4) — the
 fetch path was ``PrefetchingIter`` (io.py), a literal Python port of
 dmlc ``ThreadedIter`` double buffering: ONE thread decoding JPEGs while
 the GIL serializes everything else.  The reference never ran that way:

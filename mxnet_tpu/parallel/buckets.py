@@ -1,9 +1,9 @@
 """Gradient bucketing for backward-overlapped all-reduce (NCCL-DDP style).
 
 Round 5 measured the data-parallel gradient exchange compiling to ONE
-combined synchronous all-reduce (MULTICHIP_r05.json: no async pairs)
+combined synchronous all-reduce (its HLO held no async start/done pair)
 — a reduction that depends on EVERY gradient cannot start until backward
-finishes, so nothing can hide it and projected eff@256 stalls at ~0.85.
+finishes, so nothing can hide it.
 The fix is the same one NCCL DDP and the reference's engine-priority
 path (python/mxnet/gluon/trainer.py:190, src/kvstore/kvstore_nccl.h:281)
 converged on: partition the gradient pytree into REVERSE-LAYER-ORDER,
@@ -161,7 +161,7 @@ def plan_with_tuning(entries: Sequence[Tuple],
 
     Returns ``(plan, tuning_meta)``; ``tuning_meta`` is None on the
     untuned path and the applied caps + plan provenance otherwise (the
-    meta rides plan_meta into flight-recorder/BENCH/SCALING stamps).
+    meta rides plan_meta into the flight-recorder header's stamp).
     An EXPLICIT ``cap_bytes`` bypasses tuning entirely — a caller
     pinning a cap means it."""
     if cap_bytes is not None:
@@ -401,8 +401,8 @@ def plan_meta(plan: Optional[Sequence[Bucket]],
               cap_bytes: Optional[int] = None,
               tuning: Optional[Dict] = None) -> Dict:
     """Self-describing summary of one reduction schedule — stamped into
-    the flight-recorder header (diagnostics.py) and the BENCH_*/
-    SCALING_* perf artifacts so every dump records which bucket plan
+    the flight-recorder header (diagnostics.py), which a traceview
+    summary carries on, so every dump records which bucket plan
     produced it.  ``tuning`` (plan_with_tuning's meta) records that —
     and from which plan file — the caps were autotuned rather than the
     env default."""

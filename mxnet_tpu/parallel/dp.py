@@ -24,7 +24,7 @@ Gradient exchange: on a pure-dp multi-device mesh the step compiles
 through ``shard_map`` with the gradients reduced in REVERSE-LAYER-ORDER
 size-capped buckets (parallel/buckets.py, NCCL-DDP style) instead of
 letting the SPMD partitioner fold everything into the single combined
-synchronous all-reduce the driver recorded in MULTICHIP_r05.json (zero
+synchronous all-reduce that round 5 found in the compiled HLO (zero
 async start/done pairs).  Per-bucket reductions become operand-
 ready while backward is still running, so XLA's latency-hiding
 scheduler can emit async start/done pairs that overlap backward compute
@@ -406,7 +406,7 @@ class FusedTrainStep:
 
     This is the structural equivalent of the reference's fully-cached
     GraphExecutor fast path (InitCachedOps + bulk segments + kvstore push),
-    collapsed into a single jit.  Used by bench.py and dryrun_multichip.
+    collapsed into a single jit.  Used by perfbench and dryrun_multichip.
 
     The SGD-momentum update has ONE path on one chip and on the
     replicated multi-chip path: ``optimizer.fused_sgd_mom_grouped``

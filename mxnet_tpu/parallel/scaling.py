@@ -1,75 +1,33 @@
-"""Scaling-efficiency harness: sweep + collective accounting + projection.
+"""Gradient-exchange accounting and the bucket-pipeline timing model.
 
-North-star metric #2 (BASELINE.md): allreduce scaling efficiency 8->256
-chips, reference = 90.1% for resnet-152 at 256 GPUs
-(example/image-classification/README.md:309-319).  Real multi-chip
-hardware is not reachable from this environment, so this module provides
-the three measurable proxies the judge asked for (VERDICT r2 item 4):
+What the bucket planner, the autotuner and their tests share:
 
-1. ``sweep()``     — run the fused train step on 1/2/4/8(/16/32) VIRTUAL
-   devices (fresh subprocess per count, XLA
-   --xla_force_host_platform_device_count); assert the loss trajectory
-   matches the single-device run (data-parallel psum-mean == full-batch
-   gradient, up to fp reduction order).
-2. ``collective_stats()`` — parse the compiled HLO of the sharded step
-   and account every collective: op counts + payload bytes per step.
-   This is ground truth about what the program will put on the wire.
-3. ``project_efficiency()`` — a ring-allreduce cost model over the
-   measured gradient bytes and the MEASURED single-chip step time:
-   eff(n) = t_compute / (t_compute + t_exposed_comm(n)), with
-   t_comm(n) = 2(n-1)/n * bytes / ICI_BW and an overlap factor for the
-   fraction of the allreduce XLA hides under the backward pass (the
-   compiled step fuses gradient psum INTO backward, so most of it
-   overlaps; the reference gets the same effect from engine priorities,
-   python/mxnet/gluon/trainer.py:190).
-
-Assumptions are part of the output, not hidden: ICI bandwidth default is
-the public v5e figure (4 links x ~50 GB/s/dir -> ~1.6 Tbit/s aggregate;
-we use 45 GB/s effective per direction, 'ici_GBps'), overlap 0.7
-conservative.  DCN hops (>1 pod) are out of scope exactly as the
-reference table is single-cluster.
+* ``reduction_accounting()`` — one row per collective instruction of a
+  compiled step's HLO, with its payload bytes: what the program will
+  put on the wire, bucket by bucket.
+* ``grad_entries()`` / ``grad_leaf_bytes()`` — the ``(name, shape,
+  dtype)`` leaves of any model's gradient exchange in layer order, and
+  their payload bytes: the input of ``buckets.partition`` and of the
+  autotuner's timing model.
+* ``simulate_bucketed_overlap()`` / ``project_efficiency_bucketed()`` —
+  a DDP pipeline MODEL over a bucket plan and a step time: buckets
+  become issueable through backward and serialize on the comm stream;
+  what runs past the end of backward is exposed.  Its assumptions (ICI
+  bandwidth 45 GB/s effective per direction, backward two thirds of the
+  step) are returned with its output.  It has not been held against a
+  run on chips (ROADMAP W1).
 """
 from __future__ import annotations
 
-import json
-import os
 import re
-import subprocess
-import sys
 from typing import Dict, List, Optional, Sequence
 
 from .overlap import hlo_bytes_in as _hlo_bytes_in
-
-_HLO_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
-                    "collective-permute", "all-to-all")
 
 _COLL_RE = re.compile(
     r"=\s+(.*?)\s*\b"
     r"(all-reduce|all-gather|reduce-scatter|collective-permute|"
     r"all-to-all)(-start|-done)?\(")
-
-
-def collective_stats(hlo_text: str) -> Dict[str, Dict[str, float]]:
-    """Count collectives + payload bytes (result shapes) in compiled HLO.
-
-    HLO instruction forms: ``%n = f32[N]{0} all-reduce(...)`` or, for
-    XLA's fused whole-gradient exchange, a tuple result
-    ``%n = (f32[...], f32[...], ...) all-reduce(...)`` — every element
-    counts.  Async pairs count once (at -start).  A `while` (scan) body
-    appears once in HLO, so a K-step scanned program reports
-    per-iteration traffic."""
-    out: Dict[str, Dict[str, float]] = {}
-    for line in hlo_text.splitlines():
-        m = _COLL_RE.search(line)
-        if m is None:
-            continue
-        shapes, op, suffix = m.group(1), m.group(2), m.group(3)
-        if suffix == "-done":
-            continue
-        entry = out.setdefault(op, {"count": 0, "bytes": 0.0})
-        entry["count"] += 1
-        entry["bytes"] += _hlo_bytes_in(shapes)
-    return out
 
 
 def reduction_accounting(hlo_text: str) -> List[Dict[str, object]]:
@@ -89,210 +47,6 @@ def reduction_accounting(hlo_text: str) -> List[Dict[str, object]]:
         rows.append({"op": op + (suffix or ""),
                      "bytes": int(_hlo_bytes_in(shapes))})
     return rows
-
-
-def _child_code(n: int, steps: int, batch: int, dtype: str = "",
-                lr: float = 0.05) -> str:
-    return r"""
-import json, os, sys
-import numpy as np
-import jax
-jax.config.update("jax_platforms", "cpu")
-sys.path.insert(0, %r)
-import mxnet_tpu as mx
-from mxnet_tpu import gluon, nd
-from mxnet_tpu.gluon.model_zoo import vision
-from mxnet_tpu.parallel.dp import FusedTrainStep
-from mxnet_tpu.parallel.mesh import make_mesh
-from mxnet_tpu.parallel.scaling import collective_stats, \
-    reduction_accounting
-
-np.random.seed(0); mx.random.seed(0)
-n = %d
-dtype = %r or None
-net = vision.resnet18_v1(classes=16)
-net.initialize(mx.init.Xavier())
-mesh = make_mesh((n,), ("dp",), jax.devices()[:n])
-step = FusedTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                      mesh=mesh, learning_rate=%r, momentum=0.9,
-                      dtype=dtype)
-X = nd.random.uniform(shape=(%d, 3, 32, 32))
-y = nd.array((np.arange(%d) %% 16).astype("float32"))
-losses = step.run_steps(X, y, steps=%d)
-tr = [float(v) for v in np.asarray(losses.asnumpy()).reshape(-1)]
-comp = step._multi_step_same[%d].lower(
-    step._param_vals, step._moms,
-    jax.device_put(X._data.astype(dtype) if dtype else X._data,
-                   step._data_sh),
-    jax.device_put(y._data, step._data_sh),
-    step._key_root, step._key_ctr).compile()
-stats = collective_stats(comp.as_text())
-print("SCALING_CHILD " + json.dumps({"n": n, "losses": tr,
-                                     "collectives": stats,
-                                     "bucketed": bool(step.bucketed),
-                                     "buckets": step.bucket_accounting(),
-                                     "reductions": reduction_accounting(
-                                         comp.as_text())}))
-""" % (os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), n, dtype, lr, batch, batch, steps,
-        steps)
-
-
-def _run_child(n: int, code: str, timeout: int, x64: bool = False) -> Dict:
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    if x64:
-        env["JAX_ENABLE_X64"] = "1"
-    flags = " ".join(f for f in env.get("XLA_FLAGS", "").split()
-                     if "host_platform_device_count" not in f)
-    env["XLA_FLAGS"] = (flags +
-                        " --xla_force_host_platform_device_count=%d"
-                        % n).strip()
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=timeout)
-    if proc.returncode != 0:
-        return {"n": n, "error": (proc.stdout + proc.stderr)[-1500:]}
-    for line in proc.stdout.splitlines():
-        if line.startswith("SCALING_CHILD "):
-            return json.loads(line[len("SCALING_CHILD "):])
-    return {"n": n, "error": "no child output"}
-
-
-def sweep(device_counts: Sequence[int] = (1, 2, 4, 8),
-          steps: int = 4, batch: int = 16,
-          timeout: int = 1200) -> Dict:
-    """Numeric-consistency + collective sweep over virtual device counts.
-
-    Same seeds, same GLOBAL batch at every n: the dp-sharded loss
-    trajectory must reproduce the single-device one."""
-    results: List[Dict] = []
-    for n in device_counts:
-        results.append(_run_child(n, _child_code(n, steps, batch),
-                                  timeout))
-
-    ref = next((r for r in results if r.get("n") == 1
-                and "losses" in r), None)
-    for r in results:
-        if "losses" not in r or r is ref or ref is None:
-            continue
-        # the first two losses see at most one parameter update: fp
-        # reduction-order noise only, so the tolerance is tight.  Later
-        # steps amplify that noise through the (chaotic) training
-        # dynamics — reported as drift, quantified as chaos by
-        # control_sweep (fp64: the same trajectories collapse together).
-        head = [abs(a - b) / max(abs(a), 1e-6)
-                for a, b in zip(r["losses"][:2], ref["losses"][:2])]
-        drift = max(abs(a - b) / max(abs(a), 1e-6)
-                    for a, b in zip(r["losses"], ref["losses"]))
-        r["first_step_rel_err"] = round(max(head), 8)
-        r["trajectory_rel_drift"] = round(drift, 6)
-        # fp32 first-step gate: 5e-3, not 1e-4.  One-pass BatchNorm
-        # statistics (var = E[x²]−E[x]², ops/nn.py) cancel two large
-        # all-reduced sums, so reduction-order noise amplifies by
-        # E[x²]/var — measured up to ~2e-3 at small per-device batch.
-        # CORRECTNESS of the sharded computation is pinned by the fp64
-        # control (control_sweep: same trajectories collapse to ~1e-12
-        # across n), which this noise-level gate does not substitute.
-        r["numerically_consistent"] = bool(max(head) < 5e-3)
-    return {"steps": steps, "global_batch": batch, "sweep": results}
-
-
-def control_sweep(device_counts: Sequence[int] = (1, 2, 8),
-                  steps: int = 4, batch: int = 16,
-                  timeout: int = 1200) -> Dict:
-    """The drift-is-chaos control (VERDICT r3 item 6).
-
-    The fp32 sweep's multi-step trajectories diverge ~0.5 rel by step 4;
-    the claim is that this is fp reduction-order noise amplified by
-    chaotic training dynamics, not a sharding bug.  Two controls make
-    that falsifiable:
-
-    * ``fp64``: identical sweep at float64 — reduction-order noise
-      shrinks from ~1e-7 to ~1e-16 per op, so if chaos (noise
-      amplification) is the cause, MULTI-STEP trajectories must now
-      agree across n to ~1e-9.  A sharding bug (wrong mean, missing
-      rows, rank-dependent masking) would NOT shrink with precision.
-    * ``lr0``: fp32, learning rate 0 — parameters never move, so step k
-      repeats step 0 and nothing amplifies; every step must match
-      across n to first-step tolerance.  Isolates the update feedback
-      loop as the amplifier.
-    """
-    out: Dict[str, Dict] = {}
-    for name, dtype, lr, x64, tol in (
-            ("fp64", "float64", 0.05, True, 1e-9),
-            ("lr0", "", 0.0, False, 1e-4)):
-        results = [
-            _run_child(n, _child_code(n, steps, batch, dtype=dtype, lr=lr),
-                       timeout, x64=x64)
-            for n in device_counts]
-        ref = next((r for r in results if r.get("n") == 1
-                    and "losses" in r), None)
-        ok = ref is not None
-        for r in results:
-            if "losses" not in r:
-                ok = False
-                continue
-            if r is ref or ref is None:
-                continue
-            drift = max(abs(a - b) / max(abs(a), 1e-12)
-                        for a, b in zip(r["losses"], ref["losses"]))
-            r["multi_step_rel_drift"] = float(drift)
-            r["multi_step_consistent"] = bool(drift < tol)
-            ok = ok and r["multi_step_consistent"]
-        out[name] = {"dtype": dtype or "float32", "lr": lr,
-                     "tolerance": tol, "steps": steps,
-                     "sweep": results, "all_consistent": ok}
-    return out
-
-
-def mp_placement_sweep(timeout: int = 1200) -> Dict:
-    """dp×mp second workload (VERDICT r3 item 6): the reference's OWN
-    model-parallel LSTM (example/model-parallel/lstm/lstm.py, run
-    byte-identical through tests/mp_lstm_runner.py) trained with
-    ctx_group placement over 1 vs 2 device groups.
-
-    Placement moves buffers, not the algorithm: the per-epoch NLL
-    trajectory must agree across group counts to fp tolerance.  (Not
-    bitwise: each placement compiles DIFFERENT per-device XLA programs,
-    whose fusion choices reorder fp32 reductions — measured ~2.5e-5
-    rel.  A placement bug — wrong copy, stale buffer, dropped grad —
-    shows up orders of magnitude above that.)"""
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    runner = os.path.join(root, "tests", "mp_lstm_runner.py")
-    out: Dict[str, object] = {"workload": "model-parallel LSTM "
-                              "(reference lstm.py, ctx_group placement)"}
-    trajs = {}
-    for ngpu in (1, 2):
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["MP_LSTM_NGPU"] = str(ngpu)
-        flags = " ".join(f for f in env.get("XLA_FLAGS", "").split()
-                         if "host_platform_device_count" not in f)
-        env["XLA_FLAGS"] = (flags +
-                            " --xla_force_host_platform_device_count=8")
-        proc = subprocess.run([sys.executable, runner], env=env,
-                              capture_output=True, text=True,
-                              timeout=timeout)
-        if proc.returncode != 0 or "MP_LSTM_OK" not in proc.stdout:
-            out["ngpu%d" % ngpu] = {
-                "error": (proc.stdout + proc.stderr)[-1500:]}
-            continue
-        nlls = [float(m) for m in
-                re.findall(r"Train: Time: [\d.]+ sec, NLL=([\d.]+)",
-                           proc.stdout)]
-        trajs[ngpu] = nlls
-        out["ngpu%d" % ngpu] = {"train_nll": nlls}
-    if 1 in trajs and 2 in trajs and trajs[1] and trajs[2] and \
-            len(trajs[1]) == len(trajs[2]):
-        rel = max(abs(a - b) / max(abs(a), 1e-9)
-                  for a, b in zip(trajs[1], trajs[2]))
-        out["max_rel_diff"] = rel
-        out["tolerance"] = 1e-3
-        out["trajectories_match"] = bool(rel < 1e-3)
-    else:
-        out["trajectories_match"] = False
-    return out
 
 
 def grad_entries(params, dtype: Optional[str] = None) -> List[tuple]:
@@ -332,43 +86,6 @@ def grad_leaf_bytes(entries: Sequence[tuple]) -> List[int]:
     from . import buckets as _buckets
 
     return [_buckets._nbytes(shape, dt) for _name, shape, dt in entries]
-
-
-def resnet50_grad_entries(dtype: str = "float32") -> List[tuple]:
-    """The data-parallel resnet50 gradient exchange's raw leaves (the
-    zoo workload instance of :func:`grad_entries`).  One eager forward
-    settles deferred shapes; no train compile."""
-    import numpy as np
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import autograd, nd
-    from mxnet_tpu.gluon.model_zoo import vision
-
-    np.random.seed(0)
-    net = vision.resnet50_v1(classes=1000)
-    net.initialize(mx.init.Xavier())
-    with autograd.pause():
-        net(nd.random.uniform(shape=(1, 3, 224, 224)))
-    return grad_entries(net.collect_params(), dtype=dtype)
-
-
-def resnet50_grad_leaf_bytes(dtype: str = "float32") -> List[int]:
-    """Per-gradient leaf payload bytes in LAYER order (resnet50
-    instance of :func:`grad_leaf_bytes`)."""
-    return grad_leaf_bytes(resnet50_grad_entries(dtype))
-
-
-def resnet50_bucket_bytes(dtype: str = "float32",
-                          cap_bytes: Optional[int] = None) -> List[int]:
-    """Per-bucket payload bytes of the data-parallel resnet50 exchange:
-    the zoo model's trainable params in layer order, partitioned by the
-    SAME reverse-layer-order partitioner the in-graph exchange uses
-    (parallel/buckets.py) — no compile needed, ground truth for the
-    bucket-pipeline projection."""
-    from . import buckets as _buckets
-
-    plan = _buckets.partition(resnet50_grad_entries(dtype), cap_bytes)
-    return [int(b.nbytes) for b in plan]
 
 
 def simulate_bucketed_overlap(bucket_bytes: Sequence[int],
@@ -466,59 +183,6 @@ def project_efficiency_bucketed(bucket_bytes: Sequence[int],
         "backward_frac_assumed": backward_frac,
         "coll_latency_s_assumed": coll_latency_s,
         "overlap_by_chips": detail,
-        "projected_efficiency": table,
-        "reference_resnet152_256gpu": 0.901,
-    }
-
-
-def resnet50_grad_bytes(dtype_bytes: int = 4) -> int:
-    """Gradient payload of one data-parallel resnet50 step = parameter
-    bytes (each grad allreduced once)."""
-    import numpy as np
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import autograd, nd
-    from mxnet_tpu.gluon.model_zoo import vision
-
-    np.random.seed(0)
-    net = vision.resnet50_v1(classes=1000)
-    net.initialize(mx.init.Xavier())
-    with autograd.pause():
-        net(nd.random.uniform(shape=(1, 3, 224, 224)))
-    total = 0
-    for p in net.collect_params().values():
-        if p.grad_req != "null":
-            total += int(np.prod(p.shape))
-    return total * dtype_bytes
-
-
-def project_efficiency(grad_bytes: int, step_time_s: float,
-                       chips: Sequence[int] = (8, 16, 32, 64, 128, 256),
-                       ici_GBps: float = 45.0,
-                       overlap: float = 0.7,
-                       overlap_source: str = "assumed") -> Dict:
-    """Ring-allreduce cost model -> projected scaling efficiency.
-
-    t_comm(n) = 2(n-1)/n * grad_bytes / (ici_GBps GB/s); the exposed
-    part is (1-overlap) of it.  ``overlap`` should come from
-    parallel/overlap.py's scheduled-HLO measurement whenever available
-    (overlap_source='measured (scheduled HLO)'); the r4 default of 0.7
-    was an assumption, and the measured schedule emits the combined
-    gradient all-reduce as a SYNC op — overlap 0.  Assumptions are
-    returned with the numbers."""
-    table = {}
-    for n in chips:
-        t_comm = 2.0 * (n - 1) / n * grad_bytes / (ici_GBps * 1e9)
-        exposed = (1.0 - overlap) * t_comm
-        table[str(n)] = round(step_time_s / (step_time_s + exposed), 4)
-    return {
-        "model": "ring allreduce, eff = t_step/(t_step + "
-                 "(1-overlap)*2(n-1)/n*B/BW)",
-        "grad_bytes": grad_bytes,
-        "step_time_s": step_time_s,
-        "ici_GBps_assumed": ici_GBps,
-        "overlap": overlap,
-        "overlap_source": overlap_source,
         "projected_efficiency": table,
         "reference_resnet152_256gpu": 0.901,
     }

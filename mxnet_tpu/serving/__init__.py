@@ -67,8 +67,7 @@ from .generate import (GenerationEngine, GenerationRuntime, GenRequest,
                        stub_greedy_reference)
 from .http import HttpFrontend
 from .kvcache import CacheExhausted, PagedKVCache
-from .loadgen import (BackgroundLoad, gen_tokens_at_slo, qps_at_slo,
-                      run_generation_load, run_load)
+from .loadgen import BackgroundLoad, qps_at_slo, run_load
 from .runtime import (ModelRuntime, demo_params, demo_runtime,
                       plan_batch_buckets)
 from .server import CircuitBreaker, ModelServer
@@ -85,7 +84,6 @@ __all__ = [
     "demo_generation_runtime", "StubGenerationRuntime",
     "stub_greedy_reference",
     "CircuitBreaker", "ModelServer", "HttpFrontend",
-    "run_load", "qps_at_slo", "run_generation_load",
-    "gen_tokens_at_slo", "BackgroundLoad",
+    "run_load", "qps_at_slo", "BackgroundLoad",
     "reqtrace",
 ]

@@ -23,8 +23,7 @@ token blocks, its block table gathered INSIDE the compiled decode step
 compacts cache memory.  Continuous batching rides on top: a finished
 (or cancelled, or evicted) sequence's slot and blocks are reclaimed on
 the NEXT decode tick and refilled from the queue without draining the
-co-riding sequences — the whole-batch comparator mode (``continuous=
-False``) exists so bench.py can measure exactly what that buys.
+co-riding sequences.
 
 Numerics contract, pinned by tests/test_zz_generate_e2e.py: greedy
 decode
@@ -158,7 +157,6 @@ class GenerationRuntime:
                  max_new: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  prefill_batch: Optional[int] = None,
-                 continuous: bool = True,
                  source: str = "inline"):
         from .. import env as _env
 
@@ -169,7 +167,6 @@ class GenerationRuntime:
         self.version = 1
         self.source = source
         self.cfg = cfg
-        self.continuous = bool(continuous)
         self.slots = max(knob(slots, "MXNET_SERVE_GEN_SLOTS"), 1)
         self.block_tokens = max(
             knob(block_tokens, "MXNET_SERVE_KV_BLOCK_TOKENS"), 1)
@@ -358,7 +355,6 @@ class GenerationRuntime:
             max_context=self.max_context, max_new=self.max_new,
             num_blocks=self.kv.num_blocks,
             prefill_batch=self.prefill_batch,
-            continuous=self.continuous,
             source="checkpoint:%s@step%s" % (directory,
                                              payload.get("step")))
 
@@ -512,16 +508,13 @@ class GenerationEngine:
 
     def _admit(self, rep) -> None:
         """Batched prefill for up to ``prefill_batch`` waiting
-        sequences (whole-batch comparator mode only admits into an
-        EMPTY engine — that is the A/B).  Cache-exhausted admissions
-        stay waiting; their deadline keeps running."""
+        sequences.  Cache-exhausted admissions stay waiting; their
+        deadline keeps running."""
         import numpy as np
 
         from .. import chaos as _chaos
 
         rt = self.rt
-        if not rt.continuous and self.active:
-            return
         room = rt.slots - len(self.active)
         group: List[GenRequest] = []
         seqs: List[str] = []

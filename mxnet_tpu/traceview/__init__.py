@@ -8,8 +8,8 @@ parse.py the jax-free walker that classifies device ops into step
 phases (H2D / forward / backward / per-bucket reduce / optimizer /
 D2H) and computes MEASURED per-bucket collective occupancy and
 compute/comm overlap.  Consumers: ``autotune.timing.from_trace``,
-``tools/merge_traces.py --health`` phase-skew, ``bench.py``'s
-``overlap_measured`` block, ``profiler.summary()``'s phase table.
+``tools/merge_traces.py --health`` phase-skew,
+``profiler.summary()``'s phase table.
 
 ``python -m mxnet_tpu.traceview --self-test`` replays the committed
 miniature trace fixture through the walker against golden attribution.

@@ -5,8 +5,7 @@ The capture side (capture.py) wraps ``jax.profiler`` and brackets each
 training/serving dispatch with a ``mxnet:step:<i>:k=<k>`` annotation;
 this module turns the resulting chrome trace-event JSON into the ONE
 summary the consumers share (autotune ``from_trace``, ``merge_traces
---health`` phase-skew, ``bench.py``'s ``overlap_measured`` block,
-``profiler.summary()``'s phase table):
+--health`` phase-skew, ``profiler.summary()``'s phase table):
 
   * device lanes — XLA thunk/stream events, recognized by their
     ``args.hlo_op``/``args.hlo_module`` stamps (XLA:CPU's per-thunk

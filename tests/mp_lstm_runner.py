@@ -68,10 +68,7 @@ def main():
 
     # the reference placement plan (lstm_ptb.py:96-100) on N virtual
     # devices: embed on gpu(0), decode on the last, layers striped.
-    # MP_LSTM_NGPU=1 collapses every group onto one device — used by the
-    # scaling harness's placement-invariance control
-    # (parallel/scaling.py mp_placement_sweep)
-    ngpu = int(os.environ.get("MP_LSTM_NGPU", "2"))
+    ngpu = 2
     group2ctx = {"embed": mx.gpu(0), "decode": mx.gpu(ngpu - 1)}
     for i in range(num_lstm_layer):
         group2ctx["layer%d" % i] = mx.gpu(i * ngpu // num_lstm_layer)

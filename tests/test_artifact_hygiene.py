@@ -20,7 +20,10 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 _ARTIFACT_PATTERNS = ("flightrecorder_rank*", "profile_rank*",
                       "profile_merged*", "profile.json", "metrics*.prom",
-                      "reqtrace_rank*")
+                      "reqtrace_rank*",
+                      # the retired pre-chip benchmark's and scaling
+                      # report's results (PR 31): nothing writes them
+                      "BENCH_r*", "MULTICHIP_r*", "SCALING_r*")
 
 
 def _child_env(extra=None, drop_dump_dir=False):

@@ -92,11 +92,13 @@ def test_load_any_sniffs_all_three_formats(tmp_path):
     tm = autotune.load_any(str(flight), step_time_s=0.01)
     assert tm.source["kind"] == "flight" and tm.step_time_s == 0.01
 
-    scaling = tmp_path / "SCALING_x.json"
-    scaling.write_text(json.dumps({"projection_bucket_pipeline": {
-        "bfloat16": {"bucket_bytes": [MIB] * 4, "step_time_s": 0.02}}}))
-    tm = autotune.load_any(str(scaling))
-    assert tm.source["kind"] == "scaling" and tm.step_time_s == 0.02
+    summary = tmp_path / "traceview_summary_rank0.json"
+    summary.write_text(json.dumps({
+        "format": "mxnet-tpu-traceview-summary",
+        "buckets": [{"bucket": i, "bytes": MIB} for i in range(4)],
+        "steps": {"mean_s": 0.02, "n": 3}}))
+    tm = autotune.load_any(str(summary))
+    assert tm.source["kind"] == "trace" and tm.step_time_s == 0.02
 
     bt = tmp_path / "bucket_timings.json"
     bt.write_text(json.dumps({"format": "bucket-timings", "version": 1,

@@ -530,12 +530,9 @@ def _fit_module(nctx, with_bn=False):
     it = mio.NDArrayIter(X, y, batch_size=16)
     ctxs = [mx.cpu(i) for i in range(nctx)] if nctx > 1 else mx.cpu()
     mod = mx.mod.Module(symbol=net, context=ctxs)
-    engine.set_bulk_size(4)
-    try:
+    with engine.bulk(4):
         mod.fit(it, optimizer="sgd",
                 optimizer_params={"learning_rate": 0.05}, num_epoch=4)
-    finally:
-        engine.set_bulk_size(0)
     return mod
 
 

@@ -9,6 +9,7 @@ the runtime — a dropped push response, a SIGKILL'd worker mid-step, a
 NaN gradient, a permanent collective hang — and these tests assert the
 system RECOVERS: bitwise-exact resume, retry-absorbed drops, documented
 exit codes, dead peers named by merge_traces --health."""
+import contextlib
 import json
 import os
 import signal
@@ -625,19 +626,14 @@ def _fit_pipe(bulk=0, **kw):
     pipe = iop.InputPipeline(
         iop.make_ndarray_iter_fn(x, y, batch_size=8), num_workers=2,
         device=True)
-    # restore the engine's full bulk state (value AND explicitness) —
-    # set_bulk_size(prev) alone would leave the default 15 EXPLICIT,
-    # flipping every later per-batch fit in the session into bulk mode
-    prev_state = (engine._bulk_size, engine._bulk_explicit)
-    if bulk:
-        engine.set_bulk_size(bulk)
     try:
-        mod = mx.mod.Module(symbol=_mlp(), context=mx.cpu())
-        mod.fit(pipe, optimizer="sgd",
-                optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
-                num_epoch=2, **kw)
+        with engine.bulk(bulk) if bulk else contextlib.nullcontext():
+            mod = mx.mod.Module(symbol=_mlp(), context=mx.cpu())
+            mod.fit(pipe, optimizer="sgd",
+                    optimizer_params={"learning_rate": 0.1,
+                                      "momentum": 0.9},
+                    num_epoch=2, **kw)
     finally:
-        engine._bulk_size, engine._bulk_explicit = prev_state
         pipe.close()
     return mod.get_params()[0]
 

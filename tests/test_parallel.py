@@ -143,37 +143,11 @@ def test_module_multi_context():
     assert score[0][1] > 0.85, score
 
 
-def test_dryrun_entrypoints(monkeypatch):
-    # GRAFT_SKIP_SWEEP: the full scaling report (a dozen compile
-    # subprocesses) belongs to the driver's dedicated dryrun phase and
-    # the slow-marked tests in test_scaling.py; tier-1 pins the dryrun
-    # entrypoint itself (mesh build + dp x mp fused step) inside budget
-    monkeypatch.setenv("GRAFT_SKIP_SWEEP", "1")
+def test_dryrun_entrypoints():
     _need_devices(8)
     import __graft_entry__ as ge
 
     ge.dryrun_multichip(8)
-
-
-@pytest.mark.slow
-def test_dryrun_scaling_report_full():
-    """The full dryrun + scaling report (sweep, controls, bucketing
-    accounting, SCALING_r08.json) — the driver-phase behavior."""
-    _need_devices(8)
-    import __graft_entry__ as ge
-
-    ge.dryrun_multichip(8)
-    import json
-    import os
-
-    path = os.path.join(os.path.dirname(os.path.abspath(ge.__file__)),
-                        "SCALING_r08.json")
-    with open(path) as f:
-        rep = json.load(f)
-    assert rep["bucketing"]["bucketed"] is True
-    assert len(rep["bucketing"]["buckets"]) > 1
-    # per-reduction accounting: >1 gradient reduction, no monolith
-    assert len(rep["bucketing"]["per_reduction"]) > 1
 
 
 def test_fused_step_observes_set_data():

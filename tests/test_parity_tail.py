@@ -116,11 +116,17 @@ def test_name_manager_scope_resets_counters():
 
 # ---------------------------------------------------------------- engine
 def test_engine_bulk_api():
+    before = mx.engine.fit_bulk_size()
     prev = mx.engine.set_bulk_size(30)
+    assert mx.engine.fit_bulk_size() == 30
     assert mx.engine.set_bulk_size(prev) == 30
+    # handing the previous value back restores the opt-in state with
+    # the number: a later Module.fit is not left in bulk mode
+    assert mx.engine.fit_bulk_size() == before
     with mx.engine.bulk(5):
         x = nd.ones((4,)) + 1
     assert float(x.sum().asnumpy()) == 8.0
+    assert mx.engine.fit_bulk_size() == before
 
 
 # ------------------------------------------------------------------- rtc

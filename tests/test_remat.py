@@ -319,16 +319,27 @@ def _symbol_fit_params(policy):
     return {k: v.asnumpy() for k, v in params.items()}
 
 
-def test_symbol_path_stage_trajectory_matches_none():
+@pytest.mark.parametrize("bulk", [1, 15], ids=["per_batch", "bulk"])
+def test_symbol_path_stage_trajectory_matches_none(bulk):
     """Module.fit (symbol->apply path) honors MXNET_REMAT_POLICY=stage
     via the executor's stage segmentation: the 3-epoch trained params
-    must match the policy=none run bitwise (a remat segment threads its
-    exact boundary values — same math, fewer residuals)."""
-    p_none = _symbol_fit_params("none")
-    p_stage = _symbol_fit_params("stage")
+    must match the policy=none run (a remat segment threads its exact
+    boundary values — same math, fewer residuals).  The fit mode is
+    said here, not inherited from whatever an earlier test left in the
+    engine: the K-step scan matches bitwise; per batch XLA:CPU rounds
+    one recomputed bias gradient differently (1 ulp), so that path is
+    held to the tolerance of the gluon trajectories above."""
+    with mx.engine.bulk(bulk):
+        p_none = _symbol_fit_params("none")
+        p_stage = _symbol_fit_params("stage")
     assert set(p_none) == set(p_stage)
     for k in p_none:
-        np.testing.assert_array_equal(p_none[k], p_stage[k], err_msg=k)
+        if bulk > 1:
+            np.testing.assert_array_equal(p_none[k], p_stage[k],
+                                          err_msg=k)
+        else:
+            np.testing.assert_allclose(p_none[k], p_stage[k], rtol=1e-6,
+                                       atol=1e-7, err_msg=k)
 
 
 def test_symbol_path_stage_rematerializes():

@@ -391,6 +391,58 @@ def test_e2e_fail_execute_chaos_trips_breaker(monkeypatch):
 
 
 # ---------------------------------------------------------------------
+# the SLO ramp (serving.qps_at_slo) and the tool that drives it
+# ---------------------------------------------------------------------
+def test_qps_at_slo_reports_the_last_rate_that_held():
+    rt = serving.demo_runtime(max_batch=8)
+    srv = serving.ModelServer(max_batch=8, queue_max=128,
+                              batch_deadline_ms=2,
+                              default_deadline_ms=5_000)
+    srv.add_model(rt)
+    try:
+        # a generous SLO: both rates of the ramp (20, 40) hold, and the
+        # answer is the last one's achieved rate
+        out = serving.qps_at_slo(srv, rt.name, slo_p99_ms=5_000,
+                                 start_qps=20, max_qps=50, window_s=0.4)
+        assert [st["offered_qps"] for st in out["ramp"]] == [20.0, 40.0]
+        assert all(st["met_slo"] for st in out["ramp"]), out
+        assert out["qps_at_slo"] == out["ramp"][-1]["achieved_qps"] > 0
+        assert out["p99_ms_at_slo"] == out["ramp"][-1]["p99_ms"]
+        # an SLO nothing can meet: the ramp stops at its first rate and
+        # no rate is reported as held
+        none = serving.qps_at_slo(srv, rt.name, slo_p99_ms=0.0,
+                                  start_qps=20, max_qps=50, window_s=0.4)
+        assert [st["met_slo"] for st in none["ramp"]] == [False], none
+        assert none["qps_at_slo"] == 0.0
+        assert none["p99_ms_at_slo"] is None
+    finally:
+        srv.drain()
+
+
+@pytest.mark.parametrize("mode, keys", [
+    (["--qps", "50", "--duration", "0.5"],
+     ("offered", "admitted", "ok", "shed", "shed_total", "p50_ms",
+      "p99_ms", "achieved_qps")),
+    (["--slo-p99-ms", "0.0"],
+     ("slo_p99_ms", "qps_at_slo", "p99_ms_at_slo", "ramp")),
+], ids=["fixed_rate", "slo_ramp"])
+def test_serve_loadgen_tool_prints_its_accounting(mode, keys):
+    res = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "serve_loadgen.py")]
+        + mode, capture_output=True, text=True, env=_child_env(),
+        cwd=ROOT, timeout=300)
+    assert res.returncode == 0, res.stdout + res.stderr
+    out = json.loads(res.stdout[res.stdout.index("{"):])
+    for key in keys:
+        assert key in out, (key, out)
+    if "ramp" in out:
+        assert all("met_slo" in st for st in out["ramp"]), out
+    else:
+        assert out["admitted"] + out["shed_total"] == out["offered"]
+        assert out["hung"] == 0 and out["ok"] > 0, out
+
+
+# ---------------------------------------------------------------------
 # SIGTERM drain: subprocess exits 83 with zero admitted requests lost
 # ---------------------------------------------------------------------
 def test_sigterm_drain_exits_83_and_completes_admitted(tmp_path):

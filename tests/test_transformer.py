@@ -563,7 +563,7 @@ def test_lm_token_iter_skip_batches_replay():
 
 def test_env_knobs(monkeypatch):
     for name in ("MXNET_ATTENTION_IMPL", "MXNET_REMAT_POLICY",
-                 "MXNET_ZERO_STAGE", "MXNET_BENCH_TRANSFORMER"):
+                 "MXNET_ZERO_STAGE"):
         assert mxenv.is_registered(name), name
     monkeypatch.setenv("MXNET_ATTENTION_IMPL", "ulysses")
     assert attention_impl() == "ulysses"

@@ -8,7 +8,7 @@ being measured; swap in a real checkpoint with --ckpt-dir):
   # fixed-rate window: offered/admitted/ok/shed/p50/p99
   python tools/serve_loadgen.py --qps 500 --duration 3
 
-  # SLO ramp: the BENCH row — QPS sustained at a fixed p99 SLO
+  # SLO ramp: QPS sustained at a fixed p99 SLO (qps_at_slo, ramp)
   python tools/serve_loadgen.py --slo-p99-ms 50
 
 Chaos composes exactly like training: MXNET_CHAOS="slow_request:
