@@ -30,11 +30,11 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 __all__ = ["yarn_inv_freq", "yarn_softmax_mscale", "latent_qkv",
            "gated_ffn", "expert_ffn", "sinkhorn", "stream_maps",
-           "hyper_residual"]
+           "hyper_residual", "site_tally"]
 
 
 # ---------------------------------------------------------------------
@@ -231,47 +231,346 @@ def sinkhorn(m, iters: int, eps: float):
     return m
 
 
-def stream_maps(xs, lp: Dict, which: str, cfg):
-    """The three maps of one sublayer from the streams ``xs``
-    (B, T, n, D): ``H_pre`` (B, T, n), ``H_post`` (B, T, n) and the
-    Sinkhorn-projected ``H_res`` (B, T, n, n), all float32."""
+# call sites of hyper_residual traced so far, by what their operands allow
+_sites = {"kernel": 0, "plain": 0}
+_LEAVES = ("w", "alpha", "b_pre", "b_post", "b_res")
+
+
+class _Mix(NamedTuple):
+    """What a pass is specialised on beside its operands' shapes."""
+    n: int
+    eps: float
+    clamp: float
+    iters: int
+    sink_eps: float
+    sinkhorn: Callable      # ``blocks.sinkhorn`` as the trace found it
+    tile: Optional[int]     # tokens a kernel tile; None: the plain passes
+    interpret: bool = False
+
+
+def site_tally(since=None):
+    """How many calls of ``hyper_residual`` have been traced so far (or
+    since an earlier tally), by what their operands allow: ``kernel``
+    sites run the fused passes' kernels wherever the program is lowered
+    for a TPU (and plain ``jax.numpy`` where it is lowered for anything
+    else), ``plain`` sites run the plain formulation everywhere."""
+    return {how: n - (since[how] if since else 0)
+            for how, n in _sites.items()}
+
+
+def _pre_map(a, u, b):
+    """``H_pre`` from its share of the normed projection; the one line of
+    the maps that the fused forward evaluates inside its pass, so that
+    the pre-mix shares the projection's read of the streams."""
+    import jax
+
+    return jax.nn.sigmoid(a * u + b)
+
+
+def _maps(u, ms, alpha, b_pre, b_post, b_res, st: _Mix):
+    """The three maps from the raw projection ``u`` (..., n*n + 2n) and
+    the mean square ``ms`` (..., 1) of a token's streams, float32."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
+    n = st.n
+    u = u * lax.rsqrt(ms + st.eps)
+    pre = _pre_map(alpha[0], u[..., :n], b_pre)
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * u[..., n:2 * n] + b_post)
+    res = jnp.clip(alpha[2] * u[..., 2 * n:].reshape(u.shape[:-1] + (n, n))
+                   + b_res, -st.clamp, st.clamp)
+    return pre, post, st.sinkhorn(jnp.exp(res), st.iters, st.sink_eps)
+
+
+def _statics(cfg, n, tile=None, interpret=False) -> _Mix:
+    return _Mix(n, cfg.eps, cfg.hc_clamp, cfg.hc_sinkhorn_iters, cfg.hc_eps,
+                sinkhorn, tile, interpret)
+
+
+def _leaves(lp: Dict, which: str):
+    import jax.numpy as jnp
+
+    return [lp["hc_%s_%s" % (which, k)].astype(jnp.float32)
+            for k in _LEAVES]
+
+
+def _projected(x, w):
+    """Streams ``x`` (N, n*D) in float32, their raw projection and the
+    mean square of a token's values: the plain formulation's."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    xf = x.astype(jnp.float32)
+    return (xf, jnp.dot(xf, w, precision=lax.Precision.HIGHEST),
+            jnp.mean(xf * xf, axis=-1, keepdims=True))
+
+
+def stream_maps(xs, lp: Dict, which: str, cfg):
+    """The three maps of one sublayer from the streams ``xs``
+    (B, T, n, D): ``H_pre`` (B, T, n), ``H_post`` (B, T, n) and the
+    Sinkhorn-projected ``H_res`` (B, T, n, n), all float32."""
     b, t, n, d = xs.shape
-    xf = xs.reshape(b, t, n * d).astype(jnp.float32)
-    inv = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + cfg.eps)
-    u = jnp.einsum("btk,kj->btj", xf,
-                   lp["hc_%s_w" % which].astype(jnp.float32),
-                   precision=lax.Precision.HIGHEST) * inv
-    alpha = lp["hc_%s_alpha" % which].astype(jnp.float32)
-    pre = jax.nn.sigmoid(alpha[0] * u[..., :n]
-                         + lp["hc_%s_b_pre" % which].astype(jnp.float32))
-    post = 2.0 * jax.nn.sigmoid(
-        alpha[1] * u[..., n:2 * n]
-        + lp["hc_%s_b_post" % which].astype(jnp.float32))
-    res = jnp.clip(alpha[2] * u[..., 2 * n:].reshape(b, t, n, n)
-                   + lp["hc_%s_b_res" % which].astype(jnp.float32),
-                   -cfg.hc_clamp, cfg.hc_clamp)
-    return pre, post, sinkhorn(jnp.exp(res), cfg.hc_sinkhorn_iters,
-                               cfg.hc_eps)
+    w, *leaves = _leaves(lp, which)
+    _, u, ms = _projected(xs.reshape(b * t, n * d), w)
+    pre, post, res = _maps(u, ms, *leaves, _statics(cfg, n))
+    return (pre.reshape(b, t, n), post.reshape(b, t, n),
+            res.reshape(b, t, n, n))
+
+
+# -- the plain formulation: every backend, every shape; the oracle ------
+def _plain_pre(x, w, alpha, b_pre, b_post, b_res, st: _Mix):
+    """Streams ``x`` (N, n*D) -> ``mixed`` (N, D), the streams again,
+    ``H_post`` (N, n) and ``H_res`` (N, n, n)."""
+    import jax.numpy as jnp
+
+    xf, u, ms = _projected(x, w)
+    pre, post, res = _maps(u, ms, alpha, b_pre, b_post, b_res, st)
+    mixed = jnp.einsum("tn,tnd->td", pre, xf.reshape(x.shape[0], st.n, -1))
+    return mixed.astype(x.dtype), x, post, res
+
+
+def _plain_post(x, y, res, post, st: _Mix):
+    """``out[i] = sum_j H_res[i, j] x[j] + H_post[i] y`` (N, n*D)."""
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32).reshape(x.shape[0], st.n, -1)
+    out = jnp.einsum("tij,tjd->tid", res, xf) \
+        + post[..., None] * y.astype(jnp.float32)[:, None, :]
+    return out.astype(x.dtype).reshape(x.shape)
+
+
+# -- the fused passes: the same sums where the streams lie --------------
+def _fused_tile(xs):
+    """Tokens a tile of the fused passes over these streams, or None
+    where they do not take them; every condition is one a compile for
+    the chip, or a trace, refused."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import mhc_kernels
+
+    b, t, n, d = xs.shape
+    takes = (
+        d % mhc_kernels.LANES == 0
+        # a map entry a lane, and three bf16 parts of each side by side
+        and n >= 2 and 3 * (n * n + 2 * n) <= mhc_kernels.LANES
+        and xs.dtype in (jnp.bfloat16, jnp.float32)
+        # Mosaic does not lower the passes' loop indices at 64 bits
+        and not jax.config.jax_enable_x64
+        and not getattr(jax.typeof(xs), "vma", None))
+    if not takes:
+        return None
+    return mhc_kernels.token_tile(b * t, n * d, xs.dtype.itemsize)
+
+
+def _lanes(a):
+    """(N, ...) -> (N, 128) float32, entry ``k`` of a token in lane k."""
+    import jax.numpy as jnp
+
+    from .mhc_kernels import LANES
+
+    a = a.reshape(a.shape[0], -1)
+    return jnp.pad(a, ((0, 0), (0, LANES - a.shape[1])))
+
+
+def _parts(a, k: int):
+    """``k`` bfloat16 arrays that sum to float32 ``a`` (to 8k bits)."""
+    import jax.numpy as jnp
+
+    out = []
+    for _ in range(k):
+        out.append(a.astype(jnp.bfloat16))
+        a = a - out[-1].astype(jnp.float32)
+    return out
+
+
+def _pre_forward(x, w, alpha, b_pre, b_post, b_res, *, st: _Mix):
+    import jax.numpy as jnp
+
+    from . import mhc_kernels as mk
+
+    k = w.shape[1]
+    if x.dtype == jnp.bfloat16:
+        # the streams are exact in bf16; W in three bf16 parts, one MXU
+        # pass over all of them, keeps float32-grade products
+        w_cols = jnp.concatenate(_parts(w, 3), axis=1)
+        fold = jnp.tile(jnp.eye(k, mk.LANES, dtype=jnp.float32), (3, 1))
+    else:
+        w_cols, fold = w, jnp.eye(k, mk.LANES, dtype=jnp.float32)
+    pad = mk.LANES - w_cols.shape[1]
+    u, ms, mixed = mk.pre_fwd(
+        x, jnp.pad(w_cols, ((0, 0), (0, pad))),
+        jnp.pad(fold, ((0, pad), (0, 0))),
+        jnp.full((1, mk.LANES), alpha[0]), _lanes(b_pre[None]),
+        n=st.n, eps=st.eps, pre_map=_pre_map, tile=st.tile,
+        interpret=st.interpret)
+    u, ms = u[:, :k], ms[:, :1]
+    _, post, res = _maps(u, ms, alpha, b_pre, b_post, b_res, st)
+    return mixed, post, res, u, ms
+
+
+def _pre_backward(x, w, alpha, b_pre, b_post, b_res, u, ms, g_mixed, g_out,
+                  g_post, g_res, *, st: _Mix):
+    """``g_out`` is the cotangent of the NEW streams, as ``_fused_post``
+    hands it back: ``H_res^T`` is applied here, with the other two parts
+    of the streams' gradient, so that they are summed in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import mhc_kernels as mk
+
+    n, k = st.n, w.shape[1]
+    kw = dict(n=n, tile=st.tile, interpret=st.interpret)
+    d_pre = mk.pre_bwd_maps(x, g_mixed, **kw)[:, :n]
+    (pre, _, res), vjp = jax.vjp(
+        lambda *a: _maps(*a, st), u, ms, alpha, b_pre, b_post, b_res)
+    du, dms, dalpha, db_pre, db_post, db_res = vjp((d_pre, g_post, g_res))
+    if x.dtype == jnp.bfloat16:
+        # dU W^T to 16 bits (hi hi + hi lo + lo hi; the sum is rounded to
+        # 8), dU^T X float32-grade (X exact, dU in three parts)
+        (du_hi, du_lo), (w_hi, w_lo) = _parts(du, 2), _parts(w, 2)
+        du_cols = jnp.concatenate([du_hi, du_hi, du_lo], axis=1)
+        wt_cols = jnp.concatenate([w_hi, w_lo, w_hi], axis=1).T
+        du_rows = jnp.concatenate(_parts(du, 3), axis=1).T
+    else:
+        du_cols, wt_cols, du_rows = du, w.T, du.T
+    pad = mk.LANES - du_cols.shape[1]
+    dx, dw = mk.pre_bwd_streams(
+        x, g_out, g_mixed, jnp.pad(du_cols, ((0, 0), (0, pad))),
+        jnp.pad(du_rows, ((0, pad), (0, 0))),
+        jnp.pad(wt_cols, ((0, pad), (0, 0))),
+        _lanes(dms * (2.0 / x.shape[1])), _lanes(pre), _lanes(res), **kw)
+    dw = sum(dw[g * k:(g + 1) * k] for g in range(du_rows.shape[0] // k)).T
+    return dx, dw, dalpha, db_pre, db_post, db_res
+
+
+def _post_forward(x, y, res, post, *, st: _Mix):
+    from . import mhc_kernels as mk
+
+    return mk.post_fwd(x, y, _lanes(res), _lanes(post), n=st.n,
+                       tile=st.tile, interpret=st.interpret)
+
+
+def _post_backward(x, y, post, g_out, *, st: _Mix):
+    from . import mhc_kernels as mk
+
+    n = st.n
+    dy, d_res, d_post = mk.post_bwd(x, y, g_out, _lanes(post), n=n,
+                                    tile=st.tile, interpret=st.interpret)
+    return dy, d_res[:, :n * n].reshape(-1, n, n), d_post[:, :n]
+
+
+@functools.lru_cache(maxsize=None)
+def _body_jaxpr(body, operands, st: _Mix):
+    import jax
+
+    return jax.make_jaxpr(functools.partial(body, st=st), return_shape=True)(
+        *(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in operands))
+
+
+def _body(body):
+    """``body(*arrays, st=)`` under ``jax.jit``, its Python run ONCE for
+    each operand signature (shapes, dtypes, ``st``) and its jaxpr
+    replayed ever after.  jit alone traces a body anew whenever an
+    operand's type differs in its mesh or the tracing context does (the
+    first sublayer's streams against a pass's own result, the forward
+    against its linearisation: up to four times a body), and tracing a
+    kernel or Sinkhorn's backward costs a start-up hundreds of
+    milliseconds a time; a replay costs none."""
+    import jax
+    from jax.extend.core import jaxpr_as_fun
+
+    def replay(*arrays, st):
+        closed, out = _body_jaxpr(
+            body, tuple((a.shape, a.dtype) for a in arrays), st)
+        return jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(out), jaxpr_as_fun(closed)(*arrays))
+
+    replay.__name__ = body.__name__
+    return jax.jit(replay, static_argnames=("st",))
+
+
+@functools.lru_cache(maxsize=None)
+def _fused():
+    """The two passes as operations with a backward of their own, and
+    ``jax.jit`` round each of their four bodies: all sublayers of a step
+    have one operand signature, so the lowered module holds each body
+    once and a call at every site (forward, forward under remat,
+    backward).  Where a body is lowered for anything but a TPU its
+    passes are plain ``jax.numpy`` (``mhc_kernels``)."""
+    import jax
+
+    pre_forward, pre_backward = _body(_pre_forward), _body(_pre_backward)
+    post_forward, post_backward = _body(_post_forward), _body(_post_backward)
+
+    def pre_saving(x, w, alpha, b_pre, b_post, b_res, st):
+        leaves = (w, alpha, b_pre, b_post, b_res)
+        mixed, post, res, u, ms = pre_forward(x, *leaves, st=st)
+        return (mixed, x, post, res), (x,) + leaves + (u, ms)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+    def pre(*args):
+        return pre_saving(*args)[0]
+
+    pre.defvjp(pre_saving, lambda st, saved, cts:
+               pre_backward(*saved, *cts, st=st))
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+    def post(x, y, res, post_map, st):
+        return post_forward(x, y, res, post_map, st=st)
+
+    def post_saving(x, y, res, post_map, st):
+        return post_forward(x, y, res, post_map, st=st), (x, y, post_map)
+
+    def post_bwd(st, saved, g_out):
+        # the streams' slot carries the new streams' cotangent back to
+        # ``pre``'s backward untouched: see ``_pre_backward``
+        return (g_out,) + post_backward(*saved, g_out, st=st)
+
+    post.defvjp(post_saving, post_bwd)
+    return pre, post
+
+
+def _hyper_residual(xs, lp: Dict, which: str, cfg, sublayer, how: str):
+    """``how``: ``plain`` (the plain formulation), ``dispatch`` (the two
+    passes, kernels where lowered for a TPU) or ``interpret`` (the two
+    passes, their kernels run by the Pallas interpreter)."""
+    import jax
+
+    b, t, n, d = xs.shape
+    x = xs.reshape(b * t, n * d)
+    leaves = _leaves(lp, which)
+    if how == "plain":
+        st = _statics(cfg, n)
+        pre_pass, post_pass = _plain_pre, _plain_post
+    else:
+        st = _statics(cfg, n, _fused_tile(xs), interpret=how == "interpret")
+        pre_pass, post_pass = _fused()
+    with jax.named_scope("mhc"):
+        mixed, x, post, res = pre_pass(x, *leaves, st)
+    y, aux = sublayer(mixed.reshape(b, t, d))
+    with jax.named_scope("mhc"):
+        out = post_pass(x, y.reshape(b * t, d), res, post, st)
+    return out.reshape(xs.shape), aux
 
 
 def hyper_residual(xs, lp: Dict, which: str, cfg, sublayer):
     """``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] * F(sum_i H_pre[i]
     X[i])`` over the streams ``xs`` (B, T, n, D); ``sublayer`` is ``F``
-    with its own norm, and may return ``(y, aux)``."""
-    import jax
-    import jax.numpy as jnp
+    with its own norm, and may return ``(y, aux)``.
 
-    dt = xs.dtype
-    with jax.named_scope("mhc"):
-        pre, post, res = stream_maps(xs, lp, which, cfg)
-        xf = xs.astype(jnp.float32)
-        mixed = jnp.einsum("btn,btnd->btd", pre, xf).astype(dt)
-    y, aux = sublayer(mixed)
-    with jax.named_scope("mhc"):
-        out = jnp.einsum("btij,btjd->btid", res, xf) \
-            + post[..., None] * y.astype(jnp.float32)[:, :, None, :]
-    return out.astype(dt), aux
+    One algorithm, two lowerings.  Where the operands allow
+    (``_fused_tile``) it is two passes over the streams as they lie, one
+    before and one after the sublayer, under a backward of their own:
+    Pallas kernels where the program is lowered for a TPU, the same sums
+    in plain ``jax.numpy`` where it is lowered for anything else
+    (``mhc_kernels``).  Any other operands take the plain formulation,
+    differentiated by autodiff, which is also the tests' oracle.  The
+    maps between (sigmoid, clamp, Sinkhorn) are plain float32
+    ``jax.numpy`` either way.  The choice is made from the lowering
+    platform and the operands' shapes and dtypes, and by nothing else;
+    ``site_tally()`` counts it."""
+    how = "plain" if _fused_tile(xs) is None else "dispatch"
+    _sites["kernel" if how == "dispatch" else "plain"] += 1
+    return _hyper_residual(xs, lp, which, cfg, sublayer, how)

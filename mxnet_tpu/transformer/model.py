@@ -409,7 +409,9 @@ def _make_block(cfg: TransformerConfig, attn_fn, positions, policy, kind):
     """``block(h, lp) -> (h, aux)`` for one layer kind: a mixer and a
     feed-forward, each added to the residual (or, with ``hc_mult``
     streams, mixed into them); ``aux`` is what an expert layer counted
-    (empty otherwise).  ``h`` is (B, T, D), or (B, T, n, D) streams."""
+    (empty otherwise).  ``h`` is (B, T, D), or the n streams side by
+    side, (B, T, n*D): between blocks they lie as ``hyper_residual``'s
+    passes read them, so no other layout of them is ever written."""
     import jax
 
     from . import blocks as _blocks
@@ -454,10 +456,12 @@ def _make_block(cfg: TransformerConfig, attn_fn, positions, policy, kind):
             h = h + attn_ck(h, lp)
             y, aux = ffn_part(h, lp)
             return h + y, aux
-        h, _ = _blocks.hyper_residual(
-            h, lp, "attn", cfg, lambda x: (attn_ck(x, lp), None))
-        return _blocks.hyper_residual(
-            h, lp, "mlp", cfg, lambda x: ffn_part(x, lp))
+        xs = h.reshape(h.shape[:2] + (cfg.hc_mult, cfg.d_model))
+        xs, _ = _blocks.hyper_residual(
+            xs, lp, "attn", cfg, lambda x: (attn_ck(x, lp), None))
+        xs, aux = _blocks.hyper_residual(
+            xs, lp, "mlp", cfg, lambda x: ffn_part(x, lp))
+        return xs.reshape(h.shape), aux
 
     return checkpoint_scope(block, policy, "block")
 
@@ -468,8 +472,7 @@ def _to_streams(h, cfg: TransformerConfig):
 
     if cfg.hc_mult == 1:
         return h
-    return jnp.broadcast_to(h[:, :, None, :],
-                            h.shape[:2] + (cfg.hc_mult, h.shape[-1]))
+    return jnp.tile(h, (1, 1, cfg.hc_mult))
 
 
 def _from_streams(h, cfg: TransformerConfig):
@@ -478,7 +481,8 @@ def _from_streams(h, cfg: TransformerConfig):
 
     if cfg.hc_mult == 1:
         return h
-    return jnp.sum(h.astype(jnp.float32), axis=2).astype(h.dtype)
+    streams = h.reshape(h.shape[:2] + (cfg.hc_mult, -1))
+    return jnp.sum(streams.astype(jnp.float32), axis=2).astype(h.dtype)
 
 
 def _trunk(params: Dict, tokens, cfg: TransformerConfig, *, attn_fn,

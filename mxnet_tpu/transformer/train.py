@@ -100,8 +100,10 @@ class TransformerTrainStep:
         self._given_params = params
         # what the expert layers of the newest steps counted, unread
         self._aux_log: List[Dict] = []
-        # how the newest traced step's calls of flash_attention lower
+        # how the newest traced step's calls of flash_attention, and of
+        # hyper_residual, lower
         self._attn_sites: Dict[str, int] = {}
+        self._mhc_sites: Dict[str, int] = {"kernel": 0, "plain": 0}
         self._built = False
         self._step_no = 0  # optimizer steps dispatched: mx.step's number
 
@@ -478,26 +480,39 @@ class TransformerTrainStep:
             else {"kind": "unknown"},
         }
 
-    def _note_attn_sites(self, before: Dict[str, int]) -> None:
+    @staticmethod
+    def _site_tallies():
+        from ..parallel import attention as _attention
+        from . import blocks as _blocks
+
+        return _attention.site_tally(), _blocks.site_tally()
+
+    def _note_sites(self, before) -> None:
         """After a call of a compiled step: if the call traced it, keep
         how many of its calls of ``flash_attention`` run as the tiled
-        kernels and how many as the scan ON THIS MESH (a site whose
-        shapes the kernels take still lowers to the scan for anything
-        but a TPU)."""
+        kernels and how many as the scan ON THIS MESH, and how many of
+        its calls of ``hyper_residual`` run as the fused passes and how
+        many as the plain formulation (a site whose shapes the kernels
+        take still lowers to the other for anything but a TPU)."""
         from ..parallel import attention as _attention
+        from . import blocks as _blocks
 
-        new = _attention.site_tally(since=before)
-        if not any(new.values()):
+        attn = _attention.site_tally(since=before[0])
+        mhc = _blocks.site_tally(since=before[1])
+        if not any(attn.values()) and not any(mhc.values()):
             return
         if self.mesh.devices.flat[0].platform != "tpu":
-            new = {"kernel": 0, "scan": new["kernel"] + new["scan"]}
-        self._attn_sites = new
+            attn = {"kernel": 0, "scan": attn["kernel"] + attn["scan"]}
+            mhc = {"kernel": 0, "plain": mhc["kernel"] + mhc["plain"]}
+        self._attn_sites, self._mhc_sites = attn, mhc
 
     def _stamp_telemetry(self):
         from .. import profiler as _profiler
 
         for how, n in self._attn_sites.items():
             _profiler.record_counter("attn.%s_sites" % how, n)
+        for how, n in self._mhc_sites.items():
+            _profiler.record_counter("mhc.%s_sites" % how, n)
         if self._sharded:
             from ..parallel import buckets as _buckets
 
@@ -509,7 +524,6 @@ class TransformerTrainStep:
         array — not blocked on, so steps pipeline."""
         from .. import profiler as _profiler
         from .. import traceview as _traceview
-        from ..parallel import attention as _attention
 
         self._step_no += 1
         with _profiler.span("mx.step", cat="dispatch",
@@ -522,12 +536,12 @@ class TransformerTrainStep:
             if self._sdc:
                 self._sdc_ctr += 1
                 args += (self._sdc_ctr,)
-            sites = _attention.site_tally()
+            sites = self._site_tallies()
             with _traceview.step_window("TransformerTrainStep") as _tvw:
                 out = self._step(*args)
                 if _tvw is not None:
                     _tvw.block(out[2])
-            self._note_attn_sites(sites)
+            self._note_sites(sites)
             if self._sdc:
                 (self._params, self._moms, loss, aux,
                  self._last_sdc_rows) = out
@@ -590,7 +604,6 @@ class TransformerTrainStep:
         per-step losses (K,)."""
         from .. import profiler as _profiler
         from .. import traceview as _traceview
-        from ..parallel import attention as _attention
 
         k = int(steps)
         with _profiler.span("mx.step", cat="dispatch",
@@ -603,14 +616,14 @@ class TransformerTrainStep:
             if runner is None:
                 runner = self._multi_same_fn(k)
                 self._multi_same[k] = runner
-            sites = _attention.site_tally()
+            sites = self._site_tallies()
             with _traceview.step_window("TransformerTrainStep",
                                         k=k) as _tvw:
                 self._params, self._moms, losses = runner(
                     self._params, self._moms, tokens, labels)
                 if _tvw is not None:
                     _tvw.block(losses)
-            self._note_attn_sites(sites)
+            self._note_sites(sites)
             for _ in range(k):
                 self._stamp_telemetry()
         self._step_no += k

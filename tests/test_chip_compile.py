@@ -110,3 +110,82 @@ def test_flash_attention_compiles_with_narrower_values(one_chip, no_cache,
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "while" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def _stream_block(layers):
+    """``layers`` sublayers of four 3,584-wide streams over 4,096 tokens
+    in bf16, each under ``block`` remat with leaves of its own, and the
+    gradient of a loss over them: (function, arguments to lower it on)."""
+    from mxnet_tpu.transformer import TransformerConfig, blocks
+
+    n, d = 4, 3584
+    cfg = TransformerConfig(vocab_size=16384, n_layers=1, d_model=d,
+                            n_heads=32, dtype="bfloat16", hc_mult=n)
+
+    def loss(xs, lps):
+        for lp in lps:
+            def block(xs, lp):
+                return blocks.hyper_residual(
+                    xs, lp, "mlp", cfg,
+                    lambda m: (m * lp["gain"].astype(m.dtype), None))[0]
+
+            xs = jax.checkpoint(block)(xs, lp)
+        # of one stream: the test's own loss widens none of the four
+        return jnp.sum(xs[:, :, 0].astype(jnp.float32) ** 2)
+
+    def args(one_chip):
+        def spec(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        lp = {"hc_mlp_w": spec((n * d, n * n + 2 * n)),
+              "hc_mlp_alpha": spec((3,)), "hc_mlp_b_pre": spec((n,)),
+              "hc_mlp_b_post": spec((n,)), "hc_mlp_b_res": spec((n, n)),
+              "gain": spec((d,))}
+        return spec((1, 4096, n, d), jnp.bfloat16), [lp] * layers
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))), args
+
+
+def test_stream_mixing_writes_no_float32_copy_of_the_streams(one_chip,
+                                                             no_cache):
+    """One ``hc_mult`` 4 sublayer at the published widths, forward and
+    backward under ``block`` remat, lowered for the chip: the fused
+    passes (Mosaic custom calls), and no buffer of the streams in
+    float32, whichever way they are folded (234.9 MB each: the plain
+    formulation wrote several a sublayer)."""
+    import re
+
+    fn, args = _stream_block(1)
+    with jax.enable_x64(False):     # as the chip runs; under x64: plain
+        text = fn.lower(*args(one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "bf16[4096,14336]" in text
+    wide = re.findall(r"f32\[(?:1,4096,4,3584|4096,14336|1,4096,14336"
+                      r"|4096,4,3584)\]", text)
+    assert not wide, sorted(set(wide))
+
+
+def test_stream_mixing_is_lowered_once_whatever_the_depth(one_chip,
+                                                          no_cache):
+    """The guard of set-up: tracing and lowering run on every start,
+    outside the compile cache, and a kernel's lowering is paid at every
+    copy of its body.  All sublayers have one operand signature, so the
+    LOWERED module of one layer and of three hold the same bodies of the
+    fused passes, called from every site: as many kernels, and as many
+    functions that hold one.  A count, not a time."""
+    import re
+
+    def bodies(layers):
+        fn, args = _stream_block(layers)
+        with jax.enable_x64(False):
+            text = fn.lower(*args(one_chip)).as_text()
+        kernels = text.count("tpu_custom_call")
+        holders = sum("tpu_custom_call" in body for body in
+                      re.split(r"\n\s*func\.func ", text)[1:])
+        calls = len(re.findall(r"call @_(?:pre|post)_(?:for|back)ward",
+                               text))
+        return kernels, holders, calls
+
+    one, three = bodies(1), bodies(3)
+    assert one[:2] == three[:2] and one[0] >= 5, (one, three)
+    assert three[2] == 3 * one[2] > 0, (one, three)   # the sites call them

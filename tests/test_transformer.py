@@ -720,3 +720,43 @@ def test_step_stamps_how_its_attention_lowers(seq_len, tiles_fit):
     assert stamped["attn.scan_sites"]["max"] == 1
     assert stamped["attn.scan_sites"]["count"] == 2     # every step
     assert stamped["attn.kernel_sites"]["max"] == 0
+
+
+@pytest.mark.parametrize("streams,x64,sites", [
+    (2, True, {"kernel": 0, "plain": 2}),       # as tier-1 traces
+    (2, False, {"kernel": 2, "plain": 0}),      # as the chip runs
+    (1, False, {"kernel": 0, "plain": 0})])     # the dense block
+def test_step_stamps_how_its_stream_mixing_lowers(streams, x64, sites):
+    """``mhc.kernel_sites`` / ``mhc.plain_sites``: the calls of
+    hyper_residual the traced step holds (two a traced block), by how
+    they lower on the step's
+    devices: stamped every step, nought of either by the dense block.
+    ``blocks.site_tally`` says what the operands allow (bf16 streams at
+    a kernel's widths: ``kernel``, but never under x64); on the CPU's
+    mesh every site lowers to the plain formulation."""
+    from mxnet_tpu import profiler
+    from mxnet_tpu.transformer import blocks
+
+    cfg = _cfg(n_layers=1, d_model=128, n_heads=2, hc_mult=streams,
+               dtype="bfloat16")
+    s = TransformerTrainStep(cfg, seed=0, attn_impl="flash", remat="block")
+    rng = np.random.RandomState(0)
+    tokens = rng.randint(0, 64, (2, 17)).astype("int32")
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    before = blocks.site_tally()
+    try:
+        with jax.enable_x64(x64):
+            for _ in range(2):
+                s.step(mx.nd.array(tokens[:, :-1]),
+                       mx.nd.array(tokens[:, 1:]))
+    finally:
+        profiler.set_state("stop")
+    assert blocks.site_tally(since=before) == sites
+    assert s._mhc_sites == {"kernel": 0, "plain": sum(sites.values())}
+    stamped = profiler.summary()["counters"]["counter"]
+    profiler.dumps(reset=True)
+    assert stamped["mhc.plain_sites"]["max"] == sum(sites.values())
+    assert stamped["mhc.plain_sites"]["count"] == 2     # every step
+    assert stamped["mhc.kernel_sites"]["max"] == 0
+    assert stamped["mhc.kernel_sites"]["count"] == 2

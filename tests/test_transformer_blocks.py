@@ -241,6 +241,114 @@ def test_stream_maps_are_not_the_identity_under_the_cell_s_init():
     assert float(jnp.std(res[:, :, 0, 0])) > 1e-3   # it is a function of x
 
 
+def _mix_case(dtype, n, which, width=128, batch=2, seq=16):
+    """Streams, a layer's stream leaves, a sublayer with a weight and an
+    ``aux`` of its own, and a cotangent for the new streams."""
+    cfg = TransformerConfig(vocab_size=64, n_layers=1, d_model=width,
+                            n_heads=2, dtype=dtype, hc_mult=n)
+    ks = jax.random.split(jax.random.PRNGKey(11), 7)
+    normal = jax.random.normal
+    lp = {"hc_%s_w" % which: 0.05 * normal(ks[0], (n * width,
+                                                   n * n + 2 * n)),
+          "hc_%s_alpha" % which: jnp.asarray([0.7, 1.1, 0.9]),
+          "hc_%s_b_pre" % which: 0.3 * normal(ks[1], (n,)),
+          "hc_%s_b_post" % which: 0.3 * normal(ks[2], (n,)),
+          "hc_%s_b_res" % which: normal(ks[3], (n, n)),
+          "gain": 1.0 + 0.1 * normal(ks[4], (width,))}
+    xs = normal(ks[5], (batch, seq, n, width)).astype(dtype)
+    ct = normal(ks[6], xs.shape)
+
+    def run(how):
+        def loss(xs, lp):
+            def sublayer(m):
+                y = jnp.tanh(m.astype(jnp.float32) * lp["gain"])
+                return y.astype(m.dtype), {"sum": jnp.sum(y)}
+
+            out, aux = blocks._hyper_residual(xs, lp, which, cfg,
+                                              sublayer, how)
+            seen = {}
+            blocks._hyper_residual(
+                xs, lp, which, cfg,
+                lambda m: (seen.setdefault("mixed", m), None), how)
+            return (jnp.sum(out.astype(jnp.float32) * ct)
+                    + 0.01 * aux["sum"]), (out, seen["mixed"])
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(xs, lp)
+
+    return run
+
+
+@pytest.mark.parametrize("dtype,n,which,how", [
+    ("float32", 4, "attn", "interpret"), ("float32", 4, "mlp", "interpret"),
+    ("float32", 2, "attn", "interpret"), ("bfloat16", 4, "attn", "interpret"),
+    ("bfloat16", 4, "mlp", "interpret"), ("bfloat16", 2, "mlp", "interpret"),
+    ("float32", 4, "mlp", "dispatch"), ("bfloat16", 4, "attn", "dispatch")])
+def test_fused_stream_passes_match_the_plain_formulation(dtype, n, which,
+                                                         how):
+    """``mixed``, the new streams and every gradient (streams, the
+    sublayer's own weight, the five stream leaves) of the two passes
+    under their own backward, against the plain formulation kept beside
+    them and differentiated by autodiff: the kernels through the Pallas
+    interpreter (``interpret``), and what the same passes lower to for
+    the CPU (``dispatch``).  Float32 streams agree to round-off, bf16
+    streams to one rounding of what is written in bf16."""
+    with jax.enable_x64(False):             # as the chip runs
+        run = _mix_case(dtype, n, which)
+        assert blocks._fused_tile(
+            jnp.zeros((2, 16, n, 128), dtype)) is not None
+        (l0, (out0, mixed0)), (dx0, dlp0) = run("plain")
+        (l1, (out1, mixed1)), (dx1, dlp1) = run(how)
+    f32 = lambda a: np.asarray(a, np.float32)      # noqa: E731
+    # one rounding: half a unit in the last of bf16's 8 bits, each side
+    wide = dict(rtol=2 ** -7, atol=2 ** -7) if dtype == "bfloat16" \
+        else dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(f32(mixed1), f32(mixed0), **wide)
+    np.testing.assert_allclose(f32(out1), f32(out0), **wide)
+    np.testing.assert_allclose(f32(dx1), f32(dx0), **wide)
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-5)
+    assert sorted(dlp1) == sorted(dlp0) and len(dlp0) == 6
+    for name in dlp0:
+        # float32 leaves either way: sums over all tokens of products
+        # that the bf16 case rounds at other places
+        scale = float(np.abs(f32(dlp0[name])).max())
+        tol = (2e-2 if dtype == "bfloat16" else 2e-5) * scale
+        np.testing.assert_allclose(f32(dlp1[name]), f32(dlp0[name]),
+                                   atol=tol, err_msg=name)
+
+
+def test_fused_stream_passes_take_sinkhorn_from_the_module(monkeypatch):
+    """Both lowerings reach Sinkhorn as ``blocks.sinkhorn`` when they are
+    traced (the benchmark's rehearsal plants its fault there), also after
+    an earlier trace of the same shapes."""
+    with jax.enable_x64(False):
+        run = _mix_case("float32", 4, "attn")
+        (_, (real, _)), _ = run("interpret")
+        monkeypatch.setattr(
+            blocks, "sinkhorn",
+            lambda m, iters, eps: jnp.broadcast_to(
+                jnp.eye(m.shape[-1]), m.shape) + 0.0 * m)
+        (_, (fused, _)), _ = run("interpret")
+        (_, (plain, _)), _ = run("plain")
+    assert float(jnp.max(jnp.abs(fused - real))) > 0.1
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(plain),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,dtype,fits", [
+    ((1, 4096, 4, 3584), "bfloat16", True),     # the cell's
+    ((2, 16, 2, 128), "float32", True),
+    ((2, 16, 4, 32), "float32", False),         # the toy's width
+    ((1, 24, 4, 128), "float32", False),        # no tile of whole tokens
+    ((2, 16, 6, 128), "bfloat16", False),       # 3 * 48 map entries
+    ((2, 16, 4, 128), "float16", False)])
+def test_fused_stream_passes_are_chosen_from_the_operands(shape, dtype, fits):
+    xs = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    with jax.enable_x64(False):
+        assert (blocks._fused_tile(xs) is not None) == fits
+    assert blocks._fused_tile(xs) is None       # tier-1 traces under x64
+
+
 # ---------------------------------------------------------------------
 # attention
 # ---------------------------------------------------------------------
