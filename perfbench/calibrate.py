@@ -1,4 +1,4 @@
-"""Readings for the limits of a training cell's comparison.
+"""Readings for the limits of a cell's comparison.
 
     python -m perfbench.calibrate --workload <cell> --seeds 1,2,3 \
         [--control 1] [--faults 1] [--out chiprun_out/<file>.jsonl]
@@ -9,7 +9,9 @@ control the cell names against the same reference (``control.program``:
 the program's own lower-precision path, given as configuration keys to
 override; ``control.reference``: the reference with that quantiser on
 its operands); with ``--faults`` a training cell's reference with half
-of each batch left out, and with its state left unchanged.  One JSON
+of each batch left out, and with its state left unchanged, or a serving
+cell's own ``faults`` planted under the server one after another
+(``drivers/serve_lm.py``).  One JSON
 line a reading; the file named by ``--out`` also gets both sides' norms
 leaf by leaf.  The benchmark's own runs never run this; ``PERF.md``
 records what it read on the chip.
@@ -67,7 +69,11 @@ def main(argv=None, *, manifest_path=None, data_root=None) -> int:
         drv = module.Driver(cell, cfg, seed, devices, _spans.Spans())
         drv.setup()
         if "calibrate_seconds" in cell:
+            if hasattr(drv, "warm"):
+                drv.warm(cell["calibrate_seconds"])
             print(drv.window(cell["calibrate_seconds"])["info"], flush=True)
+            if args.faults and hasattr(drv, "fault_windows"):
+                drv.fault_windows(cell["calibrate_seconds"])
         drv.release()
         return drv, time.perf_counter() - t0
 

@@ -7,17 +7,33 @@ cache and the compiled prefill and decode programs (the call shapes of
 ``chip_smoke.py``'s serving leg, without the HTTP front end).  Load is
 open loop at the cell's fixed rate (``perfbench/loadgen.py``).
 
+Before the window opens, ``warm`` offers the same schedule for the
+cell's ``warm_seconds`` and the window's requests follow at once, so
+the timed and the traced window alike open on a server at its steady
+occupancy.  That is neither set-up (``run.py`` stamps ``setup_s``
+before it) nor measured (a trace starts after it).
+
 After the window closes the driver waits for the first token of every
 request already sent, cancels what is still decoding, and keeps a
 sample of the requests the window FINISHED for the check: the
 reference runs once over each prompt with its served tokens, and the
 number compared is the widest gap by which a served token's logit lies
 below the reference's best at its position.
+
+While the window is open a clock reads, ten times a second, how many
+cache blocks live sequences hold (``PagedKVCache.stats()``): what the
+traffic fills of the pool that ``memory_peak_bytes`` pays for.
+
+``perfbench.calibrate --faults 1`` plants the cell's ``faults`` one
+after another under the same server (``FAULTS``: what the engine hands
+a compiled decode step is altered on its way in) and reads what each
+does to the number compared.  The benchmark's own runs plant nothing.
 """
 from __future__ import annotations
 
 import importlib
 import random
+import threading
 import time
 from typing import Dict, List
 
@@ -25,10 +41,45 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import flops, loadgen, weights
+from .. import flops, loadgen, program_trace, weights
 
 MODEL = "bench_gen"
 FIRST_TOKEN_WAIT_S = 60.0
+KV_SAMPLE_S = 0.1
+
+
+def _position_before(tokens, positions, tables):
+    """Every rider decodes one position early: its new K and V land on
+    the token before, which is lost, and its rotary angle is off by
+    one against the prompt's."""
+    return tokens, np.maximum(positions - 1, 0), tables
+
+
+def _first_block_stale(tokens, positions, tables):
+    """Every rider's first 128 tokens are read from block 0, the pool's
+    scratch block, which holds whatever was written there last."""
+    tables = tables.copy()
+    tables[:, 0] = 0
+    return tokens, positions, tables
+
+
+def _tables_rolled(tokens, positions, tables):
+    """Every rider reads and writes the block table of the rider in the
+    slot before it."""
+    return tokens, positions, np.roll(tables, 1, axis=0)
+
+
+# what ``perfbench.calibrate --faults 1`` can plant in a decode step
+FAULTS = {"decode_position_before": _position_before,
+          "decode_first_block_stale": _first_block_stale,
+          "decode_tables_rolled": _tables_rolled}
+
+
+def _planted(step, alter):
+    def call(params, tokens, positions, pages, tables):
+        tokens, positions, tables = alter(tokens, positions, tables)
+        return step(params, tokens, positions, pages, tables)
+    return call
 
 
 class Driver:
@@ -74,30 +125,69 @@ class Driver:
             queue_max=cell["queue_max"],
             default_deadline_ms=cell["deadline_ms"])
         self.srv.add_generator(self.rt)       # compiles every plan cell
+        self.compiled = self._compiles()
+
+    @staticmethod
+    def _compiles() -> Dict[str, int]:
+        from mxnet_tpu import diagnostics
+
+        return {name: st["count"]
+                for name, st in diagnostics.recompile_stats().items()
+                if name.startswith("gen_")}
+
+    def _submit(self, req) -> None:
+        """Send one request; its tokens are stamped on the engine's
+        thread with ``perf_counter()`` as it reads, and moved onto the
+        window's clock once the window has closed."""
+        from mxnet_tpu.serving.errors import Rejected
+
+        def on_token(tok):
+            if tok is not None:
+                req.token_s.append(time.perf_counter())
+                req.tokens.append(tok)
+
+        try:
+            req.handle = self.srv.submit_generation(
+                MODEL, req.prompt, max_new=req.max_new,
+                on_token=on_token, request_id="bench-%d" % req.index)
+        except Rejected as e:
+            req.outcome = "shed:%s" % e.reason
+
+    # -- before the window: a server at its steady occupancy ----------
+    def warm(self, seconds: float) -> None:
+        """Offer the part of the schedule that is due before the window
+        opens; return as it opens.  ``seconds`` is the length the one
+        schedule is made for: the run's, so that a shorter (traced)
+        window sees the beginning of the same traffic."""
+        self.plan = loadgen.schedule(self.cell, self.seed, seconds,
+                                     self.config["vocab_size"])
+        self.opens = time.perf_counter() + float(
+            self.cell.get("warm_seconds", 0.0))
+        loadgen.offer(self.plan, self._submit, 0.0, self.opens, self.spans)
 
     # -- the measured window -----------------------------------------
     def window(self, seconds: float) -> Dict:
-        from mxnet_tpu.serving.errors import Rejected, ServeError
+        from mxnet_tpu.serving.errors import ServeError
 
-        cell, srv = self.cell, self.srv
-        self.plan = plan = loadgen.schedule(cell, self.seed, seconds,
-                                            self.config["vocab_size"])
+        plan, kv = self.plan, self.rt.kv
+        block_bytes = self.cell["block_tokens"] * flops.kv_bytes_per_token(
+            self.config, jnp.dtype(self.config["dtype"]).itemsize)
+        held: List[float] = []
+        closed = threading.Event()
+
+        def sample():
+            # a clock of its own, so that an idle server is read too
+            while not closed.wait(KV_SAMPLE_S):
+                held.append(kv.stats()["blocks_live"] * block_bytes)
+
+        clock = threading.Thread(target=sample, name="bench-kv-clock")
         t0 = time.perf_counter()
-
-        def submit(req):
-            def on_token(tok, req=req):        # the engine's thread
-                if tok is not None:
-                    req.token_s.append(time.perf_counter() - t0)
-                    req.tokens.append(tok)
-
-            try:
-                req.handle = srv.submit_generation(
-                    MODEL, req.prompt, max_new=req.max_new,
-                    on_token=on_token, request_id="bench-%d" % req.index)
-            except Rejected as e:
-                req.outcome = "shed:%s" % e.reason
-
-        loadgen.offer(plan, submit, seconds, t0, self.spans)
+        clock.start()
+        try:
+            loadgen.offer(plan, self._submit, seconds, t0, self.spans)
+        finally:
+            closed.set()
+            clock.join()
         elapsed = time.perf_counter() - t0
         sent = [r for r in plan if r.handle is not None]
         with self.spans("bench.drain"):
@@ -117,34 +207,74 @@ class Driver:
                 except ServeError:
                     pass       # read from ``handle.error`` just below
         for r in sent:
+            # every stream has ended: onto the window's clock
+            r.token_s = [t - t0 for t in r.token_s]
             err = r.handle.error
             if err is None or type(err).__name__ == "Cancelled":
                 r.outcome = "ok"
             else:
                 r.outcome = "error:%s" % type(err).__name__
+        compiled = self._compiles()
+        if compiled != self.compiled:
+            # a run that compiled under traffic measured the compiler
+            raise RuntimeError("plan cells compiled under traffic: %s" % {
+                k: (self.compiled.get(k), v) for k, v in compiled.items()
+                if v != self.compiled.get(k)})
         numbers = loadgen.summarize(plan, seconds,
                                     seconds + FIRST_TOKEN_WAIT_S)
+        gaps = [b - a for r in plan for a, b in zip(r.token_s, r.token_s[1:])
+                if 0.0 <= a and b <= seconds]
         finished = [r for r in plan if r.outcome == "ok"
                     and len(r.tokens) == r.max_new
-                    and r.token_s[-1] <= seconds]
+                    and 0.0 <= r.token_s[-1] <= seconds]
         self.sample = self._draw_sample(finished)
-        self.counters = self._count(plan, seconds, numbers, len(finished))
+        self.counters = dict(
+            self._count(plan, seconds, numbers, len(finished)),
+            kv_live_bytes=held)
         return {
             "t_start": t0, "elapsed_s": elapsed,
             "attempted": numbers["attempted"], "failed": numbers["failed"],
             "metrics": {k: numbers[k] for k in
                         ("serve_tokens_per_s", "tpot_p95_ms")},
             "counters": self.counters,
-            "info": "%d requests due, %d failed, %d tokens and %d gaps in "
-                    "%.1f s; ttft p50 %.1f ms; generator late p90 %.2f ms; "
-                    "%d finished in the window" % (
-                        numbers["attempted"], numbers["failed"],
+            "info": "%d requests due (%d more in %g s of warm-up, the "
+                    "window opened %.3f s after it), %d failed, %d tokens "
+                    "and %d gaps in %.1f s (p50/p90/p95/p99 %s ms); ttft "
+                    "p50/p90 %.3f/%.3f ms; generator late p90 %.2f ms; %d "
+                    "finished in the window; %s; live sequences held "
+                    "p50/max %d/%d bytes of cache (%d readings)" % (
+                        numbers["attempted"],
+                        sum(1 for r in plan if r.due_s < 0.0),
+                        self.cell.get("warm_seconds", 0.0),
+                        t0 - self.opens, numbers["failed"],
                         numbers["tokens_in_window"], numbers["token_gaps"],
-                        seconds, numbers["ttft_p50_ms"],
+                        seconds, "/".join(
+                            "%.3f" % (1e3 * loadgen.percentile(gaps, q))
+                            for q in (0.5, 0.9, 0.95, 0.99)) if gaps
+                        else "-",
+                        numbers["ttft_p50_ms"], numbers["ttft_p90_ms"],
                         loadgen.percentile(numbers["late_ms"], 0.9)
                         if numbers["late_ms"] else float("nan"),
-                        self.counters["finished_in_window"]),
+                        self.counters["finished_in_window"],
+                        self._occupancy(t0, seconds),
+                        loadgen.percentile(held, 0.5) if held else 0,
+                        max(held, default=0), len(held)),
         }
+
+    @staticmethod
+    def _occupancy(t0: float, seconds: float) -> str:
+        """The decode slots' occupancy in the window's first fifth
+        beside the rest's: the proof that the window opened on a warmed
+        server."""
+        from mxnet_tpu.profiler import spans_between
+
+        spans = spans_between(t0, t0 + seconds)
+        fifth = t0 + seconds / 5.0
+        first, rest = (program_trace.slot_occupancy_pct(spans, lo, hi)
+                       for lo, hi in ((t0, fifth), (fifth, t0 + seconds)))
+        return "slots occupied %.1f %% in the first fifth, %.1f %% in " \
+            "the rest" % (float("nan") if first is None else first,
+                          float("nan") if rest is None else rest)
 
     def _draw_sample(self, done) -> List:
         """Of the requests the window finished, a sample drawn from the
@@ -164,15 +294,18 @@ class Driver:
 
         cfg = self.config
         prefilled = [len(r.prompt) for r in plan
-                     if r.token_s and r.token_s[0] <= seconds]
+                     if r.token_s and 0.0 <= r.token_s[0] <= seconds]
         # a decode tick emits token i (i >= 1) of a request after
         # reading the prompt and the i tokens before it
         decode_reads = [len(r.prompt) + i for r in plan
                         for i, t in enumerate(r.token_s)
-                        if i >= 1 and t <= seconds]
+                        if i >= 1 and 0.0 <= t <= seconds]
+        due = {"bench-%d" % r.index for r in plan
+               if loadgen.in_window(r, seconds)}
         queue_ms = [1e3 * rec["phases"]["queue"]
                     for rec in reqtrace.snapshot()["recent"]
-                    if "queue" in rec.get("phases", {})]
+                    if rec.get("id") in due
+                    and "queue" in rec.get("phases", {})]
         prefill_flops = sum(flops.prefill_flops(cfg, n) for n in prefilled)
         decode_flops = sum(flops.transformer_forward_flops(cfg, 1, n)
                            for n in decode_reads)
@@ -190,7 +323,26 @@ class Driver:
             "queue_ms": queue_ms,
             "late_ms": numbers["late_ms"],
             "ttft_p90_ms": numbers["ttft_p90_ms"],
+            "ttft_p50_ms": numbers["ttft_p50_ms"],
         }
+
+    # -- planted faults (``perfbench.calibrate --faults 1`` only) ------
+    def fault_windows(self, seconds: float) -> None:
+        """On the server the program's window has just left: each of
+        the cell's ``faults`` planted in turn under every compiled
+        decode step, the cell's warm-up and a window of ``seconds``,
+        and the window's sample kept for ``calibration``."""
+        sound, self.faulty = dict(self.rt._decode), {}
+        served = self.sample
+        for name in self.cell.get("faults", ()):
+            for key, step in sound.items():
+                self.rt._decode[key] = _planted(step, FAULTS[name])
+            self.warm(seconds)
+            print("%s: %s" % (name, self.window(seconds)["info"]),
+                  flush=True)
+            self.faulty[name] = self.sample
+        self.sample = served
+        self.rt._decode.update(sound)
 
     def release(self) -> None:
         report = self.srv.drain(timeout_s=30)
@@ -211,16 +363,26 @@ class Driver:
     def calibration(self, control: Dict, faults: bool, quantisers: Dict,
                     rebuilt):
         """``(what, numbers, extra)`` for ``perfbench.calibrate``."""
+        def extra(sample):
+            served = [t for _, tokens in sample for t in tokens]
+            return {"requests": len(sample), "tokens": len(served),
+                    "distinct_tokens": len(set(served)),
+                    "repeats_of_the_token_before": sum(
+                        1 for _, tokens in sample
+                        for a, b in zip(tokens, tokens[1:]) if a == b)}
+
         gaps = self.token_gaps(quantisers.get(control.get("reference")))
-        served = [t for _, tokens in self.sample for t in tokens]
-        extra = {"requests": len(self.sample), "tokens": len(served),
-                 "distinct_tokens": len(set(served)),
-                 "repeats_of_the_token_before": sum(
-                     1 for _, tokens in self.sample
-                     for a, b in zip(tokens, tokens[1:]) if a == b)}
-        yield "program", {"token_gap": gaps["served"]}, extra
+        yield "program", {"token_gap": gaps["served"]}, extra(self.sample)
         if "reference" in control:
-            yield "control", {"token_gap": gaps["control"]}, extra
+            yield "control", {"token_gap": gaps["control"]}, \
+                extra(self.sample)
+        if faults:
+            sound = self.sample
+            for name, sample in getattr(self, "faulty", {}).items():
+                self.sample = sample
+                yield name, {"token_gap": self.token_gaps()["served"]}, \
+                    extra(sample)
+            self.sample = sound
 
     def token_gaps(self, quantise=None) -> Dict[str, float]:
         """``served``: the widest gap, over the sample's served tokens,

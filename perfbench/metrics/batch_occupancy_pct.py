@@ -1,13 +1,14 @@
 """Share of the decode slots that held a live sequence, weighted by
 tick time: sum of tick time x ``live`` over sum of tick time x
-``slots``, from the engine's ``mx.tick`` spans inside the window.  No
-cell reports it today (``PERF.md`` §7 row 2)."""
+``slots``, from the engine's ``mx.tick`` spans that start inside the
+window.  A share of slots, counted by the program: a run without a chip
+reads it too."""
 from perfbench import program_trace
 
 
 def read(ctx):
-    ticks = program_trace.spans_in_window(ctx, "mx.tick")
-    full = sum((s.t1 - s.t0) * s.args["slots"] for s in ticks or ())
-    if not full:
+    spans = program_trace.ring_spans(ctx)
+    if spans is None:
         return None
-    return 100.0 * sum((s.t1 - s.t0) * s.args["live"] for s in ticks) / full
+    return program_trace.slot_occupancy_pct(
+        spans, *program_trace.window_on_host(ctx))

@@ -1,10 +1,9 @@
 """90th percentile, over all requests DUE in the window, of first token
 minus the time the request was due; a shed or failed request counts
-with the whole wait.  The serving cell is out of ``BENCHMARK.json``
-(``PERF.md`` §7): at some 70 requests a window this tail differed by up
-to 16 % between two runs of one seed (my chip runs, PR 25), so the cell
-that comes back needs more requests a window, or a median beside it,
-to carry a time to first token end to end."""
+with the whole wait.  A tail over some 37 requests a window: it spread
+by 9 % in two sets of six runs (my chip runs, PR 34; 16 % between two
+runs of one seed in PR 25), so it stands beside ``tpot_p95_ms`` and is
+held to no bound."""
 
 
 def read(ctx):

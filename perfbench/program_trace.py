@@ -126,6 +126,18 @@ def spans_in_window(ctx: Dict, name: str) -> Optional[List]:
     return [s for s in spans if s.name == name and win[0] <= s.t0 <= win[1]]
 
 
+def slot_occupancy_pct(spans: Iterable, lo: float, hi: float
+                       ) -> Optional[float]:
+    """Share of the decode slots that held a live sequence, weighted by
+    tick time, over the engine's ``mx.tick`` spans that start in
+    ``[lo, hi]`` (``perf_counter()`` seconds); None where none does."""
+    ticks = [s for s in spans if s.name == "mx.tick" and lo <= s.t0 <= hi]
+    full = sum((s.t1 - s.t0) * s.args["slots"] for s in ticks)
+    if not full:
+        return None
+    return 100.0 * sum((s.t1 - s.t0) * s.args["live"] for s in ticks) / full
+
+
 def count_spans(ctx: Dict, name: str) -> Optional[int]:
     spans = spans_in_window(ctx, name)
     return None if spans is None else len(spans)

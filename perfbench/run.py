@@ -2,8 +2,10 @@
 
     python -m perfbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Load, warm up, measure for ``--seconds``, check what the timed path
-produced against the plain reference, print one JSON line
+Load, warm up (``setup_s`` ends here), bring a server to its steady
+occupancy where the driver has a ``warm``, measure for ``--seconds``,
+check what the timed path produced against the plain reference, print
+one JSON line
 (``correct``, ``attempted``, ``failed``, ``metrics``, ``device``,
 ``breakdown`` when traced, ``compared`` last), exit 0.  Without a TPU,
 or with fewer chips than the cell asks for, it names what it found and
@@ -140,6 +142,7 @@ def main(argv=None, *, manifest_path: Optional[str] = None,
         "perfbench.drivers." + config["driver"]).Driver(
         cell, config, args.seed, devices[:cell["chips"]], spans)
     driver.setup()
+    setup_s = time.perf_counter() - T_PROCESS
 
     seconds = args.seconds
     trace_dir = os.path.join(ROOT, ".perfbench_out", "trace")
@@ -147,12 +150,18 @@ def main(argv=None, *, manifest_path: Optional[str] = None,
         # a trace of the whole window would be large and is not needed:
         # the cell says how long a traced window has to be
         seconds = min(seconds, float(cell["trace_seconds"]))
+    # a driver whose window has to open on a system in its steady state
+    # (a server at its occupancy) brings it there now: after set-up,
+    # before the window and before any trace.  It plans for the whole
+    # run, so that a traced window is the timed window's beginning
+    if hasattr(driver, "warm"):
+        driver.warm(args.seconds)
+    if args.trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
         spans.annotate = jax.profiler.TraceAnnotation
         jax.profiler.start_trace(trace_dir,
                                  profiler_options=_trace_options())
     spans.clear()
-    setup_s = time.perf_counter() - T_PROCESS
     with spans(trace_reduce.WINDOW):
         result = driver.window(seconds)
     trace = None

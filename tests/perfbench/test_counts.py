@@ -203,6 +203,90 @@ def test_summarize_times_from_due_and_counts_the_missing():
     assert got["late_ms"] == pytest.approx([10.0] * 4)
 
 
+def test_a_warm_up_is_the_same_schedule_begun_before_the_window():
+    warm = dict(TRAFFIC, warm_seconds=10.0)
+    a = loadgen.schedule(warm, 7, 40.0, 50304)
+    assert len(a) == 100                     # 2/s over 10 + 40 s
+    assert a[0].due_s < -9.0 and a[-1].due_s == pytest.approx(40.0,
+                                                              rel=0.05)
+    # Poisson: about 20 of the 100 fall into the 10 s before the window
+    assert 10 <= sum(1 for r in a if r.due_s < 0.0) <= 30
+    # the same work for every seed, the warm-up's too
+    b = loadgen.schedule(warm, 11, 40.0, 50304)
+    assert [(r.due_s, len(r.prompt), r.max_new) for r in a] \
+        == [(r.due_s, len(r.prompt), r.max_new) for r in b]
+
+
+class _Quiet:
+    def __call__(self, name):
+        import contextlib
+        return contextlib.nullcontext()
+
+
+def test_offer_sends_the_warm_up_first_and_each_request_once():
+    import time
+
+    plan = loadgen.schedule(dict(TRAFFIC, rate=100.0, warm_seconds=0.1),
+                            1, 0.2, 1000)
+    sent = []
+    opens = time.perf_counter() + 0.1
+    loadgen.offer(plan, sent.append, 0.0, opens, _Quiet())
+    assert time.perf_counter() >= opens
+    assert sent and all(r.due_s < 0.0 for r in sent)
+    warm = len(sent)
+    assert warm == sum(1 for r in plan if r.due_s < 0.0)
+    loadgen.offer(plan, sent.append, 0.2, time.perf_counter(), _Quiet())
+    assert len(sent) == len({r.index for r in sent})
+    assert all(0.0 <= r.due_s < 0.2 for r in sent[warm:])
+    assert len(sent) == sum(1 for r in plan if r.due_s < 0.2)
+
+
+@pytest.mark.parametrize("what", ["due_before", "straddles", "warm_failed"])
+def test_summarize_with_a_warm_up(what):
+    """A request due before the window is in no time to first token and
+    not in ``attempted``; one that straddles the opening gives the
+    window only its tokens and gaps inside; a warm-up request that
+    failed is a failed operation."""
+    plan = loadgen.schedule(TRAFFIC, 1, 10.0, 1000)[:3]
+    for r, due in zip(plan, (-2.0, -0.5, 1.0)):
+        r.due_s, r.sent_s = due, due + 0.01
+    plan[0].token_s = [-1.9, -1.0, -0.2]      # all before the opening
+    plan[1].token_s = [-0.3, -0.1, 0.2, 0.5, 0.6]
+    plan[2].token_s = [1.4, 1.5]
+    if what == "warm_failed":
+        plan[0].outcome = "error:ExecutorFailure"
+    got = loadgen.summarize(plan, 10.0, 70.0)
+    if what == "due_before":
+        assert got["attempted"] == 1 and got["failed"] == 0
+        assert got["ttft_p50_ms"] == pytest.approx(400.0)
+        assert got["ttft_p90_ms"] == pytest.approx(400.0)
+        assert got["late_ms"] == pytest.approx([10.0])
+    elif what == "straddles":
+        # 3 of the straddler's 5 tokens, 2 of its 4 gaps (the gap from
+        # -0.1 to 0.2 began before the opening), and the third
+        # request's 2 tokens and 1 gap
+        assert got["tokens_in_window"] == 5 and got["token_gaps"] == 3
+        assert got["serve_tokens_per_s"] == pytest.approx(0.5)
+        assert got["tpot_p95_ms"] == pytest.approx(300.0)
+    else:
+        assert got["attempted"] == 2 and got["failed"] == 1
+
+
+@pytest.mark.parametrize("first, second, waiting, median, held", [
+    # PR 34's sweep at 50 s on the chip (PERF.md section 6): 0.8, 0.9,
+    # 0.95 (a backlog from the warm-up that stands), 1.0 and 1.1 a second
+    (679.1, 77.3, 0, 77.1, True), (162.3, 292.2, 0, 91.6, True),
+    (2801.2, 1448.9, 0, 2205.7, False), (68.5, 4803.5, 8, 82.5, False),
+    (631.4, 8516.6, 15, 1924.8, False),
+    # the first tokens keep up, and requests pile up all the same
+    (100.0, 120.0, 4, 110.0, False)])
+def test_the_sweeps_rule_for_a_sustained_rate(first, second, waiting,
+                                              median, held):
+    from perfbench import sweep
+
+    assert sweep.sustained(first, second, waiting, median) is held
+
+
 def test_percentile_is_a_value_of_the_sample():
     values = list(range(1, 101))
     assert loadgen.percentile(values, 0.90) == 90

@@ -39,14 +39,18 @@ def _call(capsys, manifest_path, cell, trace, seed, require_chip):
 
 TRAIN_CELLS = ["toy_image", "toy_lm"]
 CELLS = TRAIN_CELLS + ["toy_serve"]
+# what each toy cell reports is what the committed manifest says of the
+# cell it stands for
 END_TO_END = {
-    "toy_image": {"train_step_ms", "setup_s"},
-    "toy_lm": {"train_step_ms", "setup_s"},
-    "toy_serve": {"serve_tokens_per_s", "tpot_p95_ms", "setup_s"},
-}
+    cell: {m["name"] for m in toy_manifest.build()["end_to_end"]
+           if cell in m.get("workloads", [cell])} for cell in CELLS}
+# the per-layer metrics that a run without a chip reads: the host's
+# clock, and what the program counts
 HOST_METRICS = {
     "toy_image": {"dispatch_ms.train"}, "toy_lm": {"dispatch_ms.train"},
-    "toy_serve": {"queue_ms_p90", "loadgen_late_p90_ms", "ttft_p90_ms"},
+    "toy_serve": {"queue_ms_p90", "loadgen_late_p90_ms", "ttft_p90_ms",
+                  "ttft_p50_ms", "batch_occupancy_pct",
+                  "kv_live_bytes_p50"},
 }
 
 
@@ -75,6 +79,50 @@ def test_traced_run_writes_no_cpu_number_under_a_device_name(_run, cell):
     assert set(line["metrics"]) == HOST_METRICS[cell]
     assert "busy_s" not in line["device"]
     assert line["breakdown"] == {"device_ops": [], "idle_gaps": []}
+
+
+def test_end_to_end_metrics_are_the_manifests():
+    assert {"train_step_ms", "setup_s"} == END_TO_END["toy_lm"] \
+        == END_TO_END["toy_image"]
+    assert {"serve_tokens_per_s", "tpot_p95_ms", "setup_s"} \
+        == END_TO_END["toy_serve"]
+
+
+def test_the_serving_window_opens_on_a_warmed_server(_run):
+    """The toy cell's 0.3 s of warm-up: its requests are sent, are no
+    part of ``attempted``, and the window's first fifth finds slots
+    occupied; the cache's live bytes are whole blocks."""
+    rc, out, _ = _run("toy_serve", 1, seed=23)
+    assert rc == 0
+    line = json.loads(out[-1])
+    info = next(ln for ln in out if "of warm-up" in ln)
+    # 40 a second: 20 due in the window's 0.5 s, 12 in the warm-up
+    assert line["attempted"] in range(18, 23)
+    assert "more in 0.3 s of warm-up" in info
+    assert int(info.split("(")[1].split()[0]) in range(10, 15)
+    first_fifth = float(info.split("slots occupied ")[1].split()[0])
+    assert first_fifth > 0.0
+    assert 0.0 < line["metrics"]["batch_occupancy_pct"]["value"] <= 100.0
+    with open(os.path.join(TOY, "workloads", "toy_serve.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(TOY, "configs", "dense_toy_serve.json")) as f:
+        cfg = json.load(f)
+    block = cell["block_tokens"] * 2 * cfg["num_hidden_layers"] \
+        * cfg["hidden_size"] * 4                   # float32 cache
+    held = line["metrics"]["kv_live_bytes_p50"]["value"]
+    # whole blocks; 0 where the median send found the toy server idle
+    assert held >= 0 and held % block == 0
+
+
+def test_a_plan_cell_compiled_under_traffic_fails_the_run(_run,
+                                                          monkeypatch):
+    from perfbench.drivers import serve_lm
+
+    counts = iter([{"gen_decode:x": 1}, {"gen_decode:x": 2}])
+    monkeypatch.setattr(serve_lm.Driver, "_compiles",
+                        staticmethod(lambda: next(counts)))
+    with pytest.raises(RuntimeError, match="compiled under traffic"):
+        _run("toy_serve", 0, seed=29)
 
 
 def test_without_a_chip_there_is_no_result(_run):
@@ -156,3 +204,41 @@ def test_the_control_comes_out_not_correct(capsys, manifest_path, cell):
     by_what = {ln["what"]: ln for ln in lines}
     assert all(by_what["program"][k] <= v for k, v in limits.items())
     assert any(by_what["control"][k] > v for k, v in limits.items())
+
+
+SERVE_FAULTS = ["decode_position_before", "decode_first_block_stale",
+                "decode_tables_rolled"]
+
+
+@pytest.fixture(scope="module")
+def _planted(tmp_path_factory):
+    """``perfbench.calibrate --faults 1`` on the toy serving cell, once:
+    its lines by what they read."""
+    import contextlib
+    import io
+
+    from perfbench import calibrate
+
+    path = toy_manifest.write(tmp_path_factory.mktemp("planted"))
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        rc = calibrate.main(["--workload", "toy_serve", "--seeds", "6",
+                             "--faults", "1"],
+                            manifest_path=path, data_root=TOY)
+    assert rc == 0
+    return {ln["what"]: ln for ln in map(json.loads, (
+        ln for ln in out.getvalue().splitlines() if ln.startswith("{")))}
+
+
+@pytest.mark.parametrize("fault", SERVE_FAULTS)
+def test_a_planted_cache_fault_fails_the_toy_cells_number(_planted, fault):
+    """What the engine hands a compiled decode step is altered on its
+    way in (a position, a block, a table row), under the same server,
+    after the program's own window, which stays within the limit."""
+    with open(os.path.join(TOY, "workloads", "toy_serve.json")) as f:
+        cell = json.load(f)
+    assert fault in cell["faults"]
+    limit = cell["limits"]["token_gap"]
+    assert _planted["program"]["token_gap"] <= limit
+    assert _planted[fault]["token_gap"] > limit
+
