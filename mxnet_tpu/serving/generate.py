@@ -20,10 +20,15 @@ recorded avals against the declared plan).
 The cache is paged (kvcache.py): a sequence holds a LIST of fixed-size
 token blocks, its block table gathered INSIDE the compiled decode step
 (``transformer.model.apply_decode``), so slot churn never copies or
-compacts cache memory.  Continuous batching rides on top: a finished
-(or cancelled, or evicted) sequence's slot and blocks are reclaimed on
-the NEXT decode tick and refilled from the queue without draining the
-co-riding sequences.
+compacts cache memory.  Nor does a step: the K and V pools are DONATED
+to both compiled steps, which write their new rows into the buffers
+they were handed and return them (no copy of a pool per tick).  The
+dict that went into a step is dead afterwards; a step that fails after
+it consumed the pools fails every live sequence and the pools are made
+again (``GenerationEngine._recover_pools``).  Continuous batching
+rides on top: a finished (or cancelled, or evicted) sequence's slot
+and blocks are reclaimed on the NEXT decode tick and refilled from the
+queue without draining the co-riding sequences.
 
 Numerics contract, pinned by tests/test_zz_generate_e2e.py: greedy
 decode
@@ -246,7 +251,28 @@ class GenerationRuntime:
                 params, tokens, positions, cfg, pages=pages,
                 block_tables=block_tables, block_tokens=bt)
 
-        return jax.jit(prefill_fn), jax.jit(decode_fn)
+        # ``pages`` (argument 3) is donated: each step scatters its
+        # rows into the pools it was handed, where an undonated pool
+        # would be copied whole before every write
+        return (jax.jit(prefill_fn, donate_argnums=(3,)),
+                jax.jit(decode_fn, donate_argnums=(3,)))
+
+    def _stamp_donation(self, steps_args) -> int:
+        """``kv.pools_donated`` of ``kv.pools``: the pools that every
+        one of the compiled steps takes donated (read off each traced
+        step's ``args_info``, as ``analysis.check_donation`` does; the
+        trace is the one the step's first call reuses), over the pools
+        there are.  A step that is no jit donates nothing.  Returns
+        the former."""
+        pools = set(self.kv.pages)
+        donated = set(pools)
+        for step, args in steps_args:
+            trace = getattr(step, "trace", None)
+            info = trace(*args).args_info[0][3] if trace else {}
+            donated &= {k for k, a in info.items() if a.donated}
+        _profiler.record_counter("kv.pools_donated", len(donated))
+        _profiler.record_counter("kv.pools", len(pools))
+        return len(donated)
 
     def compile(self, warmup: bool = True) -> Dict[str, float]:
         """Compile + warm every cell of BOTH plans, one instrumented
@@ -268,52 +294,48 @@ class GenerationRuntime:
             meta = {"model": self.name,
                     "block_tokens": bt,
                     "decode_plan": [list(c) for c in self.decode_plan]}
-            for bb, tb in self.prefill_plan:
-                key = (bb, tb)
-                if key in self._prefill:
-                    continue
-                nm = "gen_prefill:%s:v%d:%dx%d" % (self.name,
-                                                   self.version, bb, tb)
-                w = _diag.instrument_jit(
-                    nm, pjit, meta=dict(meta, kind="generate_prefill"))
-                t0 = time.perf_counter()
-                if warmup:
-                    out, pages = w(
-                        self._params,
-                        np.zeros((bb, tb), dtype=np.int32),
-                        np.zeros((bb,), dtype=np.int32),
-                        self.kv.pages,
-                        np.zeros((bb, tb // bt), dtype=np.int32))
-                    jax.block_until_ready(out)  # mxlint: disable=MXL004
-                    self.kv.pages = pages
-                self._compile_ms[nm] = (time.perf_counter() - t0) * 1e3
-                self._prefill[key] = w
-                self._feed_compile_metrics(self._compile_ms[nm])
-            for bb, lb in self.decode_plan:
-                key = (bb, lb)
-                if key in self._decode:
-                    continue
-                nm = "gen_decode:%s:v%d:%dx%d" % (self.name,
-                                                  self.version, bb, lb)
-                w = _diag.instrument_jit(
-                    nm, djit, meta=dict(meta, kind="generate_decode"))
-                t0 = time.perf_counter()
-                if warmup:
-                    out, pages = w(
-                        self._params,
-                        np.zeros((bb,), dtype=np.int32),
-                        np.zeros((bb,), dtype=np.int32),
-                        self.kv.pages,
-                        np.zeros((bb, lb // bt), dtype=np.int32))
-                    jax.block_until_ready(out)  # mxlint: disable=MXL004
-                    self.kv.pages = pages
-                self._compile_ms[nm] = (time.perf_counter() - t0) * 1e3
-                self._decode[key] = w
-                self._feed_compile_metrics(self._compile_ms[nm])
+
+            def ints(*shape):
+                return np.zeros(shape, dtype=np.int32)
+
+            # a cell's warm-up arguments, made when the cell is warmed:
+            # the pools are whatever the step before gave back
+            def prefill_args(bb, tb):
+                return (self._params, ints(bb, tb), ints(bb),
+                        self.kv.pages, ints(bb, tb // bt))
+
+            def decode_args(bb, lb):
+                return (self._params, ints(bb), ints(bb),
+                        self.kv.pages, ints(bb, lb // bt))
+
+            donated = self._stamp_donation(
+                [(pjit, prefill_args(*self.prefill_plan[0])),
+                 (djit, decode_args(*self.decode_plan[0]))])
+            for kind, plan, cells, fn, args_of in (
+                    ("prefill", self.prefill_plan, self._prefill, pjit,
+                     prefill_args),
+                    ("decode", self.decode_plan, self._decode, djit,
+                     decode_args)):
+                for key in plan:
+                    if key in cells:
+                        continue
+                    nm = "gen_%s:%s:v%d:%dx%d" % (
+                        (kind, self.name, self.version) + key)
+                    w = _diag.instrument_jit(
+                        nm, fn, meta=dict(meta, kind="generate_" + kind))
+                    t0 = time.perf_counter()
+                    if warmup:
+                        out, pages = w(*args_of(*key))
+                        jax.block_until_ready(out)  # mxlint: disable=MXL004
+                        self.kv.pages = pages
+                    self._compile_ms[nm] = (time.perf_counter() - t0) * 1e3
+                    cells[key] = w
+                    self._feed_compile_metrics(self._compile_ms[nm])
             _log.info(
                 "serving: compiled generator %r — %d prefill + %d "
-                "decode plan cells (warmup=%s)", self.name,
-                len(self._prefill), len(self._decode), warmup)
+                "decode plan cells (warmup=%s), %d of %d KV pools "
+                "donated", self.name, len(self._prefill),
+                len(self._decode), warmup, donated, len(self.kv.pages))
             return dict(self._compile_ms)
 
     def _feed_compile_metrics(self, dur_ms: float) -> None:
@@ -560,8 +582,11 @@ class GenerationEngine:
                     "tokens": int(plens[:len(group)].sum())}):
                 logits, pages = w(rt._params, tokens, plens,
                                   self.kv.pages, tables)
-                self.kv.pages = pages
                 first = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+                # kept once the logits are read: a run that fails on
+                # the device raises there, with pools as dead as those
+                # it was handed
+                self.kv.pages = pages
         except Exception as e:
             err = e if isinstance(e, ExecutorFailure) else \
                 ExecutorFailure("prefill for %r failed: %r"
@@ -570,6 +595,7 @@ class GenerationEngine:
                 self.kv.free(seq_id)
                 self._finish(req, "error", err)
                 rep["outcomes"].append((req, "error", err))
+            self._recover_pools(rep, err)
             raise err
         rep["ticked"] = True
         prefill_dur = time.monotonic() - prefill_t0
@@ -651,12 +677,14 @@ class GenerationEngine:
                     "live": len(riders), "slots": rt.slots}):
                 logits, pages = w(rt._params, tokens, positions,
                                   self.kv.pages, tables)
-                self.kv.pages = pages
                 nxt = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+                self.kv.pages = pages  # once read: see _admit
         except Exception as e:
-            raise self._fail_riders(rep, ExecutorFailure(
+            err = self._fail_riders(rep, ExecutorFailure(
                 "decode tick for %r (bucket %dx%d) failed: %r"
                 % (rt.name, bb, lb, e)))
+            self._recover_pools(rep, err)
+            raise err
         rep["ticked"] = True
         if trace_on:
             tick_dur = time.monotonic() - tick_t0
@@ -695,6 +723,25 @@ class GenerationEngine:
             rep["outcomes"].append((s.req, "error", err))
         self.active = []
         return err
+
+    def _recover_pools(self, rep, err: ExecutorFailure) -> None:
+        """After any exception from a compiled step.  The pools are
+        donated, so a step that raised once it had been dispatched has
+        consumed them (``kv.pools_lost()``: an array of ``kv.pages``
+        is deleted) and every live sequence's history with them: fail
+        every rider, the prefill path's too, free their blocks and
+        make the pools again, so the breaker's next probe finds a
+        cache that works.  A step that raised before that (the chaos
+        injections, a tracing or argument error) leaves the pools
+        whole, and the riders keep streaming."""
+        if not self.kv.pools_lost():
+            return
+        _log.warning(
+            "serving: a failed step of %r consumed the KV pools — "
+            "failing %d live sequence(s) and rebuilding the pools",
+            self.rt.name, len(self.active))
+        self._fail_riders(rep, err)
+        self.kv.rebuild_pools()
 
 
 def demo_generation_runtime(name: str = "gen", seed: int = 0, *,

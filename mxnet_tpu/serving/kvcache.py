@@ -15,9 +15,15 @@ every write from a padded position or an inactive slot there (see
 branches on liveness and a freed slot costs nothing to keep riding.
 
 The allocator is HOST state (block tables, free list, cursors); the
-pools themselves are device arrays threaded functionally through the
-compiled prefill/decode steps (``engine.pages`` is replaced by each
-step's returned ``new_pages``).
+pools themselves are device arrays DONATED to the compiled
+prefill/decode steps: a step writes its new rows into the buffers it
+was handed and returns them, and the engine replaces ``kv.pages`` with
+what came back.  The dict that went into a step is dead once the call
+has been dispatched (its arrays read ``is_deleted()``), so nobody may
+keep a reference to ``kv.pages`` or to one of its arrays across a
+step.  A step that raises after it consumed the pools has taken every
+live sequence's history with it: :meth:`PagedKVCache.pools_lost` says
+so and :meth:`PagedKVCache.rebuild_pools` makes them again.
 """
 from __future__ import annotations
 
@@ -40,8 +46,6 @@ class PagedKVCache:
     def __init__(self, *, n_layers: int, n_heads: int, head_dim: int,
                  num_blocks: int, block_tokens: int,
                  dtype: str = "float32", name: str = "gen"):
-        import jax.numpy as jnp
-
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "garbage block)")
@@ -58,15 +62,42 @@ class PagedKVCache:
         #: seq_id -> tokens actually written (fragmentation accounting)
         self._lengths: Dict[str, int] = {}
         self.evictions = 0
-        shape = (self.num_blocks, self.block_tokens, int(n_heads),
-                 int(head_dim))
-        #: device pools, threaded functionally through the compiled
-        #: steps — the engine replaces this dict with each step's
-        #: returned new_pages
-        self.pages = {}
-        for i in range(self.n_layers):
-            self.pages["k%d" % i] = jnp.zeros(shape, dtype=dtype)
-            self.pages["v%d" % i] = jnp.zeros(shape, dtype=dtype)
+        self.pool_rebuilds = 0
+        self._pool_shape = (self.num_blocks, self.block_tokens,
+                            int(n_heads), int(head_dim))
+        self._pool_dtype = dtype
+        #: device pools, donated to the compiled steps: the engine
+        #: replaces this dict with what each step returns, and the
+        #: dict that went in is dead — keep no reference to it or to
+        #: its arrays across a step
+        self.pages = self._zeroed_pools()
+        (self._device,) = self.pages["k0"].devices()
+
+    def _zeroed_pools(self) -> Dict:
+        import jax.numpy as jnp
+
+        return {"%s%d" % (kv, i): jnp.zeros(self._pool_shape,
+                                            dtype=self._pool_dtype)
+                for i in range(self.n_layers) for kv in "kv"}
+
+    def pools_lost(self) -> bool:
+        """Whether a step consumed the donated pools and gave none
+        back: some array of ``pages`` is deleted."""
+        # the host stub's pools are numpy arrays: never donated
+        return any(a.is_deleted() for a in self.pages.values()
+                   if hasattr(a, "is_deleted"))
+
+    def rebuild_pools(self) -> None:
+        """Zeroed pools again, with the shape, dtype and placement of
+        ``__init__``.  The block tables still name blocks whose rows
+        are gone: the caller fails and frees every live sequence."""
+        import jax
+
+        # placed by the default device as __init__'s were, and so not
+        # committed: a committed pool would compile every plan cell anew
+        with jax.default_device(self._device):
+            self.pages = self._zeroed_pools()
+        self.pool_rebuilds += 1
 
     # -- allocation ----------------------------------------------------
     def _blocks_for(self, n_tokens: int) -> int:
@@ -195,5 +226,12 @@ class PagedKVCache:
                 labels=lab)
             if st["evictions"] > c.value:
                 c.inc(st["evictions"] - c.value)
+            c = _diag.metrics.counter(
+                "mxnet_serve_kv_pool_rebuilds_total",
+                help="times the KV pools were made again after a "
+                     "failed step consumed them",
+                labels=lab)
+            if self.pool_rebuilds > c.value:
+                c.inc(self.pool_rebuilds - c.value)
         except Exception:
             pass

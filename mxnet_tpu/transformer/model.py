@@ -735,6 +735,76 @@ def gather_kv(pages, block_tables, layer):
             v.reshape((b, w * bt) + v.shape[3:]))
 
 
+def _prefill_layer(h, k_pool, v_pool, w, block_tables, pos2, valid, mask,
+                   *, cfg, block_tokens):
+    """One block of :func:`apply_prefill`: ``(h, k_pool, v_pool)`` in,
+    the same out, the prompt's roped K and raw V scattered into the
+    layer's two pools."""
+    import jax
+
+    b, t = pos2.shape
+    q, k, v = _qkv(h, w["attn_norm"], w["wqkv"],
+                   (b, t, cfg.n_heads, cfg.head_dim), pos2[0], cfg)
+    k_pool = _scatter_tokens(k_pool, k, block_tables, pos2, block_tokens,
+                             valid=valid)
+    v_pool = _scatter_tokens(v_pool, v, block_tables, pos2, block_tokens,
+                             valid=valid)
+    with jax.named_scope("attn"):
+        o = _masked_attn(q, k, v, mask)
+    h = h + _attn_out(o, w["wo"], (b, t, cfg.d_model))
+    h = h + _mlp(h, w["mlp_norm"], w["w1"], w["w2"], cfg)
+    return h, k_pool, v_pool
+
+
+def _decode_layer(h, k_pool, v_pool, w, block_tables, pos2, mask, *, cfg,
+                  block_tokens):
+    """One block of :func:`apply_decode`: rope q/k at the cursor,
+    scatter k/v into the layer's pools, THEN gather the history
+    through the block tables and attend under the length mask."""
+    import jax
+
+    b = pos2.shape[0]
+    q, k, v = _qkv(h, w["attn_norm"], w["wqkv"],
+                   (b, 1, cfg.n_heads, cfg.head_dim), pos2, cfg)
+    k_pool = _scatter_tokens(k_pool, k, block_tables, pos2, block_tokens)
+    v_pool = _scatter_tokens(v_pool, v, block_tables, pos2, block_tokens)
+    kc, vc = gather_kv({"k0": k_pool, "v0": v_pool}, block_tables, 0)
+    with jax.named_scope("attn"):
+        o = _masked_attn(q, kc, vc, mask)
+    h = h + _attn_out(o, w["wo"], (b, 1, cfg.d_model))
+    h = h + _mlp(h, w["mlp_norm"], w["w1"], w["w2"], cfg)
+    return h, k_pool, v_pool
+
+
+@functools.lru_cache(maxsize=None)
+def _traced_once(layer):
+    """``layer`` under ``jax.jit``: every block of a generation forward
+    has one operand signature, so its Python runs once a traced step
+    and the step's module holds the block once, called at every layer
+    (XLA inlines the calls).  Unrolled, tracing and lowering 24 blocks
+    anew for each of a server's 30 plan cells was most of its
+    set-up."""
+    import jax
+
+    return jax.jit(layer, static_argnames=("cfg", "block_tokens"))
+
+
+def _through_layers(layer, h, params, pages, cfg, block_tokens, *operands):
+    """``h`` through every block, each layer's pools replaced by what
+    its block returns; -> (h, new_pages)."""
+    import jax
+
+    layer = _traced_once(layer)
+    new_pages = dict(pages)
+    with jax.named_scope("layers"):
+        for i in range(cfg.n_layers):
+            h, new_pages["k%d" % i], new_pages["v%d" % i] = layer(
+                h, pages["k%d" % i], pages["v%d" % i],
+                _layer_params(params, "blk%d." % i), *operands, cfg=cfg,
+                block_tokens=int(block_tokens))
+    return h, new_pages
+
+
 def apply_prefill(params, tokens, prompt_lens, cfg: TransformerConfig,
                   *, pages, block_tables, block_tokens):
     """Prefill forward: right-padded prompts ``tokens`` (B, T) with
@@ -755,29 +825,15 @@ def apply_prefill(params, tokens, prompt_lens, cfg: TransformerConfig,
             "is not written")
     compute = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
-    positions = jnp.arange(t)
-    pos2 = jnp.broadcast_to(positions[None, :], (b, t))
+    pos2 = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
     valid = pos2 < prompt_lens[:, None]
     causal = jnp.tril(jnp.ones((t, t), dtype=bool))
     mask = jnp.broadcast_to(causal[None], (b, t, t))
     with jax.named_scope("embed"):
         h = params["embed"].astype(compute)[tokens]
-    new_pages = dict(pages)
-    shape = (b, t, cfg.n_heads, cfg.head_dim)
-    for i in range(cfg.n_layers):
-        p = "blk%d." % i
-        with jax.named_scope("layer%02d" % i):
-            q, k, v = _qkv(h, params[p + "attn_norm"], params[p + "wqkv"],
-                           shape, positions, cfg)
-            for nm, val in (("k%d" % i, k), ("v%d" % i, v)):
-                new_pages[nm] = _scatter_tokens(
-                    new_pages[nm], val, block_tables, pos2, block_tokens,
-                    valid=valid)
-            with jax.named_scope("attn"):
-                o = _masked_attn(q, k, v, mask)
-            h = h + _attn_out(o, params[p + "wo"], (b, t, cfg.d_model))
-            h = h + _mlp(h, params[p + "mlp_norm"], params[p + "w1"],
-                         params[p + "w2"], cfg)
+    h, new_pages = _through_layers(
+        _prefill_layer, h, params, pages, cfg, block_tokens, block_tables,
+        pos2, valid, mask)
     h = _final_norm(h, params, cfg)
     last = h[jnp.arange(b), jnp.clip(prompt_lens - 1, 0, t - 1)]
     return _logits(last, params, cfg, "bd,vd->bv"), new_pages
@@ -810,21 +866,8 @@ def apply_decode(params, tokens, positions, cfg: TransformerConfig, *,
     mask = jnp.broadcast_to(mask, (b, 1, span))
     with jax.named_scope("embed"):
         h = params["embed"].astype(compute)[tokens][:, None, :]
-    new_pages = dict(pages)
-    shape = (b, 1, cfg.n_heads, cfg.head_dim)
-    for i in range(cfg.n_layers):
-        p = "blk%d." % i
-        with jax.named_scope("layer%02d" % i):
-            q, k, v = _qkv(h, params[p + "attn_norm"], params[p + "wqkv"],
-                           shape, pos2, cfg)
-            for nm, val in (("k%d" % i, k), ("v%d" % i, v)):
-                new_pages[nm] = _scatter_tokens(
-                    new_pages[nm], val, block_tables, pos2, block_tokens)
-            kc, vc = gather_kv(new_pages, block_tables, i)
-            with jax.named_scope("attn"):
-                o = _masked_attn(q, kc, vc, mask)
-            h = h + _attn_out(o, params[p + "wo"], (b, 1, cfg.d_model))
-            h = h + _mlp(h, params[p + "mlp_norm"], params[p + "w1"],
-                         params[p + "w2"], cfg)
+    h, new_pages = _through_layers(
+        _decode_layer, h, params, pages, cfg, block_tokens, block_tables,
+        pos2, mask)
     h = _final_norm(h, params, cfg)
     return _logits(h[:, 0], params, cfg, "bd,vd->bv"), new_pages

@@ -189,3 +189,72 @@ def test_stream_mixing_is_lowered_once_whatever_the_depth(one_chip,
     one, three = bodies(1), bodies(3)
     assert one[:2] == three[:2] and one[0] >= 5, (one, three)
     assert three[2] == 3 * one[2] > 0, (one, three)   # the sites call them
+
+
+def _served_step(kind, layers, one_chip):
+    """The runtime's own compiled step (``GenerationRuntime._jit_fns``,
+    pools donated) of the served configuration's widths at ``layers``
+    layers, with its arguments as shapes on the described chip: the
+    largest plan cell of its kind."""
+    import types
+
+    from mxnet_tpu import serving
+    from mxnet_tpu.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig(vocab_size=50304, n_layers=layers, d_model=2048,
+                            n_heads=16, d_ff=8192, dtype="bfloat16",
+                            param_dtype="bfloat16")
+    prefill, decode = serving.GenerationRuntime._jit_fns(
+        types.SimpleNamespace(cfg=cfg, block_tokens=128))
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pages = {"%s%d" % (kv, i): spec((161, 128, 16, 128), jnp.bfloat16)
+             for i in range(layers) for kv in "kv"}
+    if kind == "prefill":
+        return prefill, (params, spec((1, 1536)), spec((1,)), pages,
+                         spec((1, 12)))
+    return decode, (params, spec((10,)), spec((10,)), pages,
+                    spec((10, 16)))
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_served_step_writes_the_pools_in_place(one_chip, no_cache, kind):
+    """The chip's compiler aliases every donated K and V pool (84.4 MB
+    each at the cell's 161 blocks) to its own output and holds no copy
+    of a pool's shape: undonated, every call copied each pool whole
+    before it scattered a row a rider into it."""
+    import re
+
+    step, args = _served_step(kind, 2, one_chip)
+    with jax.enable_x64(False):
+        text = step.lower(*args).compile().as_text()
+    head = next(ln for ln in text.splitlines() if ln.startswith("HloModule"))
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
+    assert aliased and len(re.findall(r"-alias\)", aliased.group(1))) == 4
+    assert not re.findall(
+        r"= bf16\[(?:161,128,16,128|20608,16,128)\]\S* copy(?:-start)?\(",
+        text)
+
+
+def test_served_step_lowers_one_block_whatever_the_depth(one_chip):
+    """The guard of the server's set-up: every plan cell is traced and
+    lowered on every start.  All layers have one operand signature, so
+    the lowered decode step of two layers and of four hold the block
+    once, called at every layer.  A count, not a time."""
+    import re
+
+    def lowered(layers):
+        step, args = _served_step("decode", layers, one_chip)
+        with jax.enable_x64(False):
+            text = step.lower(*args).as_text()
+        return (len(re.findall(r"func\.func ", text)),
+                len(re.findall(r"call @_decode_layer", text)))
+
+    (two_funcs, two_calls), (four_funcs, four_calls) = lowered(2), lowered(4)
+    assert two_funcs == four_funcs, (two_funcs, four_funcs)
+    assert (two_calls, four_calls) == (2, 4)

@@ -109,3 +109,24 @@ def test_stub_engine_greedy_matches_reference():
         assert r.wait(0.1)["tokens"] == serving.stub_greedy_reference(
             p, 6)
     assert rt.kv.stats()["blocks_live"] == 0
+
+
+def test_stub_runtime_donates_nothing():
+    # the stub's cells are numpy functions, no jit: compile() counts
+    # none of its pools as donated, and no failure can lose them
+    from mxnet_tpu import profiler
+
+    rt = serving.StubGenerationRuntime(
+        "gen_stub_d", slots=1, max_prompt=16, max_context=16,
+        block_tokens=16, max_new=2, prefill_batch=1)
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    try:
+        rt.compile(warmup=True)
+    finally:
+        profiler.set_state("stop")
+    stamped = profiler.summary()["counters"]["counter"]
+    profiler.dumps(reset=True)
+    assert stamped["kv.pools_donated"]["max"] == 0
+    assert stamped["kv.pools"]["max"] == 2
+    assert not rt.kv.pools_lost()

@@ -5,8 +5,9 @@ dense-cache whole-prompt reference token for token (including a
 cache-bucket promotion mid-generation), finished slots refill without
 draining co-riders, every plan cell stays at its single warmup compile
 under mixed traffic (and the decode auditor agrees), a chaos cancel
-storm leaks zero blocks, and token streaming works end-to-end over
-chunked HTTP.
+storm leaks zero blocks, the donated pools are written in place and
+made again when a failed step has consumed them, and token streaming
+works end-to-end over chunked HTTP.
 
 The ``zz`` prefix is deliberate: this module sorts after
 test_transformer.py so its XLA compile cost lands at the tail of a
@@ -245,6 +246,224 @@ def test_reqtrace_deadline_expiry_dies_waiting(grt, monkeypatch,
         assert payload["header"]["format"] == reqtrace.REQTRACE_FORMAT
     finally:
         reqtrace.reset()
+    assert grt.kv.stats()["blocks_live"] == 0
+
+
+# ---------------------------------------------------------------------
+# the K and V pools are donated: written in place, dead once handed
+# in, and made again when a failed step has consumed them
+# ---------------------------------------------------------------------
+def _live(pages):
+    return not any(a.is_deleted() for a in pages.values())
+
+
+def _dead(pages):
+    return all(a.is_deleted() for a in pages.values())
+
+
+@pytest.fixture(scope="module")
+def grt2():
+    """A two-layer runtime compiled under the profiler: (runtime, the
+    counters its compile() stamped, the pools it was built with)."""
+    from mxnet_tpu import profiler
+
+    rt = serving.demo_generation_runtime(
+        "gen_d", n_layers=2, slots=2, block_tokens=16, max_prompt=16,
+        max_context=32, max_new=8, prefill_batch=1)
+    built_with = rt.kv.pages
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    try:
+        rt.compile(warmup=True)
+    finally:
+        profiler.set_state("stop")
+    stamped = profiler.summary()["counters"]["counter"]
+    profiler.dumps(reset=True)
+    return rt, stamped, built_with
+
+
+def test_compile_counts_the_pools_donated(grt2):
+    rt, stamped, built_with = grt2
+    pools = 2 * rt.cfg.n_layers
+    assert len(rt.kv.pages) == pools
+    assert stamped["kv.pools_donated"]["max"] == pools
+    assert stamped["kv.pools"]["max"] == pools
+    assert stamped["kv.pools"]["count"] == 1        # once a compile()
+    # warm-up handed each cell what the cell before gave back
+    assert _dead(built_with) and _live(rt.kv.pages)
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_compiled_step_writes_every_pool_in_place(grt2, kind):
+    """The compiled step aliases each of the 2 x n_layers pools to its
+    own output and holds no copy of a pool's shape."""
+    import re
+
+    import jax
+
+    rt = grt2[0]
+    cells = rt._prefill if kind == "prefill" else rt._decode
+    (bb, tb), step = max(cells.items())
+    bt = rt.block_tokens
+
+    def ints(*shape):
+        return np.zeros(shape, dtype=np.int32)
+
+    tokens = ints(bb, tb) if kind == "prefill" else ints(bb)
+    text = step.lower(rt._params, tokens, ints(bb), rt.kv.pages,
+                      ints(bb, tb // bt)).compile().as_text()
+    head = next(ln for ln in text.splitlines()
+                if ln.startswith("HloModule"))
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
+    pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", alias.group(1))
+    # flat arguments: the parameters' leaves, tokens, lengths or
+    # positions, then the pools; flat results: logits, then the pools
+    first = len(jax.tree_util.tree_leaves(rt._params)) + 2
+    pools = 2 * rt.cfg.n_layers
+    assert sorted((int(o), int(i)) for o, i in pairs) == [
+        (1 + j, first + j) for j in range(pools)]
+    a_pool = next(iter(rt.kv.pages.values()))
+    shape = "f32[%s]" % ",".join(str(n) for n in a_pool.shape)
+    assert shape in head
+    assert not [ln for ln in text.splitlines()
+                if shape in ln.split("=")[-1].split("(")[0]
+                and re.search(r"\bcopy\(", ln)]
+    assert _live(rt.kv.pages)       # lowering consumes nothing
+
+
+def test_every_step_consumes_the_pools_it_was_handed(grt):
+    # max_new 1 retires at its prefill: a step that is a prefill alone
+    one = serving.GenRequest("gen_t", [3, 1, 4], 1)
+    grt.engine.enqueue(one)
+    handed = grt.kv.pages
+    grt.engine.step()
+    assert len(one.wait(0.1)["tokens"]) == 1
+    assert _dead(handed) and _live(grt.kv.pages)
+    # then a prefill with its decode tick, and decode ticks alone
+    req = serving.GenRequest("gen_t", [3, 1, 4], 5)
+    grt.engine.enqueue(req)
+    while not grt.engine.idle():
+        handed = grt.kv.pages
+        grt.engine.step()
+        assert _dead(handed) and _live(grt.kv.pages)
+    assert req.wait(0.1)["tokens"] == _dense_greedy(grt, [3, 1, 4], 5)
+
+
+def _consumes_then_raises(step):
+    """As a device fault inside the step reads to the engine: the
+    donated pools are gone and the call raises."""
+    def call(params, tokens, positions, pages, tables):
+        for a in pages.values():
+            a.delete()
+        raise RuntimeError("planted: the step died holding the pools")
+    return call
+
+
+def _result_unreadable(step):
+    """As a run that fails on the device after its dispatch: the call
+    returns, and reading the logits raises."""
+    class Unreadable:
+        def __array__(self, *a, **kw):
+            raise RuntimeError("planted: the run failed on the device")
+
+    def call(params, tokens, positions, pages, tables):
+        _, pages = step(params, tokens, positions, pages, tables)
+        return Unreadable(), pages
+    return call
+
+
+def _planted(cells, how):
+    """Every cell of one of the runtime's step tables wrapped, as
+    perfbench's serve_lm._planted wraps them; returns the sound ones."""
+    sound = dict(cells)
+    for key, step in sound.items():
+        cells[key] = how(step)
+    return sound
+
+
+def _rebuilds_counted(rt):
+    return diag.metrics.counter("mxnet_serve_kv_pool_rebuilds_total",
+                                labels={"model": rt.name}).value
+
+
+def _compiles(rt):
+    return {k: v["count"] for k, v in diag.recompile_stats().items()
+            if ":%s:" % rt.name in k}
+
+
+@pytest.mark.parametrize("how", [_consumes_then_raises,
+                                 _result_unreadable])
+def test_decode_that_lost_the_pools_leaves_a_cache_that_works(grt, how):
+    before = grt.kv.pool_rebuilds
+    counted = _rebuilds_counted(grt)
+    reqs = [serving.GenRequest("gen_t", p, 8)
+            for p in ([5, 6, 7], [9, 8])]
+    for r in reqs:
+        grt.engine.enqueue(r)
+    sound = _planted(grt._decode, how)
+    try:
+        rep = grt.engine.step()     # prefill is sound; the tick is not
+    finally:
+        grt._decode.update(sound)
+    assert isinstance(rep["exec_error"], serving.ExecutorFailure)
+    for r in reqs:
+        with pytest.raises(serving.ExecutorFailure):
+            r.wait(0.1)
+    assert grt.engine.idle()
+    assert grt.kv.stats()["blocks_live"] == 0
+    assert _live(grt.kv.pages)
+    assert grt.kv.pool_rebuilds == before + 1
+    assert _rebuilds_counted(grt) == counted + 1
+    # ...and the server serves on, correctly, in the cells it compiled
+    nxt = serving.GenRequest("gen_t", [2, 7, 1, 8], 20)
+    grt.engine.enqueue(nxt)
+    while not grt.engine.idle():
+        grt.engine.step()
+    assert nxt.wait(0.1)["tokens"] == _dense_greedy(grt, [2, 7, 1, 8], 20)
+    assert set(_compiles(grt).values()) == {1}, _compiles(grt)
+
+
+@pytest.mark.parametrize("pools_lost", [True, False])
+def test_prefill_failure_with_riders_active(grt, monkeypatch, pools_lost):
+    """A prefill that consumed the pools takes the riders' history
+    with it: they fail too.  One that raises before it is dispatched
+    (chaos ``fail_execute``) fails its own group alone, as ever."""
+    before = grt.kv.pool_rebuilds
+    rider = serving.GenRequest("gen_t", [4, 4, 2], 12)
+    grt.engine.enqueue(rider)
+    grt.engine.step()
+    assert len(rider.tokens) == 2 and not rider.done()
+    late = serving.GenRequest("gen_t", [6, 1], 4)
+    grt.engine.enqueue(late)
+    if pools_lost:
+        sound = _planted(grt._prefill, _consumes_then_raises)
+    else:
+        sound = dict(grt._prefill)
+        monkeypatch.setenv("MXNET_CHAOS",
+                           "fail_execute:model=gen_t,count=1")
+        chaos.reset()
+    try:
+        rep = grt.engine.step()
+    finally:
+        grt._prefill.update(sound)
+        monkeypatch.delenv("MXNET_CHAOS", raising=False)
+        chaos.reset()
+    assert isinstance(rep["exec_error"], serving.ExecutorFailure)
+    with pytest.raises(serving.ExecutorFailure):
+        late.wait(0.1)
+    assert _live(grt.kv.pages)
+    if pools_lost:
+        with pytest.raises(serving.ExecutorFailure):
+            rider.wait(0.1)
+        assert grt.engine.idle()
+        assert grt.kv.pool_rebuilds == before + 1
+    else:
+        assert not rider.done()
+        while not grt.engine.idle():
+            grt.engine.step()
+        assert rider.wait(0.1)["tokens"] == _dense_greedy(
+            grt, [4, 4, 2], 12)
+        assert grt.kv.pool_rebuilds == before
     assert grt.kv.stats()["blocks_live"] == 0
 
 
