@@ -30,6 +30,13 @@ rides on top: a finished (or cancelled, or evicted) sequence's slot
 and blocks are reclaimed on the NEXT decode tick and refilled from the
 queue without draining the co-riding sequences.
 
+What a block of the paged cache holds is the MODEL's to say
+(``transformer.model.cache_rows``: K and V of every head, or a latent
+mixer's compressed row), and a model with expert layers also carries
+its routing counts beside the pools (``pages["routed"]``), added up on
+the device by both compiled steps and read by
+``GenerationRuntime.routing_counters()`` when asked, never in a tick.
+
 Numerics contract, pinned by tests/test_zz_generate_e2e.py: greedy
 decode
 through this engine is token-for-token identical to running the plain
@@ -208,10 +215,13 @@ class GenerationRuntime:
             (a, b) for a in self.batch_plan for b in self.cache_plan)
         self.plan = self.decode_plan  # what stats()/dashboards show
         self._params = self._to_device(params)
+        rows, counters = self._cache_layout()
         self.kv = PagedKVCache(
-            n_layers=cfg.n_layers, n_heads=cfg.n_heads,
-            head_dim=cfg.head_dim, num_blocks=nb, block_tokens=bt,
+            rows=rows, counters=counters, num_blocks=nb, block_tokens=bt,
             dtype=cfg.dtype, name=self.name)
+        # ``routed`` as last read (it wraps at 2**32: the differences
+        # do not), and the pools' generation it was read from
+        self._routed_seen = None
         #: one instrumented wrapper per plan cell — "zero steady-state
         #: recompiles" means every wrapper's compile count stays at its
         #: warmup value of exactly 1
@@ -226,6 +236,44 @@ class GenerationRuntime:
         import jax.numpy as jnp
 
         return jax.tree_util.tree_map(jnp.asarray, params)
+
+    def _cache_layout(self):
+        """``(rows, counters)`` of the paged cache, as the model states
+        them: the row a token leaves in each pool, and the int32 arrays
+        that ride beside the pools."""
+        from ..transformer import model as _model
+
+        routed = _model.routed_shape(self.cfg)
+        return (_model.cache_rows(self.cfg),
+                {"routed": routed} if routed else {})
+
+    def routing_counters(self) -> Optional[Dict]:
+        """What the expert layers routed since the last call
+        (``transformer.model.routed_counts``), also stamped as profiler
+        counters ``moe.*``; None for a model without expert layers.
+        ONE read of the small array that both compiled steps add to in
+        place on the device, taken between two steps of the engine
+        (``kv.in_step``: it waits for a step in flight to hand the
+        pages back): call it outside a timed window."""
+        import numpy as np
+
+        from ..transformer import model as _model
+
+        if "routed" not in self.kv.pages:
+            return None
+        with self.kv.in_step:
+            now = np.asarray(  # mxlint: disable=MXL004
+                self.kv.pages["routed"]).astype(np.uint32)
+            gen = self.kv.pool_rebuilds
+        if self._routed_seen is None or self._routed_seen[0] != gen:
+            self._routed_seen = (gen, np.zeros_like(now))
+        delta = (now - self._routed_seen[1]).astype(np.int64)
+        self._routed_seen = (gen, now)
+        out = _model.routed_counts(delta, self.cfg)
+        for k in ("assignments_total", "assignments_here", "dropped",
+                  "load_max_over_mean", "experts_reached"):
+            _profiler.record_counter("moe." + k, out[k])
+        return out
 
     # -- compilation ---------------------------------------------------
     @property
@@ -264,7 +312,7 @@ class GenerationRuntime:
         trace is the one the step's first call reuses), over the pools
         there are.  A step that is no jit donates nothing.  Returns
         the former."""
-        pools = set(self.kv.pages)
+        pools = set(self.kv.pools)
         donated = set(pools)
         for step, args in steps_args:
             trace = getattr(step, "trace", None)
@@ -284,6 +332,7 @@ class GenerationRuntime:
 
         from .. import diagnostics as _diag
         from ..compile_cache import enable as _cc_enable
+        from ..parallel import attention as _attention
 
         _cc_enable()
         with self._lock:
@@ -308,9 +357,15 @@ class GenerationRuntime:
                 return (self._params, ints(bb), ints(bb),
                         self.kv.pages, ints(bb, lb // bt))
 
+            sites = _attention.site_tally()
             donated = self._stamp_donation(
                 [(pjit, prefill_args(*self.prefill_plan[0])),
                  (djit, decode_args(*self.decode_plan[0]))])
+            for how, n in _attention.site_tally(sites).items():
+                # the dense block's explicit mask is no site: it stamps
+                # nothing, as before
+                if n:
+                    _profiler.record_counter("attn.%s_sites" % how, n)
             for kind, plan, cells, fn, args_of in (
                     ("prefill", self.prefill_plan, self._prefill, pjit,
                      prefill_args),
@@ -335,7 +390,7 @@ class GenerationRuntime:
                 "serving: compiled generator %r — %d prefill + %d "
                 "decode plan cells (warmup=%s), %d of %d KV pools "
                 "donated", self.name, len(self._prefill),
-                len(self._decode), warmup, donated, len(self.kv.pages))
+                len(self._decode), warmup, donated, len(self.kv.pools))
             return dict(self._compile_ms)
 
     def _feed_compile_metrics(self, dur_ms: float) -> None:
@@ -578,8 +633,9 @@ class GenerationEngine:
                 plens[i] = p
                 tables[i] = self.kv.block_table(seqs[i], tb // bt)
             w = rt._prefill[(bb, tb)]
-            with _profiler.span("mx.prefill", cat="serving", args={
-                    "tokens": int(plens[:len(group)].sum())}):
+            with self.kv.in_step, _profiler.span(
+                    "mx.prefill", cat="serving", args={
+                        "tokens": int(plens[:len(group)].sum())}):
                 logits, pages = w(rt._params, tokens, plens,
                                   self.kv.pages, tables)
                 first = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
@@ -673,8 +729,9 @@ class GenerationEngine:
             tables[i] = self.kv.block_table(s.seq_id, lb // bt)
         try:
             w = rt._decode[(bb, lb)]
-            with _profiler.span("mx.tick", cat="serving", args={
-                    "live": len(riders), "slots": rt.slots}):
+            with self.kv.in_step, _profiler.span(
+                    "mx.tick", cat="serving", args={
+                        "live": len(riders), "slots": rt.slots}):
                 logits, pages = w(rt._params, tokens, positions,
                                   self.kv.pages, tables)
                 nxt = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
@@ -763,13 +820,10 @@ def demo_generation_runtime(name: str = "gen", seed: int = 0, *,
 
 
 class _StubGenConfig:
-    """Minimal config surface StubGenerationRuntime needs (the paged
-    cache geometry + vocab for the arithmetic token rule)."""
+    """Minimal config surface StubGenerationRuntime needs (vocab for
+    the arithmetic token rule, the cache's dtype)."""
 
     vocab_size = 64
-    n_layers = 1
-    n_heads = 1
-    head_dim = 1
     dtype = "float32"
 
 
@@ -801,6 +855,9 @@ class StubGenerationRuntime(GenerationRuntime):
 
     def _to_device(self, params):
         return params  # host stub: nothing to place on a device
+
+    def _cache_layout(self):
+        return {"k0": (1, 1), "v0": (1, 1)}, {}
 
     def _jit_fns(self):
         import numpy as np

@@ -3,8 +3,11 @@
 The generation tier budgets cache memory the way ``parallel/buckets.py``
 budgets gradient bytes: a fixed pool carved into fixed-size units, a
 deterministic plan of who holds what, and accounting that feeds
-``diagnostics.metrics``.  Each layer owns two pools
-``(num_blocks, block_tokens, n_heads, head_dim)`` — K and V — and a
+``diagnostics.metrics``.  Each layer owns the pools its mixer states
+(``rows``: a pool's name and the shape of one token's row in it; the
+dense block's K and V, ``(num_blocks, block_tokens, n_heads,
+head_dim)`` twice a layer, or a latent mixer's ``(num_blocks,
+block_tokens, kv_lora_rank + rope)`` once) and a
 sequence holds a LIST of block ids, not a contiguous span, so slot
 churn from continuous batching cannot fragment the pool into unusable
 holes: any free block serves any sequence.
@@ -41,16 +44,22 @@ class CacheExhausted(RuntimeError):
 
 
 class PagedKVCache:
-    """Free-list block allocator over per-layer K/V pools."""
+    """Free-list block allocator over per-layer pools of cache rows.
 
-    def __init__(self, *, n_layers: int, n_heads: int, head_dim: int,
-                 num_blocks: int, block_tokens: int,
-                 dtype: str = "float32", name: str = "gen"):
+    ``rows`` is ``{pool: shape of one token's row}`` as the model
+    states it (``transformer.model.cache_rows``); ``counters`` is
+    ``{name: shape}`` of int32 arrays that ride in ``pages`` beside the
+    pools, donated and returned with them (what an expert layer's steps
+    routed), and are no pool."""
+
+    def __init__(self, *, rows: Dict[str, tuple], num_blocks: int,
+                 block_tokens: int, dtype: str = "float32",
+                 name: str = "gen",
+                 counters: Optional[Dict[str, tuple]] = None):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "garbage block)")
         self.name = str(name)
-        self.n_layers = int(n_layers)
         self.num_blocks = int(num_blocks)
         self.block_tokens = int(block_tokens)
         self._lock = threading.Lock()
@@ -63,22 +72,46 @@ class PagedKVCache:
         self._lengths: Dict[str, int] = {}
         self.evictions = 0
         self.pool_rebuilds = 0
-        self._pool_shape = (self.num_blocks, self.block_tokens,
-                            int(n_heads), int(head_dim))
+        self._pool_shapes = {
+            pool: (self.num_blocks, self.block_tokens)
+            + tuple(int(n) for n in row) for pool, row in rows.items()}
+        self._counter_shapes = {k: tuple(v) for k, v in
+                                (counters or {}).items() if v}
         self._pool_dtype = dtype
-        #: device pools, donated to the compiled steps: the engine
-        #: replaces this dict with what each step returns, and the
-        #: dict that went in is dead — keep no reference to it or to
-        #: its arrays across a step
+        #: device pools (and counters), donated to the compiled steps:
+        #: the engine replaces this dict with what each step returns,
+        #: and the dict that went in is dead — keep no reference to it
+        #: or to its arrays across a step
         self.pages = self._zeroed_pools()
-        (self._device,) = self.pages["k0"].devices()
+        #: held by the engine's thread from a compiled step's dispatch
+        #: until ``pages`` names what the step returned; any other
+        #: thread that reads an array of ``pages`` takes it
+        self.in_step = threading.Lock()
+        (self._device,) = next(iter(self.pages.values())).devices()
+
+    @property
+    def pools(self) -> tuple:
+        """The names of ``pages`` that are pools of cache rows."""
+        return tuple(self._pool_shapes)
+
+    def block_bytes(self) -> int:
+        """What one block holds over all pools."""
+        import math
+
+        import jax.numpy as jnp
+
+        return sum(math.prod(shape[1:]) for shape in
+                   self._pool_shapes.values()) \
+            * jnp.dtype(self._pool_dtype).itemsize
 
     def _zeroed_pools(self) -> Dict:
         import jax.numpy as jnp
 
-        return {"%s%d" % (kv, i): jnp.zeros(self._pool_shape,
-                                            dtype=self._pool_dtype)
-                for i in range(self.n_layers) for kv in "kv"}
+        pages = {pool: jnp.zeros(shape, dtype=self._pool_dtype)
+                 for pool, shape in self._pool_shapes.items()}
+        pages.update((k, jnp.zeros(shape, dtype=jnp.int32))
+                     for k, shape in self._counter_shapes.items())
+        return pages
 
     def pools_lost(self) -> bool:
         """Whether a step consumed the donated pools and gave none

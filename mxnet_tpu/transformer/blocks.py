@@ -33,8 +33,9 @@ import math
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 __all__ = ["yarn_inv_freq", "yarn_softmax_mscale", "latent_qkv",
-           "gated_ffn", "expert_ffn", "sinkhorn", "stream_maps",
-           "hyper_residual", "site_tally"]
+           "latent_qkv_row", "latent_absorbed_query", "absorbed_attention",
+           "absorbed_values", "gated_ffn", "expert_ffn", "sinkhorn",
+           "stream_maps", "hyper_residual", "site_tally"]
 
 
 # ---------------------------------------------------------------------
@@ -86,33 +87,132 @@ def yarn_softmax_mscale(yarn) -> float:
 
 
 # ---------------------------------------------------------------------
-# latent attention: q through a rank-q_lora_rank bottleneck, k and v
-# out of one rank-kv_lora_rank latent, one rotary key shared by heads
+# latent attention: q through a rank-q_lora_rank bottleneck (or, with
+# q_lora_rank 0, one matrix), k and v out of one rank-kv_lora_rank
+# latent, one rotary key shared by heads.  Training and prefill expand
+# the latent into every head's keys and values (``latent_qkv``); decode
+# caches the latent and attends in its space (``absorbed_attention``)
 # ---------------------------------------------------------------------
+def latent_projections(a, lp: Dict, positions, cfg, rmsnorm, rope):
+    """``a`` (B, T, D), already normed -> the queries (B, T, H, nope +
+    rope) with their rotary part turned, the normed latent ``c``
+    (B, T, kv_lora_rank) and the one rotary key of all heads ``k_r``
+    (B, T, 1, rope), turned.  ``q_lora_rank`` 0 is one ``wq`` and no
+    norm on the queries' way; any other is the bottleneck ``wq_a``,
+    ``q_norm``, ``wq_b``."""
+    b, t, _ = a.shape
+    h, nope, rp = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    r = cfg.kv_lora_rank
+    dt = a.dtype
+    if cfg.q_lora_rank:
+        cq = rmsnorm(a @ lp["wq_a"].astype(dt), lp["q_norm"], cfg.eps)
+        q = cq @ lp["wq_b"].astype(dt)
+    else:
+        q = a @ lp["wq"].astype(dt)
+    q = q.reshape(b, t, h, nope + rp)
+    kva = a @ lp["wkv_a"].astype(dt)
+    ckv = rmsnorm(kva[..., :r], lp["kv_norm"], cfg.eps)
+    return q, ckv, kva[..., r:].reshape(b, t, 1, rp)
+
+
+def _turned(q, k_r, positions, cfg, rope):
+    """The rotary parts of ``q`` and the shared key at ``positions``,
+    under the configuration's frequencies (YaRN where it has them)."""
+    import jax.numpy as jnp
+
+    nope = cfg.qk_nope_head_dim
+    freqs = None if cfg.rope_yarn is None else \
+        yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_base, cfg.rope_yarn)
+    q_r = rope(q[..., nope:], positions, cfg.rope_base, freqs)
+    k_r = rope(k_r, positions, cfg.rope_base, freqs)
+    return jnp.concatenate([q[..., :nope], q_r], axis=-1), k_r
+
+
 def latent_qkv(a, lp: Dict, positions, cfg, rmsnorm, rope):
     """``a`` (B, T, D), already normed -> q, k (B, T, H, nope + rope)
     and v (B, T, H, v_head_dim).  Scope ``attn_proj`` is the caller's."""
+    q, k, v, _ = latent_qkv_row(a, lp, positions, cfg, rmsnorm, rope)
+    return q, k, v
+
+
+def latent_qkv_row(a, lp: Dict, positions, cfg, rmsnorm, rope):
+    """:func:`latent_qkv` and what a token CACHES: ``row`` (B, T,
+    kv_lora_rank + rope) = ``[RMSNorm(c) | rope(k_r)]``, from which
+    ``k`` and ``v`` of every head can be made again (and need not be:
+    :func:`absorbed_attention`)."""
     import jax.numpy as jnp
 
     b, t, _ = a.shape
     h, nope, rp, dv = (cfg.n_heads, cfg.qk_nope_head_dim,
                        cfg.qk_rope_head_dim, cfg.v_head_dim)
-    r = cfg.kv_lora_rank
     dt = a.dtype
-    cq = rmsnorm(a @ lp["wq_a"].astype(dt), lp["q_norm"], cfg.eps)
-    q = (cq @ lp["wq_b"].astype(dt)).reshape(b, t, h, nope + rp)
-    kva = a @ lp["wkv_a"].astype(dt)
-    ckv = rmsnorm(kva[..., :r], lp["kv_norm"], cfg.eps)
+    q, ckv, k_r = latent_projections(a, lp, positions, cfg, rmsnorm, rope)
     kv = (ckv @ lp["wkv_b"].astype(dt)).reshape(b, t, h, nope + dv)
-    freqs = None if cfg.rope_yarn is None else \
-        yarn_inv_freq(rp, cfg.rope_base, cfg.rope_yarn)
-    q_r = rope(q[..., nope:], positions, cfg.rope_base, freqs)
-    k_r = rope(kva[..., r:].reshape(b, t, 1, rp), positions,
-               cfg.rope_base, freqs)
-    q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+    q, k_r = _turned(q, k_r, positions, cfg, rope)
     k = jnp.concatenate(
         [kv[..., :nope], jnp.broadcast_to(k_r, (b, t, h, rp))], axis=-1)
-    return q, k, kv[..., nope:]
+    row = jnp.concatenate([ckv, k_r[:, :, 0]], axis=-1)
+    return q, k, kv[..., nope:], row
+
+
+def latent_absorbed_query(a, lp: Dict, positions, cfg, rmsnorm, rope):
+    """One new token a row, ``a`` (B, 1, D) already normed -> its
+    queries in the latent's space, ``[q_nope W_uk^T | rope(q_r)]``
+    (B, H, kv_lora_rank + rope), and its cache ``row`` (B, 1,
+    kv_lora_rank + rope).  ``W_uk`` is the keys' half of ``wkv_b`` by
+    head: ``q_nope . (c W_uk) = (q_nope W_uk^T) . c``, so a cached
+    token's keys are never made."""
+    import jax.numpy as jnp
+
+    h, nope, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    q, ckv, k_r = latent_projections(a, lp, positions, cfg, rmsnorm, rope)
+    q, k_r = _turned(q, k_r, positions, cfg, rope)
+    w_uk = lp["wkv_b"].astype(a.dtype).reshape(
+        cfg.kv_lora_rank, h, nope + dv)[..., :nope]
+    q_c = jnp.einsum("bhn,rhn->bhr", q[:, 0, :, :nope], w_uk)
+    return (jnp.concatenate([q_c, q[:, 0, :, nope:]], axis=-1),
+            jnp.concatenate([ckv, k_r[:, :, 0]], axis=-1))
+
+
+def absorbed_attention(q_abs, rows, mask, cfg):
+    """Attention of one new token a row over its cached latent ``rows``
+    (B, W, block_tokens, kv_lora_rank + rope), the blocks of its table
+    as they lie in the pool, under ``mask`` (B, W * block_tokens):
+    scores ``q_abs . row`` times :func:`latent_sm_scale`, float32 from
+    the product through the softmax, and the probabilities' sum of the
+    latents ``u`` (B, H, kv_lora_rank), float32.  The operands stay in their
+    dtype and their layout (no float32 copy of the history, no view of
+    it as one run of tokens)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, w, bt, _ = rows.shape
+    s = jnp.einsum("bhc,bwtc->bhwt", q_abs, rows,
+                   preferred_element_type=jnp.float32) * latent_sm_scale(cfg)
+    s = jnp.where(mask.reshape(b, 1, w, bt), s, -jnp.inf)
+    p = jax.nn.softmax(s.reshape(b, -1, w * bt), axis=-1)
+    # over the whole row and the rotary key's columns dropped from the
+    # sum: a slice of the history first would be a copy of it
+    u = jnp.einsum("bhwt,bwtc->bhc", p.reshape(s.shape).astype(rows.dtype),
+                   rows, preferred_element_type=jnp.float32)
+    return u[..., :cfg.kv_lora_rank]
+
+
+def absorbed_values(u, lp: Dict, cfg, dtype):
+    """``u`` (B, H, kv_lora_rank), float32 -> the heads' outputs (B, 1,
+    H, v_head_dim) in ``dtype``: ``o_h = u_h W_uv_h``, the values' half
+    of ``wkv_b`` applied after the sum instead of to every cached
+    token.  In float32 (``u`` is a row a head a sequence, the product
+    small): the expanded form sums float32 products of rounded values,
+    and rounding ``u`` first would round once more than it does."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    h, nope, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    w_uv = lp["wkv_b"].astype(u.dtype).reshape(
+        cfg.kv_lora_rank, h, nope + dv)[..., nope:]
+    return jnp.einsum("bhr,rhd->bhd", u, w_uv,
+                      precision=lax.Precision.HIGHEST)[:, None].astype(dtype)
 
 
 def latent_sm_scale(cfg) -> float:

@@ -34,7 +34,8 @@ the same program as before they existed).  A layer is a MIXER and a
 FEED-FORWARD, chosen as data:
 
   * mixer ``attn_kind``: ``mha`` (one fused ``wqkv``, full rotary) or
-    ``latent`` (queries through a ``q_lora_rank`` bottleneck, keys and
+    ``latent`` (queries through a ``q_lora_rank`` bottleneck, or one
+    ``wq`` where that is 0, keys and
     values out of one ``kv_lora_rank`` latent, ``qk_nope_head_dim`` +
     ``qk_rope_head_dim`` wide queries and keys over ``v_head_dim`` wide
     values, one rotary key shared by the heads, two inner RMSNorms,
@@ -52,11 +53,14 @@ FEED-FORWARD, chosen as data:
     embedding and the head and add ``mtp_loss_weight`` times their loss;
     ``labels`` then carries ``mtp_layers`` more columns.
 
-Only :func:`apply` / :func:`loss_and_aux` spell these;
-:func:`apply_prefill` and :func:`apply_decode` keep the dense block and
-raise ``NotImplementedError`` for a configuration with any other kind
-(a latent paged cache is not written), as do the ring and ulysses
-attention impls for ``latent``.
+
+:func:`apply` / :func:`loss_and_aux` spell all of these.  The
+generation forwards, :func:`apply_prefill` and :func:`apply_decode`,
+spell both mixers, both dense feed-forwards, the expert layer (under
+the latent mixer) and either head over a paged cache whose rows the
+mixer states (:func:`cache_rows`); they raise ``NotImplementedError``
+for residual streams and multi-token modules, as do the ring and
+ulysses attention impls for ``latent``.
 
 Rematerialization is per-block and policy-selectable
 (``MXNET_REMAT_POLICY`` = ``none`` | ``block`` | ``attention``,
@@ -77,6 +81,7 @@ __all__ = [
     "make_attn_fn", "param_shapes", "init_params", "apply", "lm_loss",
     "loss_and_aux", "frozen_names",
     "dense_causal_attn", "gather_kv", "apply_prefill", "apply_decode",
+    "cache_rows", "routed_shape", "routed_counts",
 ]
 
 ATTENTION_IMPLS = ("flash", "ring", "ulysses")
@@ -153,14 +158,6 @@ class TransformerConfig(NamedTuple):
         return "experts" in self.kinds or self.mtp_layers > 0
 
     @property
-    def dense_block(self) -> bool:
-        """True for the block the generation forwards spell."""
-        return (self.attn_kind == "mha" and self.ffn_act == "gelu_tanh"
-                and self.tied_head and self.hc_mult == 1
-                and not self.mtp_layers
-                and all(k == "dense_ffn" for k in self.kinds))
-
-    @property
     def ff_dim(self) -> int:
         return self.d_ff if self.d_ff is not None else 4 * self.d_model
 
@@ -228,15 +225,16 @@ def _mixer_shapes(cfg: TransformerConfig, p: str, dt: str):
     if cfg.attn_kind != "latent":
         raise ValueError("attn_kind %r: mha or latent" % (cfg.attn_kind,))
     H, qk = cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    return [(p + "attn_norm", (D,), dt),
-            (p + "wq_a", (D, cfg.q_lora_rank), dt),
-            (p + "q_norm", (cfg.q_lora_rank,), dt),
-            (p + "wq_b", (cfg.q_lora_rank, H * qk), dt),
-            (p + "wkv_a", (D, cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt),
-            (p + "kv_norm", (cfg.kv_lora_rank,), dt),
-            (p + "wkv_b", (cfg.kv_lora_rank,
-                           H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dt),
-            (p + "wo", (H * cfg.v_head_dim, D), dt)]
+    queries = [(p + "wq", (D, H * qk), dt)] if not cfg.q_lora_rank else [
+        (p + "wq_a", (D, cfg.q_lora_rank), dt),
+        (p + "q_norm", (cfg.q_lora_rank,), dt),
+        (p + "wq_b", (cfg.q_lora_rank, H * qk), dt)]
+    return [(p + "attn_norm", (D,), dt)] + queries + [
+        (p + "wkv_a", (D, cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt),
+        (p + "kv_norm", (cfg.kv_lora_rank,), dt),
+        (p + "wkv_b", (cfg.kv_lora_rank,
+                       H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dt),
+        (p + "wo", (H * cfg.v_head_dim, D), dt)]
 
 
 def _ffn_shapes(cfg: TransformerConfig, p: str, kind: str, dt: str):
@@ -439,15 +437,7 @@ def _make_block(cfg: TransformerConfig, attn_fn, positions, policy, kind):
                          (B, t, cfg.n_heads * cfg.v_head_dim))
 
     def ffn_part(h, lp):
-        if kind == "dense_ffn" and cfg.ffn_act == "gelu_tanh":
-            return _mlp(h, lp["mlp_norm"], lp["w1"], lp["w2"], cfg), {}
-        with jax.named_scope("norm"):
-            m = _rmsnorm(h, lp["mlp_norm"], cfg.eps)
-        with jax.named_scope("mlp"):
-            if kind == "experts":
-                return _blocks.expert_ffn(m, lp, cfg)
-            return _blocks.gated_ffn(m, lp["w_gate"], lp["w_up"],
-                                     lp["w_down"]), {}
+        return _ffn_part(h, lp, cfg, kind)
 
     attn_ck = checkpoint_scope(attn_part, policy, "attention")
 
@@ -626,6 +616,24 @@ def _mlp(h, g, w1, w2, cfg):
         return jnp.dot(_gelu(m @ w1.astype(m.dtype)), w2.astype(m.dtype))
 
 
+def _ffn_part(h, lp, cfg, kind):
+    """A layer's feed-forward over ``h`` with its own norm -> ``(y,
+    aux)``; ``aux`` is what an expert layer counted, empty otherwise."""
+    import jax
+
+    from . import blocks as _blocks
+
+    if kind == "dense_ffn" and cfg.ffn_act == "gelu_tanh":
+        return _mlp(h, lp["mlp_norm"], lp["w1"], lp["w2"], cfg), {}
+    with jax.named_scope("norm"):
+        m = _rmsnorm(h, lp["mlp_norm"], cfg.eps)
+    with jax.named_scope("mlp"):
+        if kind == "experts":
+            return _blocks.expert_ffn(m, lp, cfg)
+        return _blocks.gated_ffn(m, lp["w_gate"], lp["w_up"],
+                                 lp["w_down"]), {}
+
+
 def _final_norm(h, params, cfg):
     import jax
 
@@ -663,14 +671,26 @@ def lm_loss(logits, labels):
 # ---------------------------------------------------------------------------
 # generation forwards: prefill/decode over a PAGED KV cache
 #
-# The cache is a per-layer pool of fixed-size token blocks
-# ``{"k<i>"|"v<i>": (num_blocks, block_tokens, H, Dh)}`` plus a
-# per-sequence block table (serving/kvcache.py owns allocation; block 0
-# is the GARBAGE block — every write from a padded position or an
-# inactive slot is routed there, so the compiled step never branches on
-# liveness).  Scatter runs BEFORE gather inside the decode step, so the
-# new token attends to itself through the same cache path as its
-# history — one code path, pinned by the greedy-equality tests.
+# The cache is a per-layer pool of fixed-size token blocks, a row of it
+# what the layer's mixer keeps of one token (``cache_rows``): ``mha``
+# keeps the roped K and the raw V of every head, ``{"k<i>"|"v<i>":
+# (num_blocks, block_tokens, H, Dh)}``; ``latent`` keeps the normed
+# latent and the one roped key, ``{"c<i>": (num_blocks, block_tokens,
+# kv_lora_rank + qk_rope_head_dim)}``, prefills in the expanded form
+# through ``flash_attention`` and decodes in the ABSORBED form (the
+# keys and values of a cached token are never made:
+# ``blocks.absorbed_attention``).  A per-sequence block table addresses
+# it (serving/kvcache.py owns allocation; block 0 is the GARBAGE block
+# — every write from a padded position or an inactive slot is routed
+# there, so the compiled step never branches on liveness).  Scatter
+# runs BEFORE gather inside the decode step, so the new token attends
+# to itself through the same cache path as its history — one code path,
+# pinned by the greedy-equality tests.
+#
+# A configuration with expert layers also carries ``pages["routed"]``
+# (``routed_shape``): what its steps routed, added up in place on the
+# device by every step and read by the host when it asks
+# (``GenerationRuntime.routing_counters``), never inside a tick.
 # ---------------------------------------------------------------------------
 def _masked_attn(q, k, v, mask):
     """Naive dense attention with an explicit boolean ``mask``
@@ -723,6 +743,54 @@ def _scatter_tokens(pool, x, block_tables, pos, block_tokens,
     return flat_pool.reshape(pool.shape)
 
 
+def _write_rows(pool, x, block_tables, pos, block_tokens):
+    """One row a sequence, ``x`` (B, 1, width) at positions ``pos``
+    (B, 1), written into a pool of rows ``(N, block_tokens, width)``
+    WHERE IT LIES.  The chip keeps such a pool with the tokens of a
+    block innermost (a width of 576 is no multiple of its 128 lanes): a
+    scatter wants the rows innermost, and would copy the whole pool
+    into that order and back around every write; an update of one slice
+    a sequence takes the pool as it is.  An empty slot's table is all
+    garbage block, so its row lands there."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    bt = int(block_tokens)
+    blk = jnp.take_along_axis(block_tables, pos // bt, axis=1)[:, 0]
+    off = (pos % bt)[:, 0]
+    x = x.astype(pool.dtype)
+
+    def write(i, pool):
+        zero = jnp.zeros((), blk.dtype)
+        return lax.dynamic_update_slice(
+            pool, lax.dynamic_slice_in_dim(x, i, 1), (blk[i], off[i], zero))
+
+    return lax.fori_loop(0, x.shape[0], write, pool)
+
+
+def _write_blocks(pool, x, block_tables, block_tokens):
+    """A prompt's rows ``x`` (B, T, width), T a multiple of the block,
+    written into the pool a whole block at a time, where it lies
+    (:func:`_write_rows`).  A padded prompt's rows behind its length
+    land in its last block, where no query reads them before decode has
+    written over them (a position is read only once the cursor has
+    passed it), or, a whole block of them, in the garbage block that
+    its table names there."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    b, t = x.shape[:2]
+    bt = int(block_tokens)
+    x = x.astype(pool.dtype).reshape((b * (t // bt), 1, bt) + x.shape[2:])
+    blocks = block_tables.reshape(-1)
+
+    def write(i, pool):
+        zero = jnp.zeros((), blocks.dtype)
+        return lax.dynamic_update_slice(pool, x[i], (blocks[i], zero, zero))
+
+    return lax.fori_loop(0, x.shape[0], write, pool)
+
+
 def gather_kv(pages, block_tables, layer):
     """Gather one layer's cached K/V through the block tables:
     ``(B, W)`` tables over ``(N, bt, H, Dh)`` pools -> two
@@ -733,6 +801,101 @@ def gather_kv(pages, block_tables, layer):
     b, w, bt = k.shape[:3]
     return (k.reshape((b, w * bt) + k.shape[3:]),
             v.reshape((b, w * bt) + v.shape[3:]))
+
+
+def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
+    """``{pool: shape of one token's row}`` for every pool of the paged
+    cache, in layer order: what ``serving.PagedKVCache`` is sized
+    from."""
+    if cfg.attn_kind == "latent":
+        row = (cfg.kv_lora_rank + cfg.qk_rope_head_dim,)
+        return {"c%d" % i: row for i in range(cfg.n_layers)}
+    row = (cfg.n_heads, cfg.head_dim)
+    return {"%s%d" % (kv, i): row for i in range(cfg.n_layers)
+            for kv in "kv"}
+
+
+def routed_shape(cfg: TransformerConfig) -> Optional[Tuple[int, int]]:
+    """The shape of ``pages["routed"]`` (int32), or None without expert
+    layers: a row an expert layer, in layer order, of ``n_experts``
+    assignments of prompt tokens to each expert of all, ``n_experts``
+    of decoded tokens, then the held experts a decode tick reached with
+    at least one assignment (summed over the ticks), the decode ticks,
+    and the assignments to a held expert that were not computed (0).
+    Padded positions and empty slots are not counted."""
+    layers = sum(k == "experts" for k in cfg.kinds)
+    return (layers, 2 * cfg.n_experts + 3) if layers else None
+
+
+def _routed_row(aux: Dict, counted, cfg: TransformerConfig, decode: bool):
+    """One step's row of ``routed`` for one expert layer: ``aux`` from
+    ``expert_ffn``, ``counted`` (N,) the tokens that are real."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    e = cfg.n_experts
+    chosen = (aux["choice"][..., None] == jnp.arange(e)) \
+        & counted[:, None, None]
+    counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+    zeros = jnp.zeros((e,), jnp.int32)
+    if not decode:
+        tail = jnp.stack([0, 0, aux["dropped"]]).astype(jnp.int32)
+        return jnp.concatenate([counts, zeros, tail])
+    reached = jnp.sum(counts[np.asarray(cfg.held_experts)] > 0)
+    tail = jnp.stack([reached, 1, aux["dropped"]]).astype(jnp.int32)
+    return jnp.concatenate([zeros, counts, tail])
+
+
+def routed_counts(delta, cfg: TransformerConfig) -> Dict:
+    """What a difference ``delta`` (int64, ``routed_shape``) of two
+    readings of ``routed`` says was routed between them, in
+    :func:`_routed_row`'s layout.
+
+    ``assignments_total`` and ``assignments_here`` (of prompt and
+    decoded tokens, to every expert and to those held here),
+    ``prefill_assignments_total`` / ``_here`` and
+    ``decode_assignments_total`` / ``_here``, ``dropped`` (0: nothing
+    is dropped), ``decode_ticks``, ``experts_reached`` (held experts
+    with at least one assignment, a layer, the mean over decode ticks),
+    ``experts_reached_sum`` (the same summed over ticks and layers:
+    what the ticks had to read of the experts' matrices),
+    ``load_max_over_mean`` (the busiest held expert's assignments over
+    the mean held expert's, the mean over layers) and ``counts``
+    (layers, n_experts)."""
+    import numpy as np
+
+    e, held = cfg.n_experts, list(cfg.held_experts)
+    prefill, decode, tail = delta[:, :e], delta[:, e:2 * e], delta[:, 2 * e:]
+    counts = prefill + decode
+    here = counts[:, held]
+    ticks, reached = int(tail[0, 1]), int(tail[:, 0].sum())
+    return {
+        "assignments_total": int(counts.sum()),
+        "assignments_here": int(here.sum()),
+        "prefill_assignments_total": int(prefill.sum()),
+        "prefill_assignments_here": int(prefill[:, held].sum()),
+        "decode_assignments_total": int(decode.sum()),
+        "decode_assignments_here": int(decode[:, held].sum()),
+        "dropped": int(tail[:, 2].sum()),
+        "decode_ticks": ticks,
+        "experts_reached": reached / max(ticks * len(delta), 1),
+        "experts_reached_sum": reached,
+        "load_max_over_mean": float(np.mean(
+            here.max(axis=-1) / np.maximum(here.mean(axis=-1), 1e-30))),
+        "counts": counts,
+    }
+
+
+def _generates(cfg: TransformerConfig) -> None:
+    if cfg.hc_mult != 1 or cfg.mtp_layers:
+        raise NotImplementedError(
+            "generation spells one residual stream and no multi-token "
+            "module (hc_mult %d, mtp_layers %d)"
+            % (cfg.hc_mult, cfg.mtp_layers))
+    if cfg.attn_kind == "mha" and "experts" in cfg.kinds:
+        raise NotImplementedError(
+            "generation spells the expert layer under the latent mixer "
+            "only")
 
 
 def _prefill_layer(h, k_pool, v_pool, w, block_tables, pos2, valid, mask,
@@ -752,7 +915,7 @@ def _prefill_layer(h, k_pool, v_pool, w, block_tables, pos2, valid, mask,
     with jax.named_scope("attn"):
         o = _masked_attn(q, k, v, mask)
     h = h + _attn_out(o, w["wo"], (b, t, cfg.d_model))
-    h = h + _mlp(h, w["mlp_norm"], w["w1"], w["w2"], cfg)
+    h = h + _ffn_part(h, w, cfg, "dense_ffn")[0]
     return h, k_pool, v_pool
 
 
@@ -772,36 +935,110 @@ def _decode_layer(h, k_pool, v_pool, w, block_tables, pos2, mask, *, cfg,
     with jax.named_scope("attn"):
         o = _masked_attn(q, kc, vc, mask)
     h = h + _attn_out(o, w["wo"], (b, 1, cfg.d_model))
-    h = h + _mlp(h, w["mlp_norm"], w["w1"], w["w2"], cfg)
+    h = h + _ffn_part(h, w, cfg, "dense_ffn")[0]
     return h, k_pool, v_pool
 
 
+def _latent_prefill_layer(h, pool, w, block_tables, pos2, valid, *, cfg,
+                          block_tokens, kind):
+    """One latent block of :func:`apply_prefill`: the prompt's cache
+    rows ``[c | k_r]`` scattered into the layer's pool, attention in
+    the expanded form through ``flash_attention`` (no (T, T) scores),
+    then the layer's feed-forward; an expert layer also returns its row
+    of ``routed``."""
+    import jax
+
+    from ..parallel.attention import flash_attention
+    from . import blocks as _blocks
+
+    b, t = pos2.shape
+    with jax.named_scope("norm"):
+        a = _rmsnorm(h, w["attn_norm"], cfg.eps)
+    with jax.named_scope("attn_proj"):
+        q, k, v, row = _blocks.latent_qkv_row(a, w, pos2[0], cfg, _rmsnorm,
+                                              _rope)
+    pool = _write_blocks(pool, row, block_tables, block_tokens)
+    with jax.named_scope("attn"):
+        o = flash_attention(q, k, v, causal=True,
+                            sm_scale=_blocks.latent_sm_scale(cfg))
+    h = h + _attn_out(o, w["wo"], (b, t, cfg.n_heads * cfg.v_head_dim))
+    y, aux = _ffn_part(h, w, cfg, kind)
+    if kind != "experts":
+        return h + y, pool
+    return h + y, pool, _routed_row(aux, valid.reshape(-1), cfg, False)
+
+
+def _latent_decode_layer(h, pool, w, block_tables, pos2, mask, live, *, cfg,
+                         block_tokens, kind):
+    """One latent block of :func:`apply_decode`: the cursor's cache row
+    scattered into the layer's pool, THEN the history's rows gathered
+    through the block tables and attended in the absorbed form under
+    the length mask; then the layer's feed-forward."""
+    import jax
+
+    from . import blocks as _blocks
+
+    b = pos2.shape[0]
+    with jax.named_scope("norm"):
+        a = _rmsnorm(h, w["attn_norm"], cfg.eps)
+    with jax.named_scope("attn_proj"):
+        q_abs, row = _blocks.latent_absorbed_query(a, w, pos2, cfg, _rmsnorm,
+                                                   _rope)
+    pool = _write_rows(pool, row, block_tables, pos2, block_tokens)
+    with jax.named_scope("attn"):
+        # (B, W, block_tokens, width): whole blocks, as they lie
+        u = _blocks.absorbed_attention(q_abs, pool[block_tables], mask, cfg)
+    with jax.named_scope("attn_proj"):
+        o = _blocks.absorbed_values(u, w, cfg, h.dtype)
+    h = h + _attn_out(o, w["wo"], (b, 1, cfg.n_heads * cfg.v_head_dim))
+    y, aux = _ffn_part(h, w, cfg, kind)
+    if kind != "experts":
+        return h + y, pool
+    return h + y, pool, _routed_row(aux, live, cfg, True)
+
+
 @functools.lru_cache(maxsize=None)
-def _traced_once(layer):
-    """``layer`` under ``jax.jit``: every block of a generation forward
-    has one operand signature, so its Python runs once a traced step
-    and the step's module holds the block once, called at every layer
-    (XLA inlines the calls).  Unrolled, tracing and lowering 24 blocks
-    anew for each of a server's 30 plan cells was most of its
-    set-up."""
+def _traced_once(layer, statics):
+    """``layer`` under ``jax.jit``: the blocks of one kind in a
+    generation forward have one operand signature, so the kind's Python
+    runs once a traced step and the step's module holds it once, called
+    at every such layer (XLA inlines the calls).  Unrolled, tracing and
+    lowering 24 blocks anew for each of a server's 30 plan cells was
+    most of its set-up."""
     import jax
 
-    return jax.jit(layer, static_argnames=("cfg", "block_tokens"))
+    return jax.jit(layer, static_argnames=statics)
 
 
-def _through_layers(layer, h, params, pages, cfg, block_tokens, *operands):
-    """``h`` through every block, each layer's pools replaced by what
-    its block returns; -> (h, new_pages)."""
+def _through_layers(dense, latent, h, params, pages, cfg, block_tokens,
+                    *operands):
+    """``h`` through every block (``dense`` or ``latent``, by the
+    configuration's mixer), each layer's pools replaced by what its
+    block returns and the expert layers' rows added to ``routed``;
+    -> (h, new_pages)."""
     import jax
+    import jax.numpy as jnp
 
-    layer = _traced_once(layer)
+    statics = {"cfg": cfg, "block_tokens": int(block_tokens)}
+    if cfg.attn_kind == "latent":
+        layer = _traced_once(latent, ("cfg", "block_tokens", "kind"))
+    else:
+        layer = _traced_once(dense, ("cfg", "block_tokens"))
     new_pages = dict(pages)
+    pools, rows = list(cache_rows(cfg)), []
+    per = len(pools) // cfg.n_layers
     with jax.named_scope("layers"):
-        for i in range(cfg.n_layers):
-            h, new_pages["k%d" % i], new_pages["v%d" % i] = layer(
-                h, pages["k%d" % i], pages["v%d" % i],
-                _layer_params(params, "blk%d." % i), *operands, cfg=cfg,
-                block_tokens=int(block_tokens))
+        for i, kind in enumerate(cfg.kinds):
+            mine = pools[i * per:(i + 1) * per]
+            if cfg.attn_kind == "latent":
+                statics["kind"] = kind
+            h, *state = layer(
+                h, *(pages[k] for k in mine),
+                _layer_params(params, "blk%d." % i), *operands, **statics)
+            new_pages.update(zip(mine, state))
+            rows += state[per:]
+    if rows:
+        new_pages["routed"] = pages["routed"] + jnp.stack(rows)
     return h, new_pages
 
 
@@ -811,29 +1048,28 @@ def apply_prefill(params, tokens, prompt_lens, cfg: TransformerConfig,
     real lengths ``prompt_lens`` (B,) -> (last-real-token logits
     (B, vocab) f32, new_pages).  Dense causal attention over the
     padded length (causality makes the padding rows invisible to every
-    real row), with each layer's roped K and raw V scattered into the
-    paged cache so decode starts from a populated history.
-    ``block_tables`` is (B, T // block_tokens)."""
+    real row), with each layer's cache rows (``cache_rows``: roped K
+    and raw V, or the latent and its roped key) scattered into the
+    paged cache so decode starts from a populated history.  The latent
+    mixer attends through ``flash_attention``, the dense block through
+    the explicit mask.  ``block_tables`` is (B, T // block_tokens)."""
     import jax
     import jax.numpy as jnp
 
-    if not cfg.dense_block:
-        raise NotImplementedError(
-            "generation spells the dense block only (mha, gelu_tanh, "
-            "tied head, one residual stream, no experts, no multi-token "
-            "module); a latent paged cache with its prefill and decode "
-            "is not written")
+    _generates(cfg)
     compute = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
     pos2 = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
     valid = pos2 < prompt_lens[:, None]
-    causal = jnp.tril(jnp.ones((t, t), dtype=bool))
-    mask = jnp.broadcast_to(causal[None], (b, t, t))
+    operands = (block_tables, pos2, valid)
+    if cfg.attn_kind == "mha":
+        causal = jnp.tril(jnp.ones((t, t), dtype=bool))
+        operands += (jnp.broadcast_to(causal[None], (b, t, t)),)
     with jax.named_scope("embed"):
         h = params["embed"].astype(compute)[tokens]
     h, new_pages = _through_layers(
-        _prefill_layer, h, params, pages, cfg, block_tokens, block_tables,
-        pos2, valid, mask)
+        _prefill_layer, _latent_prefill_layer, h, params, pages, cfg,
+        block_tokens, *operands)
     h = _final_norm(h, params, cfg)
     last = h[jnp.arange(b), jnp.clip(prompt_lens - 1, 0, t - 1)]
     return _logits(last, params, cfg, "bd,vd->bv"), new_pages
@@ -843,31 +1079,32 @@ def apply_decode(params, tokens, positions, cfg: TransformerConfig, *,
                  pages, block_tables, block_tokens):
     """One decode tick: current tokens (B,) at cache cursors
     ``positions`` (B,) -> (next-token logits (B, vocab) f32,
-    new_pages).  Per layer: rope q/k at the cursor, scatter k/v into
-    the paged cache, THEN gather (B, W*bt) history through the block
-    tables — the new token reads itself back through the cache — and
-    attend under the inclusive length mask.  Inactive slots ride along
+    new_pages).  Per layer: rope q/k at the cursor, scatter the token's
+    cache rows into the paged cache, THEN gather (B, W*bt) history
+    through the block tables — the new token reads itself back through
+    the cache — and attend under the inclusive length mask (the latent
+    mixer in the absorbed form).  Inactive slots ride along
     with all-zero tables (every write lands in the garbage block) and
     their logits are sliced off by the engine."""
     import jax
     import jax.numpy as jnp
 
-    if not cfg.dense_block:
-        raise NotImplementedError(
-            "generation spells the dense block only (mha, gelu_tanh, "
-            "tied head, one residual stream, no experts, no multi-token "
-            "module); a latent paged cache with its prefill and decode "
-            "is not written")
+    _generates(cfg)
     compute = jnp.dtype(cfg.dtype)
     b = tokens.shape[0]
     span = block_tables.shape[1] * int(block_tokens)
     pos2 = positions[:, None]
-    mask = (jnp.arange(span)[None, :] <= positions[:, None])[:, None, :]
-    mask = jnp.broadcast_to(mask, (b, 1, span))
+    mask = jnp.arange(span)[None, :] <= positions[:, None]
+    if cfg.attn_kind == "mha":
+        operands = (jnp.broadcast_to(mask[:, None, :], (b, 1, span)),)
+    else:
+        # a live rider's table begins with a block of its own; an empty
+        # slot's is all garbage block
+        operands = (mask, block_tables[:, 0] != 0)
     with jax.named_scope("embed"):
         h = params["embed"].astype(compute)[tokens][:, None, :]
     h, new_pages = _through_layers(
-        _decode_layer, h, params, pages, cfg, block_tokens, block_tables,
-        pos2, mask)
+        _decode_layer, _latent_decode_layer, h, params, pages, cfg,
+        block_tokens, block_tables, pos2, *operands)
     h = _final_norm(h, params, cfg)
     return _logits(h[:, 0], params, cfg, "bd,vd->bv"), new_pages

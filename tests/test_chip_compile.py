@@ -258,3 +258,67 @@ def test_served_step_lowers_one_block_whatever_the_depth(one_chip):
     (two_funcs, two_calls), (four_funcs, four_calls) = lowered(2), lowered(4)
     assert two_funcs == four_funcs, (two_funcs, four_funcs)
     assert (two_calls, four_calls) == (2, 4)
+
+
+def _latent_served_step(kind, one_chip):
+    """``_served_step`` for a block with latent attention and experts at
+    sarvam-105b's published widths, a dense and an expert layer deep:
+    one pool of latent rows a layer and the routing counters beside
+    them."""
+    import types
+
+    from mxnet_tpu import serving
+    from mxnet_tpu.transformer import (RopeYarn, TransformerConfig,
+                                       param_shapes)
+    from mxnet_tpu.transformer import model as M
+
+    cfg = TransformerConfig(
+        vocab_size=65536, n_layers=2, d_model=4096, n_heads=64, d_ff=16384,
+        dtype="bfloat16", param_dtype="bfloat16", attn_kind="latent",
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_yarn=RopeYarn(40.0, 4096, 32.0, 1.0, 1.0, 1.0),
+        ffn_act="swiglu", tied_head=False,
+        layer_kinds=("dense_ffn", "experts"), n_experts=128,
+        experts_per_token=8, n_shared_experts=1, expert_ff=2048,
+        held_experts=tuple(range(32)), routed_scaling=2.5)
+    prefill, decode = serving.GenerationRuntime._jit_fns(
+        types.SimpleNamespace(cfg=cfg, block_tokens=128))
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {n: spec(s, d) for n, s, d in param_shapes(cfg)}
+    pages = {pool: spec((769, 128) + row, jnp.bfloat16)
+             for pool, row in M.cache_rows(cfg).items()}
+    pages["routed"] = spec(M.routed_shape(cfg))
+    if kind == "prefill":
+        return prefill, (params, spec((1, 1024)), spec((1,)), pages,
+                         spec((1, 8)))
+    return decode, (params, spec((48,)), spec((48,)), pages, spec((48, 16)))
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_latent_served_step_compiles_in_place_at_published_widths(
+        one_chip, no_cache, kind):
+    """The chip's compiler takes both steps of the latent block at the
+    published widths: the two pools of latent rows (113 MB each here)
+    and the routing counters are aliased to their outputs with no copy
+    of a pool; the prefill attends through the tiled kernels and the
+    expert layer through the grouped product; the decode holds no
+    float32 copy of the gathered history."""
+    import re
+
+    step, args = _latent_served_step(kind, one_chip)
+    with jax.enable_x64(False):
+        text = step.lower(*args).compile().as_text()
+    head = next(ln for ln in text.splitlines() if ln.startswith("HloModule"))
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
+    assert aliased and len(re.findall(r"-alias\)", aliased.group(1))) == 3
+    assert not re.findall(
+        r"= bf16\[(?:769,128,576|98432,576)\]\S* copy(?:-start)?\(", text)
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    if kind == "prefill":
+        assert "splash" in text
+    else:
+        assert not re.findall(r"= f32\[48,2048,(?:576|512)\]", text)

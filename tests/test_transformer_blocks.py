@@ -383,20 +383,30 @@ def test_yarn_frequencies_match_the_reference():
     assert float(got[-1]) == pytest.approx(plain[-1] / 4.0)
 
 
+_LATENT = dict(attn_kind="latent", q_lora_rank=8, kv_lora_rank=8,
+               qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8)
+_EXPERTS = dict(ffn_act="swiglu", n_experts=4, experts_per_token=2,
+                n_shared_experts=1, expert_ff=8, held_experts=(0, 1))
+
+
 @pytest.mark.parametrize("over", [
-    dict(attn_kind="latent", q_lora_rank=8, kv_lora_rank=8,
-         qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8),
-    dict(ffn_act="swiglu"), dict(tied_head=False), dict(hc_mult=2),
-])
+    dict(hc_mult=2), dict(_LATENT, hc_mult=2),
+    dict(_LATENT, **_EXPERTS, tied_head=False, mtp_layers=1),
+    dict(_EXPERTS, layer_kinds=("experts",)),
+], ids=["streams", "latent_streams", "multi_token", "mha_experts"])
 def test_generation_raises_for_what_it_does_not_spell(over):
+    """Since PR 38 the generation forwards spell both mixers, both
+    dense feed-forwards, the expert layer under the latent mixer and
+    either head (tests/test_generate_latent.py); residual streams, a
+    multi-token module and an expert layer under ``mha`` still raise."""
     cfg = TransformerConfig(vocab_size=32, n_layers=1, d_model=16,
                             n_heads=2, d_ff=32, **over)
     params = init_params(jax.random.PRNGKey(0), cfg)
     tokens = jnp.zeros((1, 4), jnp.int32)
-    with pytest.raises(NotImplementedError, match="dense block"):
+    with pytest.raises(NotImplementedError, match="generation spells"):
         apply_prefill(params, tokens, jnp.asarray([4]), cfg, pages={},
                       block_tables=None, block_tokens=4)
-    with pytest.raises(NotImplementedError, match="dense block"):
+    with pytest.raises(NotImplementedError, match="generation spells"):
         apply_decode(params, tokens[:, 0], jnp.asarray([0]), cfg,
                      pages={}, block_tables=None, block_tokens=4)
     # the training forward does spell it
