@@ -333,6 +333,7 @@ class GenerationRuntime:
         from .. import diagnostics as _diag
         from ..compile_cache import enable as _cc_enable
         from ..parallel import attention as _attention
+        from ..transformer import paged_latent as _paged
 
         _cc_enable()
         with self._lock:
@@ -358,6 +359,7 @@ class GenerationRuntime:
                         self.kv.pages, ints(bb, lb // bt))
 
             sites = _attention.site_tally()
+            decode_sites = _paged.site_tally()
             donated = self._stamp_donation(
                 [(pjit, prefill_args(*self.prefill_plan[0])),
                  (djit, decode_args(*self.decode_plan[0]))])
@@ -366,6 +368,10 @@ class GenerationRuntime:
                 # nothing, as before
                 if n:
                     _profiler.record_counter("attn.%s_sites" % how, n)
+            # the latent decode's attention, which only the decode step
+            # holds: the paged kernel's sites and the gather's
+            for how, n in _paged.site_tally(decode_sites).items():
+                _profiler.record_counter("attn.decode_%s_sites" % how, n)
             for kind, plan, cells, fn, args_of in (
                     ("prefill", self.prefill_plan, self._prefill, pjit,
                      prefill_args),

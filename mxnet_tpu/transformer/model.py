@@ -968,15 +968,18 @@ def _latent_prefill_layer(h, pool, w, block_tables, pos2, valid, *, cfg,
     return h + y, pool, _routed_row(aux, valid.reshape(-1), cfg, False)
 
 
-def _latent_decode_layer(h, pool, w, block_tables, pos2, mask, live, *, cfg,
+def _latent_decode_layer(h, pool, w, block_tables, pos2, lengths, *, cfg,
                          block_tokens, kind):
     """One latent block of :func:`apply_decode`: the cursor's cache row
-    scattered into the layer's pool, THEN the history's rows gathered
-    through the block tables and attended in the absorbed form under
-    the length mask; then the layer's feed-forward."""
+    scattered into the layer's pool, THEN the history's rows read
+    through the block tables and attended in the absorbed form to each
+    rider's length (``paged_latent.absorbed_decode``: the paged kernel
+    on a TPU, the gather of whole blocks under the mask elsewhere); then
+    the layer's feed-forward."""
     import jax
 
     from . import blocks as _blocks
+    from . import paged_latent as _paged
 
     b = pos2.shape[0]
     with jax.named_scope("norm"):
@@ -986,15 +989,14 @@ def _latent_decode_layer(h, pool, w, block_tables, pos2, mask, live, *, cfg,
                                                    _rope)
     pool = _write_rows(pool, row, block_tables, pos2, block_tokens)
     with jax.named_scope("attn"):
-        # (B, W, block_tokens, width): whole blocks, as they lie
-        u = _blocks.absorbed_attention(q_abs, pool[block_tables], mask, cfg)
+        u = _paged.absorbed_decode(q_abs, pool, block_tables, lengths, cfg)
     with jax.named_scope("attn_proj"):
         o = _blocks.absorbed_values(u, w, cfg, h.dtype)
     h = h + _attn_out(o, w["wo"], (b, 1, cfg.n_heads * cfg.v_head_dim))
     y, aux = _ffn_part(h, w, cfg, kind)
     if kind != "experts":
         return h + y, pool
-    return h + y, pool, _routed_row(aux, live, cfg, True)
+    return h + y, pool, _routed_row(aux, lengths > 0, cfg, True)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1094,13 +1096,13 @@ def apply_decode(params, tokens, positions, cfg: TransformerConfig, *,
     b = tokens.shape[0]
     span = block_tables.shape[1] * int(block_tokens)
     pos2 = positions[:, None]
-    mask = jnp.arange(span)[None, :] <= positions[:, None]
     if cfg.attn_kind == "mha":
+        mask = jnp.arange(span)[None, :] <= positions[:, None]
         operands = (jnp.broadcast_to(mask[:, None, :], (b, 1, span)),)
     else:
-        # a live rider's table begins with a block of its own; an empty
-        # slot's is all garbage block
-        operands = (mask, block_tables[:, 0] != 0)
+        # the rows each rider reads: a live rider's table begins with a
+        # block of its own, an empty slot's is all garbage block
+        operands = (jnp.where(block_tables[:, 0] != 0, positions + 1, 0),)
     with jax.named_scope("embed"):
         h = params["embed"].astype(compute)[tokens][:, None, :]
     h, new_pages = _through_layers(

@@ -322,3 +322,40 @@ def test_latent_served_step_compiles_in_place_at_published_widths(
         assert "splash" in text
     else:
         assert not re.findall(r"= f32\[48,2048,(?:576|512)\]", text)
+
+
+def test_latent_decode_reads_the_pool_through_the_paged_kernel(one_chip,
+                                                              no_cache):
+    """The latent decode step at sarvam-105b's published widths attends
+    through the paged kernel (``transformer/paged_latent.py``), one
+    site for each block kind, and the kernel's custom call carries
+    ``attn`` on its scope path (what ``decode_latent_attn_ms`` reads,
+    through ``parse_hlo_scopes``).  The step holds no gather of whole
+    blocks of latent rows, no transposing fusion of a gathered history
+    and no copy of a pool: the kernel reads the pool (769 blocks here)
+    as it lies, in the chip's own order of it."""
+    import re
+
+    from mxnet_tpu.traceview import scope_path, scopes
+    from mxnet_tpu.transformer import paged_latent
+
+    # the blocks' traces are cached by function: trace them anew here
+    jax.clear_caches()
+    before = paged_latent.site_tally()
+    step, args = _latent_served_step("decode", one_chip)
+    with jax.enable_x64(False):
+        text = step.lower(*args).compile().as_text()
+    assert paged_latent.site_tally(before) == {"kernel": 2, "gather": 0}
+    names = scopes.parse_hlo_scopes(text)[1]
+    kernels = [n for n in names if n.startswith("paged_latent_attention")]
+    assert len(kernels) == 2, kernels
+    for n in kernels:
+        assert "attn" in scope_path(names[n]), names[n]
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+    # whole blocks of rows, gathered (bf16[48,16,128,576], which the
+    # gather path then wrote again in the chip's order, a transposing
+    # fusion of that shape) or as the gather's flat view
+    # (bf16[768,128,576]); the pool itself is 769 blocks
+    assert not re.findall(r"bf16\[(?:\d+,)*(?!769,)\d+,128,576\]", text)
+    assert not re.findall(
+        r"= bf16\[(?:769,128,576|98432,576)\]\S* copy(?:-start)?\(", text)

@@ -130,3 +130,24 @@ def test_stub_runtime_donates_nothing():
     assert stamped["kv.pools_donated"]["max"] == 0
     assert stamped["kv.pools"]["max"] == 2
     assert not rt.kv.pools_lost()
+
+
+def test_stub_runtime_stamps_no_decode_attention_site():
+    # the stub's cells are numpy functions: compile() stamps the latent
+    # decode's attention sites once, and both are 0
+    from mxnet_tpu import profiler
+
+    rt = serving.StubGenerationRuntime(
+        "gen_stub_s", slots=1, max_prompt=16, max_context=16,
+        block_tokens=16, max_new=2, prefill_batch=1)
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    try:
+        rt.compile(warmup=True)
+    finally:
+        profiler.set_state("stop")
+    stamped = profiler.summary()["counters"]["counter"]
+    profiler.dumps(reset=True)
+    for how in ("kernel", "gather"):
+        site = stamped["attn.decode_%s_sites" % how]
+        assert (site["max"], site["count"]) == (0, 1)

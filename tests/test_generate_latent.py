@@ -11,7 +11,8 @@ expanded form on the same rows; (c) the parts that two shares of the
 experts give add up to the uncut layer, the shared expert counted once;
 (d) ``latent_qkv`` without a bottleneck against the reference, with one
 bit for bit what it gave before; (e) the dense block's steps are what
-they were; (f) the allocator over a latent row shape.
+they were; (f) the allocator over a latent row shape; (g) greedy decode
+through the paged kernel (interpreted) is the gather's.
 """
 import json
 import os
@@ -27,6 +28,7 @@ from mxnet_tpu.serving.kvcache import CacheExhausted, PagedKVCache
 from mxnet_tpu.transformer import (TransformerConfig, blocks, init_params,
                                    param_shapes)
 from mxnet_tpu.transformer import model as M
+from mxnet_tpu.transformer import paged_latent
 from perfbench import weights
 from perfbench.drivers import serve_sarvam
 from perfbench.reference import sarvam as ref
@@ -500,3 +502,67 @@ def test_routing_counters_are_read_between_the_engines_steps():
     seen.append(rt.routing_counters()["assignments_total"])
     tokens = sum(len(p) for p in prompts) + 3 * 11
     assert sum(seen) == tokens * 2 * 2
+
+
+# -- (g) the paged kernel in the decode step ---------------------------
+def _greedy(steps, block=8):
+    """Each prompt of ``LENGTHS`` prefilled alone, then ``steps`` greedy
+    decode ticks of all riders together with an empty slot riding
+    along; -> every rider's tokens and the ticks' logits."""
+    lm = lm_config()
+    params = weights.make_all(SEED, ref.leaves(TOY), "float32")
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, TOY["vocab_size"], size=n, dtype=np.int32)
+               for n in LENGTHS]
+    span = -(-(max(LENGTHS) + steps) // block)
+    kv = PagedKVCache(rows=M.cache_rows(lm), num_blocks=40,
+                      block_tokens=block, counters={"routed":
+                                                    M.routed_shape(lm)})
+    tokens = []
+    for i, p in enumerate(prompts):
+        width = -(-len(p) // block)
+        kv.alloc(i, len(p))
+        padded = np.zeros((1, width * block), np.int32)
+        padded[0, :len(p)] = p
+        logits, kv.pages = M.apply_prefill(
+            params, padded, np.asarray([len(p)], np.int32), lm,
+            pages=kv.pages, block_tables=kv.block_table(i, width)[None],
+            block_tokens=block)
+        tokens.append([int(np.argmax(logits[0]))])
+    ticks = []
+    for step in range(steps):
+        for i, p in enumerate(prompts):
+            kv.extend(i, len(p) + step + 1)
+        tables = np.stack([kv.block_table(i, span) for i in range(3)]
+                          + [np.zeros(span, np.int32)])
+        logits, kv.pages = M.apply_decode(
+            params, np.asarray([t[-1] for t in tokens] + [0], np.int32),
+            np.asarray([len(p) + step for p in prompts] + [0], np.int32),
+            lm, pages=kv.pages, block_tables=tables, block_tokens=block)
+        ticks.append(np.asarray(logits[:3]))
+        for i, t in enumerate(tokens):
+            t.append(int(np.argmax(logits[i])))
+    return tokens, np.stack(ticks)
+
+
+def test_greedy_decode_through_the_paged_kernel_is_the_gathers(monkeypatch):
+    """The decode step with the kernel in place of the gather (through
+    the Pallas interpreter: on the CPU the step takes the gather)
+    chooses every token the gather's step chooses, over ticks that cross
+    block edges, with ragged riders and an empty slot."""
+    want_tokens, want = _greedy(9)          # x64: every site gathers
+    calls = []
+
+    def interpreted(q_abs, pool, block_tables, lengths, cfg):
+        calls.append(lengths.shape)
+        return paged_latent.paged_latent_attention(
+            q_abs, pool, block_tables, lengths, rank=cfg.kv_lora_rank,
+            sm_scale=blocks.latent_sm_scale(cfg), interpret=True)
+
+    monkeypatch.setattr(paged_latent, "absorbed_decode", interpreted)
+    # at 32 bits the layers are traced anew, each kind once
+    with jax.enable_x64(False):
+        got_tokens, got = _greedy(9)
+    assert len(calls) == 2
+    assert got_tokens == want_tokens
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)

@@ -293,6 +293,50 @@ def test_compile_counts_the_pools_donated(grt2):
     assert _dead(built_with) and _live(rt.kv.pages)
 
 
+def test_compile_counts_no_latent_decode_site_of_the_dense_block(grt2):
+    # the dense block's decode attends through its own gather and mask
+    _, stamped, _ = grt2
+    for how in ("kernel", "gather"):
+        site = stamped["attn.decode_%s_sites" % how]
+        assert (site["max"], site["count"]) == (0, 1)
+
+
+def test_compile_counts_the_latent_decode_sites_once():
+    """``attn.decode_kernel_sites`` / ``attn.decode_gather_sites``: the
+    latent decode step's attention, one site for each block kind traced
+    (a dense layer and an expert layer), stamped once a ``compile()``.
+    On the CPU, under the tests' x64, every site gathers; on the chip
+    every one is the paged kernel (tests/test_chip_compile.py)."""
+    import jax
+
+    from mxnet_tpu import profiler
+    from mxnet_tpu.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig(
+        vocab_size=64, n_layers=3, d_model=32, n_heads=2, d_ff=64,
+        attn_kind="latent", kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, ffn_act="swiglu",
+        tied_head=False, layer_kinds=("dense_ffn", "experts", "experts"),
+        n_experts=4, experts_per_token=2, n_shared_experts=1, expert_ff=16,
+        held_experts=(0, 1))
+    rt = serving.GenerationRuntime(
+        "gen_latent_sites", init_params(jax.random.PRNGKey(0), cfg), cfg,
+        slots=2, block_tokens=8, max_prompt=8, max_context=16, max_new=4,
+        prefill_batch=1)
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    try:
+        rt.compile(warmup=False)
+    finally:
+        profiler.set_state("stop")
+    stamped = profiler.summary()["counters"]["counter"]
+    profiler.dumps(reset=True)
+    kernel = stamped["attn.decode_kernel_sites"]
+    gather = stamped["attn.decode_gather_sites"]
+    assert (kernel["max"], kernel["count"]) == (0, 1)
+    assert (gather["max"], gather["count"]) == (2, 1)
+
+
 @pytest.mark.parametrize("kind", ["prefill", "decode"])
 def test_compiled_step_writes_every_pool_in_place(grt2, kind):
     """The compiled step aliases each of the 2 x n_layers pools to its
