@@ -1318,6 +1318,10 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         self._metrics: Dict[Tuple[str, frozenset], Any] = {}
         self._last_flush = 0.0
+        #: counts the ``clear()`` calls: a caller that holds metrics
+        #: across calls (a serving tick's gauges) makes them again when
+        #: it moved, so what it writes is still what ``to_prom()`` shows
+        self.generation = 0
 
     def _get(self, cls, name: str, help: str, labels, **kw):
         key = (name, frozenset((labels or {}).items()))
@@ -1435,10 +1439,30 @@ class MetricsRegistry:
         with self._lock:
             self._metrics.clear()
             self._last_flush = 0.0
+            self.generation += 1
 
 
 #: process-wide registry — fit()/Speedometer/kvstore/io feed it
 metrics = MetricsRegistry()
+
+
+class Held:
+    """What ``make(metrics)`` returns — the metrics a hot path writes on
+    every call, looked up by name and labels ONCE — kept until the
+    process-wide registry is cleared or replaced, then made again."""
+
+    __slots__ = ("_make", "_registry", "_generation", "_value")
+
+    def __init__(self, make):
+        self._make = make
+        self._registry = self._generation = self._value = None
+
+    def get(self):
+        reg = metrics
+        if self._registry is not reg or self._generation != reg.generation:
+            self._value = self._make(reg)
+            self._registry, self._generation = reg, reg.generation
+        return self._value
 
 
 def record_step(step_time_s: float, samples: Optional[int] = None,

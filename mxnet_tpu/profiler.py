@@ -51,7 +51,8 @@ __all__ = ["set_config", "profiler_set_config", "set_state",
            "profiler_set_state", "dump", "dump_profile", "dumps",
            "summary", "pause", "resume", "is_running", "record_span",
            "record_counter", "record_marker", "record_bytes", "span",
-           "Span", "record_interval", "spans_between", "RING_SPANS",
+           "Span", "record_interval", "nest", "unnest", "spans_between",
+           "RING_SPANS",
            "Domain", "Counter", "Marker", "set_rank", "sample_memory"]
 
 # an RLock: the stamping helpers call each other (record_bytes ->
@@ -489,6 +490,21 @@ def record_interval(name: str, t0: float, t1: float,
     if _state == "run":
         record_span(name, _session_us(t0), (t1 - t0) * 1e6, cat,
                     args=args)
+
+
+def nest() -> int:
+    """Open one level of this thread's span nesting, as :class:`span`
+    does, with no annotation and no record: the spans opened until
+    :func:`unnest` sit one level under an interval that the caller
+    records itself (:func:`record_interval` with the depth returned)."""
+    depth = getattr(_nesting, "depth", 0)
+    _nesting.depth = depth + 1
+    return depth
+
+
+def unnest(depth: int) -> None:
+    """Close the level :func:`nest` opened: ``depth`` is what it gave."""
+    _nesting.depth = depth
 
 
 def spans_between(t0: float, t1: float) -> List[Span]:

@@ -447,7 +447,16 @@ class GenerationEngine:
     sequences, and a tick loop — reap (cancel/expire/evict), admit
     (batched prefill), decode (one token for every rider).  All engine
     state is touched from ONE worker thread (``ModelServer`` owns it);
-    requests/cancel flags are the thread-safe crossings."""
+    requests/cancel flags are the thread-safe crossings.
+
+    A step's host time is put down in the profiler's ring by phase, a
+    few records a compiled call whatever the number of riders:
+    ``mx.engine.prepare`` (from the step's entry, or from the end of the
+    prefill's streaming, to the call), the call's own ``mx.prefill`` /
+    ``mx.tick`` span, ``mx.prefill.readback`` / ``mx.tick.readback``
+    inside it (the logits to the host and their argmax), and
+    ``mx.engine.stream`` (the tokens to their callers, the retirements,
+    up to the next call's preparation or the step's return)."""
 
     def __init__(self, runtime: GenerationRuntime):
         self.rt = runtime
@@ -456,6 +465,10 @@ class GenerationEngine:
         self.waiting: "deque[GenRequest]" = deque()
         self.ticks = 0
         self.tokens_out = 0
+        # ``perf_counter()`` where the step's current host phase began,
+        # and whether it streams a call's tokens (else it prepares one)
+        self._host_t0 = 0.0
+        self._streaming = False
         # stable physical slot indices (not positions in ``active``):
         # the reqtrace slot timeline needs one lane per slot, and a
         # retiring co-rider must not renumber everyone behind it
@@ -511,6 +524,7 @@ class GenerationEngine:
         rep: Dict[str, Any] = {"outcomes": [], "ticked": False,
                                "exec_error": None, "tokens": 0}
         self.ticks += 1
+        self._host_t0, self._streaming = time.perf_counter(), False
         self._reap(rep)
         try:
             self._admit(rep)
@@ -518,7 +532,30 @@ class GenerationEngine:
         except ExecutorFailure as e:
             rep["exec_error"] = e
         self.kv.feed_metrics()
+        if self._streaming:
+            self._host_phase("mx.engine.stream", time.perf_counter())
         return rep
+
+    def _host_phase(self, name: str, t1: float,
+                    depth: Optional[int] = None) -> None:
+        """Record the host phase that began at ``_host_t0`` as ending at
+        ``t1``, where the next one begins."""
+        _profiler.record_interval(name, self._host_t0, t1, cat="serving",
+                                  depth=depth)
+        self._host_t0 = t1
+
+    def _called(self, call, logits, what: str):
+        """Inside a compiled call's span, once it has returned: its
+        preparation recorded beside it, the logits read back and their
+        argmax recorded inside it; the tokens chosen."""
+        import numpy as np
+
+        self._host_phase("mx.engine.prepare", call.t0, depth=call.depth)
+        t0 = time.perf_counter()
+        out = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+        _profiler.record_interval(what + ".readback", t0,
+                                  time.perf_counter(), cat="serving")
+        return out
 
     def _finish(self, req: GenRequest, outcome: str,
                 error: Optional[BaseException] = None) -> None:
@@ -641,14 +678,15 @@ class GenerationEngine:
             w = rt._prefill[(bb, tb)]
             with self.kv.in_step, _profiler.span(
                     "mx.prefill", cat="serving", args={
-                        "tokens": int(plens[:len(group)].sum())}):
+                        "tokens": int(plens[:len(group)].sum())}) as call:
                 logits, pages = w(rt._params, tokens, plens,
                                   self.kv.pages, tables)
-                first = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+                first = self._called(call, logits, "mx.prefill")
                 # kept once the logits are read: a run that fails on
                 # the device raises there, with pools as dead as those
                 # it was handed
                 self.kv.pages = pages
+            self._host_t0, self._streaming = time.perf_counter(), True
         except Exception as e:
             err = e if isinstance(e, ExecutorFailure) else \
                 ExecutorFailure("prefill for %r failed: %r"
@@ -693,6 +731,9 @@ class GenerationEngine:
         rt = self.rt
         if not self.active:
             return
+        if self._streaming:
+            self._host_phase("mx.engine.stream", time.perf_counter())
+            self._streaming = False
         riders: List[_Slot] = []
         for s in self.active:
             try:
@@ -737,10 +778,10 @@ class GenerationEngine:
             w = rt._decode[(bb, lb)]
             with self.kv.in_step, _profiler.span(
                     "mx.tick", cat="serving", args={
-                        "live": len(riders), "slots": rt.slots}):
+                        "live": len(riders), "slots": rt.slots}) as call:
                 logits, pages = w(rt._params, tokens, positions,
                                   self.kv.pages, tables)
-                nxt = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+                nxt = self._called(call, logits, "mx.tick")
                 self.kv.pages = pages  # once read: see _admit
         except Exception as e:
             err = self._fail_riders(rep, ExecutorFailure(
@@ -748,6 +789,7 @@ class GenerationEngine:
                 % (rt.name, bb, lb, e)))
             self._recover_pools(rep, err)
             raise err
+        self._host_t0, self._streaming = time.perf_counter(), True
         rep["ticked"] = True
         if trace_on:
             tick_dur = time.monotonic() - tick_t0

@@ -72,6 +72,7 @@ class PagedKVCache:
         self._lengths: Dict[str, int] = {}
         self.evictions = 0
         self.pool_rebuilds = 0
+        self._held = None      # the registry's objects feed_metrics sets
         self._pool_shapes = {
             pool: (self.num_blocks, self.block_tokens)
             + tuple(int(n) for n in row) for pool, row in rows.items()}
@@ -234,37 +235,42 @@ class PagedKVCache:
                 "evictions": self.evictions,
             }
 
+    def _metrics(self, reg) -> tuple:
+        lab = {"model": self.name}
+        return (
+            reg.gauge("mxnet_serve_kv_blocks_live",
+                      help="paged KV-cache blocks allocated", labels=lab),
+            reg.gauge("mxnet_serve_kv_blocks_free",
+                      help="paged KV-cache blocks free", labels=lab),
+            reg.gauge("mxnet_serve_kv_fragmentation",
+                      help="unused fraction of allocated KV token slots",
+                      labels=lab),
+            reg.counter("mxnet_serve_kv_evictions_total",
+                        help="sequences evicted under cache pressure",
+                        labels=lab),
+            reg.counter("mxnet_serve_kv_pool_rebuilds_total",
+                        help="times the KV pools were made again after a "
+                             "failed step consumed them",
+                        labels=lab))
+
     def feed_metrics(self) -> None:
         """Push allocator gauges/counters into diagnostics.metrics —
         best-effort, the serving convention (a metrics hiccup must not
-        fail a decode tick)."""
+        fail a decode tick).  The registry's objects are looked up once
+        and held (``diagnostics.Held``): this runs every engine step."""
         try:
-            from .. import diagnostics as _diag
+            if self._held is None:
+                from .. import diagnostics as _diag
 
+                self._held = _diag.Held(self._metrics)
+            live, free, frag, evicted, rebuilt = self._held.get()
             st = self.stats()
-            lab = {"model": self.name}
-            _diag.metrics.gauge("mxnet_serve_kv_blocks_live",
-                                help="paged KV-cache blocks allocated",
-                                labels=lab).set(st["blocks_live"])
-            _diag.metrics.gauge("mxnet_serve_kv_blocks_free",
-                                help="paged KV-cache blocks free",
-                                labels=lab).set(st["blocks_free"])
-            _diag.metrics.gauge(
-                "mxnet_serve_kv_fragmentation",
-                help="unused fraction of allocated KV token slots",
-                labels=lab).set(st["fragmentation"])
-            c = _diag.metrics.counter(
-                "mxnet_serve_kv_evictions_total",
-                help="sequences evicted under cache pressure",
-                labels=lab)
-            if st["evictions"] > c.value:
-                c.inc(st["evictions"] - c.value)
-            c = _diag.metrics.counter(
-                "mxnet_serve_kv_pool_rebuilds_total",
-                help="times the KV pools were made again after a "
-                     "failed step consumed them",
-                labels=lab)
-            if self.pool_rebuilds > c.value:
-                c.inc(self.pool_rebuilds - c.value)
+            live.set(st["blocks_live"])
+            free.set(st["blocks_free"])
+            frag.set(st["fragmentation"])
+            if st["evictions"] > evicted.value:
+                evicted.inc(st["evictions"] - evicted.value)
+            if self.pool_rebuilds > rebuilt.value:
+                rebuilt.inc(self.pool_rebuilds - rebuilt.value)
         except Exception:
             pass

@@ -163,6 +163,9 @@ class _ServedModel:
         # per-version {n, errors} since the canary started — the
         # promote-vs-rollback evidence window
         self._vstats: Dict[int, Dict[str, int]] = {}
+        # the registry's objects its worker sets on every pass, looked
+        # up by name and labels once (``ModelServer._meters``)
+        self.meters = None
 
 
 class ModelServer:
@@ -506,12 +509,21 @@ class ModelServer:
         breaker/canary evidence exactly as the predictor dispatch path
         does.  Exits when the queue reports drain-complete and every
         engine is empty: the SIGTERM drain finishes every admitted
-        generation."""
+        generation.
+
+        An iteration that did work is one ``mx.serve.loop`` record in the
+        profiler's ring, the engines' records one level under it; each
+        run of iterations that found nothing to do is ONE
+        ``mx.serve.idle`` record."""
         from .. import diagnostics as _diag
+        from .. import profiler as _profiler
 
         prev_stable = sm.runtime
         prev_canary = None
+        idle_t0 = None      # where the current run of idle iterations began
         while True:
+            t0 = time.perf_counter()
+            depth = _profiler.nest()
             _diag.touch_heartbeat()
             stable = sm.runtime
             with sm._lock:
@@ -571,9 +583,24 @@ class ModelServer:
                     for e in [stable] + ([canary] if canary else [])
                     + sm.gen_retired)
             self._gauge_inflight(sm)
+            _profiler.unnest(depth)
+            t1 = time.perf_counter()
+            if worked:
+                if idle_t0 is not None:
+                    _profiler.record_interval("mx.serve.idle", idle_t0, t0,
+                                              cat="serving", depth=depth)
+                    idle_t0 = None
+                _profiler.record_interval("mx.serve.loop", t0, t1,
+                                          cat="serving", depth=depth)
+            elif idle_t0 is None:
+                idle_t0 = t0
             if polled is None and sm.inflight == 0 and \
                     not sm.gen_retired:
-                return  # drained: queue closed+empty, engines empty
+                # drained: queue closed+empty, engines empty
+                if idle_t0 is not None:
+                    _profiler.record_interval("mx.serve.idle", idle_t0, t1,
+                                              cat="serving", depth=depth)
+                return
             if not worked:
                 time.sleep(0.001)  # idle tick: don't spin a core
 
@@ -596,7 +623,7 @@ class ModelServer:
                 with sm._lock:
                     sm.failed += 1
         if rep["tokens"]:
-            self._count_gen_tokens(name, rt.version, rep["tokens"])
+            self._count_gen_tokens(sm, rt.version, rep["tokens"])
         if rep["exec_error"] is not None:
             if is_canary:
                 self._record_version_result(sm, rt.version, ok=False)
@@ -616,20 +643,48 @@ class ModelServer:
                     self._record_version_result(sm, rt.version, ok=True)
         return bool(rep["ticked"] or rep["outcomes"])
 
-    def _count_gen_tokens(self, model: str, version: Optional[int],
+    def _count_gen_tokens(self, sm: _ServedModel, version: Optional[int],
                           n: int) -> None:
         try:
             from .. import diagnostics as _diag
 
-            _diag.metrics.counter(
-                "mxnet_serve_gen_tokens_total",
-                help="generated tokens streamed to callers",
-                labels={"model": model,
-                        "version": "v%d" % version if version
-                        else "unknown"}).inc(n)
+            by_version = self._meters(sm)["tokens"]
+            counter = by_version.get(version)
+            if counter is None:
+                counter = by_version[version] = _diag.metrics.counter(
+                    "mxnet_serve_gen_tokens_total",
+                    help="generated tokens streamed to callers",
+                    labels={"model": sm.runtime.name,
+                            "version": "v%d" % version if version
+                            else "unknown"})
+            counter.inc(n)
             _diag.metrics.maybe_flush()
         except Exception:
             pass
+
+    def _meters(self, sm: _ServedModel) -> Dict[str, Any]:
+        """The model's queue-depth and in-flight gauges and its token
+        counters by version, held across calls (``diagnostics.Held``):
+        the generation worker sets them on every pass."""
+        if sm.meters is None:
+            from .. import diagnostics as _diag
+
+            model = sm.runtime.name
+
+            def make(reg):
+                lab = {"model": model}
+                return {
+                    "depth": reg.gauge(
+                        "mxnet_serve_queue_depth",
+                        help="admitted requests waiting to be batched",
+                        labels=lab),
+                    "inflight": reg.gauge(
+                        "mxnet_serve_inflight_samples",
+                        help="samples dispatched, not yet answered",
+                        labels=lab),
+                    "tokens": {}}
+            sm.meters = _diag.Held(make)
+        return sm.meters.get()
 
     def _route(self, sm: _ServedModel):
         """Pick the runtime for THIS batch: the stable version, or —
@@ -1175,23 +1230,13 @@ class ModelServer:
 
     def _gauge_depth(self, sm: _ServedModel) -> None:
         try:
-            from .. import diagnostics as _diag
-
-            _diag.metrics.gauge(
-                "mxnet_serve_queue_depth",
-                help="admitted requests waiting to be batched",
-                labels={"model": sm.runtime.name}).set(sm.queue.depth())
+            self._meters(sm)["depth"].set(sm.queue.depth())
         except Exception:
             pass
 
     def _gauge_inflight(self, sm: _ServedModel) -> None:
         try:
-            from .. import diagnostics as _diag
-
-            _diag.metrics.gauge(
-                "mxnet_serve_inflight_samples",
-                help="samples dispatched, not yet answered",
-                labels={"model": sm.runtime.name}).set(sm.inflight)
+            self._meters(sm)["inflight"].set(sm.inflight)
         except Exception:
             pass
 

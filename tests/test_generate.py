@@ -151,3 +151,163 @@ def test_stub_runtime_stamps_no_decode_attention_site():
     for how in ("kernel", "gather"):
         site = stamped["attn.decode_%s_sites" % how]
         assert (site["max"], site["count"]) == (0, 1)
+
+
+# ---------------------------------------------------------------------
+# the engine's phase records, driven through ModelServer
+# ---------------------------------------------------------------------
+# what the engine and its worker add to the ring beside the compiled
+# call's own span (``mx.tick`` / ``mx.prefill``) and the launch under it
+PHASE_RECORDS = {"mx.serve.loop", "mx.engine.prepare", "mx.engine.stream",
+                 "mx.tick.readback", "mx.prefill.readback"}
+
+
+class _Logits:
+    """A compiled call's logits that count their reads to the host."""
+
+    reads = 0
+
+    def __init__(self, value):
+        self.value = value
+
+    def __array__(self, *a, **kw):
+        type(self).reads += 1
+        return self.value
+
+
+def _counted(cells, calls):
+    def wrap(step):
+        def call(*args):
+            calls.append(1)
+            logits, pages = step(*args)
+            return _Logits(logits), pages
+        return call
+    for key, step in list(cells.items()):
+        cells[key] = wrap(step)
+
+
+def _worker_records(t0, t1):
+    from mxnet_tpu import profiler
+
+    spans = profiler.spans_between(t0, t1)
+    (thread,) = {s.thread for s in spans if s.name == "mx.serve.loop"}
+    return [s for s in spans if s.thread == thread]
+
+
+def _iterations(records):
+    """``(loop record, [records inside it])`` for every worked iteration."""
+    loops = [s for s in records if s.name == "mx.serve.loop"]
+    return [(loop, [s for s in records if s is not loop
+                    and loop.t0 <= s.t0 and s.t1 <= loop.t1])
+            for loop in loops]
+
+
+@pytest.fixture
+def served_stub(monkeypatch):
+    import jax
+
+    import time as _time
+
+    rt = serving.StubGenerationRuntime(
+        "gen_phases_t", slots=8, max_prompt=16, max_context=64,
+        block_tokens=16, max_new=32, prefill_batch=1)
+    srv = serving.ModelServer(queue_max=64)
+    srv.add_generator(rt)
+    calls = []
+    _counted(rt._decode, calls)
+    _counted(rt._prefill, calls)
+    _Logits.reads = 0
+    syncs = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda *a, **k: syncs.append(a))
+    monkeypatch.setattr(jax, "device_get", lambda *a, **k: syncs.append(a))
+    t0 = _time.perf_counter()
+    yield srv, calls, syncs, t0
+    srv.drain(timeout_s=10)
+
+
+def _generate(srv, prompts, max_new):
+    reqs = [srv.submit_generation("gen_phases_t", p, max_new=max_new)
+            for p in prompts]
+    for p, r in zip(prompts, reqs):
+        assert r.wait(10)["tokens"] == serving.stub_greedy_reference(
+            p, max_new)
+
+
+def test_engine_records_each_worked_iteration_in_o1_records(served_stub):
+    import time
+
+    srv, calls, syncs, t0 = served_stub
+    _generate(srv, [[1, 2, 3]], 12)                     # one rider
+    _generate(srv, [[i + 1, 2, 3] for i in range(8)], 20)  # eight
+    srv.drain(timeout_s=10)
+    records = _worker_records(t0, time.perf_counter())
+    assert {"mx.engine.prepare", "mx.tick.readback", "mx.engine.stream",
+            "mx.prefill.readback"} <= {s.name for s in records}
+    added = {}                # riders -> new records a decode-only pass
+    for loop, inside in _iterations(records):
+        names = [s.name for s in inside]
+        new = 1 + sum(n in PHASE_RECORDS for n in names)
+        if "mx.prefill" in names:
+            assert new <= 8
+            continue
+        (tick,) = [s for s in inside if s.name == "mx.tick"]
+        assert new <= 5
+        added.setdefault(tick.args["live"], set()).add(new)
+        # the nesting: the phases one level under the loop, the
+        # readback inside the call's span; prepare ends where the call
+        # begins and the stream begins after it
+        (prep,) = [s for s in inside if s.name == "mx.engine.prepare"]
+        (back,) = [s for s in inside if s.name == "mx.tick.readback"]
+        (stream,) = [s for s in inside if s.name == "mx.engine.stream"]
+        assert prep.depth == tick.depth == stream.depth == loop.depth + 1
+        assert back.depth == loop.depth + 2
+        assert prep.t1 == tick.t0 and tick.t0 <= back.t0 <= back.t1 \
+            <= tick.t1 <= stream.t0
+    # as many records with one rider as with eight
+    assert added[1] == added[8] == {4}
+    # one read of the logits a compiled call; no other device sync
+    assert calls and _Logits.reads == len(calls)
+    assert not syncs
+
+
+def test_idle_iterations_fold_into_one_record(served_stub):
+    import time
+
+    srv, _, _, t0 = served_stub
+    _generate(srv, [[5, 6]], 4)
+    time.sleep(0.05)                 # some fifty idle passes
+    between = time.perf_counter()
+    _generate(srv, [[7, 8]], 4)
+    srv.drain(timeout_s=10)
+    records = [s for s in _worker_records(t0, time.perf_counter())
+               if s.name in ("mx.serve.loop", "mx.serve.idle")]
+    records.sort(key=lambda s: s.t0)
+    # worked and idle runs alternate: never two idle records in a row
+    assert all("mx.serve.idle" != a.name or a.name != b.name
+               for a, b in zip(records, records[1:]))
+    (gap,) = [s for s in records if s.name == "mx.serve.idle"
+              and s.t0 < between < s.t1]
+    assert gap.t1 - gap.t0 >= 0.04
+    # the drain closes the last idle run
+    assert records[-1].name == "mx.serve.idle"
+
+
+def test_held_metrics_follow_a_cleared_registry(monkeypatch):
+    # the allocator's gauges are looked up once and held; a registry
+    # cleared (or replaced) since gets them again on the next feed
+    from mxnet_tpu import diagnostics as diag
+
+    monkeypatch.setattr(diag, "metrics", diag.MetricsRegistry())
+    rt = serving.StubGenerationRuntime(
+        "gen_held_t", slots=1, max_prompt=16, max_context=16,
+        block_tokens=16, max_new=2, prefill_batch=1)
+    for clear in (False, True):
+        if clear:
+            diag.metrics.clear()
+        rt.kv.feed_metrics()
+        prom = diag.metrics.to_prom()
+        assert 'mxnet_serve_kv_blocks_free{model="gen_held_t"} %d' % (
+            rt.kv.num_blocks - 1) in prom
+        assert 'mxnet_serve_kv_pool_rebuilds_total{model="gen_held_t"} 0' \
+            in prom
