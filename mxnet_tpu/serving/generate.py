@@ -283,21 +283,30 @@ class GenerationRuntime:
 
     def _jit_fns(self):
         import jax
+        import jax.numpy as jnp
 
         from ..transformer import model as _model
 
         cfg, bt = self.cfg, self.block_tokens
 
+        # each step chooses its tokens where the logits are: the host
+        # reads back ``bb`` int32 ids, not ``bb`` rows of the vocabulary
+        # in float32 (``argmax`` takes the first maximum, as numpy's)
+        def greedy(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
         def prefill_fn(params, tokens, prompt_lens, pages,
                        block_tables):
-            return _model.apply_prefill(
+            logits, pages = _model.apply_prefill(
                 params, tokens, prompt_lens, cfg, pages=pages,
                 block_tables=block_tables, block_tokens=bt)
+            return greedy(logits), pages
 
         def decode_fn(params, tokens, positions, pages, block_tables):
-            return _model.apply_decode(
+            logits, pages = _model.apply_decode(
                 params, tokens, positions, cfg, pages=pages,
                 block_tables=block_tables, block_tokens=bt)
+            return greedy(logits), pages
 
         # ``pages`` (argument 3) is donated: each step scatters its
         # rows into the pools it was handed, where an undonated pool
@@ -305,19 +314,26 @@ class GenerationRuntime:
         return (jax.jit(prefill_fn, donate_argnums=(3,)),
                 jax.jit(decode_fn, donate_argnums=(3,)))
 
-    def _stamp_donation(self, steps_args) -> int:
-        """``kv.pools_donated`` of ``kv.pools``: the pools that every
-        one of the compiled steps takes donated (read off each traced
-        step's ``args_info``, as ``analysis.check_donation`` does; the
-        trace is the one the step's first call reuses), over the pools
-        there are.  A step that is no jit donates nothing.  Returns
-        the former."""
+    def _stamp_traces(self, prefill, decode) -> int:
+        """What the traces of both compiled steps say (each trace is
+        the one its cell's first call reuses), ``(step, args)`` each:
+        ``kv.pools_donated`` of ``kv.pools``, the pools that both take
+        donated (off ``args_info``, as ``analysis.check_donation``
+        reads it), over the pools there are; and ``gen.readback_bytes``,
+        what the decode cell hands the host beside the pools (off
+        ``out_info``).  A step that is no jit donates nothing and
+        stamps no readback.  Returns the pools donated."""
         pools = set(self.kv.pools)
         donated = set(pools)
-        for step, args in steps_args:
+        for step, args in (prefill, decode):
             trace = getattr(step, "trace", None)
-            info = trace(*args).args_info[0][3] if trace else {}
+            traced = trace(*args) if trace else None
+            info = traced.args_info[0][3] if traced else {}
             donated &= {k for k, a in info.items() if a.donated}
+        if traced:                      # the decode step's
+            ids = traced.out_info[0]
+            _profiler.record_counter("gen.readback_bytes",
+                                     ids.size * ids.dtype.itemsize)
         _profiler.record_counter("kv.pools_donated", len(donated))
         _profiler.record_counter("kv.pools", len(pools))
         return len(donated)
@@ -360,9 +376,9 @@ class GenerationRuntime:
 
             sites = _attention.site_tally()
             decode_sites = _paged.site_tally()
-            donated = self._stamp_donation(
-                [(pjit, prefill_args(*self.prefill_plan[0])),
-                 (djit, decode_args(*self.decode_plan[0]))])
+            donated = self._stamp_traces(
+                (pjit, prefill_args(*self.prefill_plan[0])),
+                (djit, decode_args(*max(self.decode_plan))))
             for how, n in _attention.site_tally(sites).items():
                 # the dense block's explicit mask is no site: it stamps
                 # nothing, as before
@@ -386,8 +402,8 @@ class GenerationRuntime:
                         nm, fn, meta=dict(meta, kind="generate_" + kind))
                     t0 = time.perf_counter()
                     if warmup:
-                        out, pages = w(*args_of(*key))
-                        jax.block_until_ready(out)  # mxlint: disable=MXL004
+                        ids, pages = w(*args_of(*key))
+                        jax.block_until_ready(ids)  # mxlint: disable=MXL004
                         self.kv.pages = pages
                     self._compile_ms[nm] = (time.perf_counter() - t0) * 1e3
                     cells[key] = w
@@ -454,7 +470,8 @@ class GenerationEngine:
     ``mx.engine.prepare`` (from the step's entry, or from the end of the
     prefill's streaming, to the call), the call's own ``mx.prefill`` /
     ``mx.tick`` span, ``mx.prefill.readback`` / ``mx.tick.readback``
-    inside it (the logits to the host and their argmax), and
+    inside it (the wait for the run, then its ``bb`` int32 ids to the
+    host), and
     ``mx.engine.stream`` (the tokens to their callers, the retirements,
     up to the next call's preparation or the step's return)."""
 
@@ -544,15 +561,15 @@ class GenerationEngine:
                                   depth=depth)
         self._host_t0 = t1
 
-    def _called(self, call, logits, what: str):
+    def _called(self, call, ids, what: str):
         """Inside a compiled call's span, once it has returned: its
-        preparation recorded beside it, the logits read back and their
-        argmax recorded inside it; the tokens chosen."""
+        preparation recorded beside it, the tokens it chose read back
+        (which waits for the run) and recorded inside it; the tokens."""
         import numpy as np
 
         self._host_phase("mx.engine.prepare", call.t0, depth=call.depth)
         t0 = time.perf_counter()
-        out = np.asarray(logits).argmax(axis=-1)  # mxlint: disable=MXL004
+        out = np.asarray(ids)  # mxlint: disable=MXL004
         _profiler.record_interval(what + ".readback", t0,
                                   time.perf_counter(), cat="serving")
         return out
@@ -679,10 +696,10 @@ class GenerationEngine:
             with self.kv.in_step, _profiler.span(
                     "mx.prefill", cat="serving", args={
                         "tokens": int(plens[:len(group)].sum())}) as call:
-                logits, pages = w(rt._params, tokens, plens,
-                                  self.kv.pages, tables)
-                first = self._called(call, logits, "mx.prefill")
-                # kept once the logits are read: a run that fails on
+                ids, pages = w(rt._params, tokens, plens,
+                               self.kv.pages, tables)
+                first = self._called(call, ids, "mx.prefill")
+                # kept once the ids are read: a run that fails on
                 # the device raises there, with pools as dead as those
                 # it was handed
                 self.kv.pages = pages
@@ -779,9 +796,9 @@ class GenerationEngine:
             with self.kv.in_step, _profiler.span(
                     "mx.tick", cat="serving", args={
                         "live": len(riders), "slots": rt.slots}) as call:
-                logits, pages = w(rt._params, tokens, positions,
-                                  self.kv.pages, tables)
-                nxt = self._called(call, logits, "mx.tick")
+                ids, pages = w(rt._params, tokens, positions,
+                               self.kv.pages, tables)
+                nxt = self._called(call, ids, "mx.tick")
                 self.kv.pages = pages  # once read: see _admit
         except Exception as e:
             err = self._fail_riders(rep, ExecutorFailure(
@@ -920,31 +937,32 @@ class StubGenerationRuntime(GenerationRuntime):
             # afterwards the pages stay host arrays
             return {k: np.array(v) for k, v in pages.items()}
 
+        # each returns the ``bb`` int32 ids, as the compiled steps do
         def prefill_fn(params, tokens, prompt_lens, pages, tables):
             pages = _np_pages(pages)
             k = pages["k0"]
             bb = int(tokens.shape[0])
-            logits = np.zeros((bb, vocab), dtype=np.float32)
+            ids = np.zeros((bb,), dtype=np.int32)
             for i in range(bb):
                 p = int(prompt_lens[i])
                 for j in range(p):
                     k[tables[i, j // bt], j % bt, 0, 0] = tokens[i, j]
                 hist = k[tables[i], :, 0, 0].reshape(-1)[:p]
-                logits[i, int(hist.sum()) % vocab] = 1.0
+                ids[i] = int(hist.sum()) % vocab
             k[0] = 0.0  # padded rows wrote here; garbage stays garbage
-            return logits, pages
+            return ids, pages
 
         def decode_fn(params, tokens, positions, pages, tables):
             pages = _np_pages(pages)
             k = pages["k0"]
             bb = int(tokens.shape[0])
-            logits = np.zeros((bb, vocab), dtype=np.float32)
+            ids = np.zeros((bb,), dtype=np.int32)
             for i in range(bb):
                 pos = int(positions[i])
                 k[tables[i, pos // bt], pos % bt, 0, 0] = tokens[i]
                 hist = k[tables[i], :, 0, 0].reshape(-1)[:pos + 1]
-                logits[i, int(hist.sum()) % vocab] = 1.0
+                ids[i] = int(hist.sum()) % vocab
             k[0] = 0.0
-            return logits, pages
+            return ids, pages
 
         return prefill_fn, decode_fn

@@ -324,6 +324,27 @@ def test_latent_served_step_compiles_in_place_at_published_widths(
         assert not re.findall(r"= f32\[48,2048,(?:576|512)\]", text)
 
 
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_latent_served_step_hands_back_one_id_a_slot(one_chip, no_cache,
+                                                      kind):
+    """What the latent block's compiled step hands the host, at the
+    published widths: one int32 id a slot, first among its results
+    (the pools and the routing counters follow), and no row of the
+    65,536-wide head's float32 logits (12.6 MB at 48 slots)."""
+    import re
+
+    step, args = _latent_served_step(kind, one_chip)
+    with jax.enable_x64(False):
+        text = step.lower(*args).compile().as_text()
+    head = next(ln for ln in text.splitlines() if ln.startswith("HloModule"))
+    results = re.search(r"entry_computation_layout=\{.*\)->\((.*)\)\}",
+                        head).group(1)
+    bb = 1 if kind == "prefill" else 48
+    assert re.match(r"s32\[%d\]\{0\S*\}, " % bb, results), results[:80]
+    assert "f32[%d,65536]" % bb not in results
+    assert results.count("bf16[769,128,576]") == 2
+
+
 def test_latent_decode_reads_the_pool_through_the_paged_kernel(one_chip,
                                                               no_cache):
     """The latent decode step at sarvam-105b's published widths attends

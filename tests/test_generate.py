@@ -6,6 +6,7 @@ e2e (greedy equality, recompile discipline, cancel storm, streaming
 HTTP) lives in tests/test_zz_generate_e2e.py, named to sort after the
 transformer suite so its XLA compile cost lands at the tail of a
 time-boxed tier-1 run."""
+import numpy as np
 import pytest
 
 from mxnet_tpu import serving
@@ -111,6 +112,35 @@ def test_stub_engine_greedy_matches_reference():
     assert rt.kv.stats()["blocks_live"] == 0
 
 
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_stub_steps_hand_back_int32_ids(kind):
+    # the stub's numpy cells keep the compiled steps' contract: ``bb``
+    # int32 ids, the token rule's, which the engine reads as they are;
+    # two riders and two empty slots
+    rt = serving.StubGenerationRuntime(
+        "gen_stub_ids", slots=4, max_prompt=16, max_context=32,
+        block_tokens=16, max_new=4, prefill_batch=4)
+    rt.compile(warmup=False)
+    prompts = [[1, 2, 3], [4, 5]]
+    tokens = np.zeros((4, 16), np.int32)
+    tables = np.zeros((4, 2), np.int32)
+    for i, p in enumerate(prompts):
+        rt.kv.alloc(i, len(p) + 1)
+        tokens[i, :len(p)] = p
+        tables[i, 0] = rt.kv.block_table(i, 1)[0]
+    ids, pages = rt._prefill[(4, 16)](
+        rt._params, tokens, np.asarray([3, 2, 1, 1], np.int32),
+        rt.kv.pages, tables[:, :1])
+    if kind == "decode":
+        ids, _ = rt._decode[(4, 32)](
+            rt._params, np.asarray(list(ids[:2]) + [0, 0], np.int32),
+            np.asarray([3, 2, 0, 0], np.int32), pages, tables)
+    assert ids.dtype == np.int32 and ids.shape == (4,)
+    n = ("prefill", "decode").index(kind)
+    assert ids[:2].tolist() == [serving.stub_greedy_reference(p, 2)[n]
+                                for p in prompts]
+
+
 def test_stub_runtime_donates_nothing():
     # the stub's cells are numpy functions, no jit: compile() counts
     # none of its pools as donated, and no failure can lose them
@@ -162,8 +192,8 @@ PHASE_RECORDS = {"mx.serve.loop", "mx.engine.prepare", "mx.engine.stream",
                  "mx.tick.readback", "mx.prefill.readback"}
 
 
-class _Logits:
-    """A compiled call's logits that count their reads to the host."""
+class _Ids:
+    """A compiled call's ids that count their reads to the host."""
 
     reads = 0
 
@@ -179,8 +209,8 @@ def _counted(cells, calls):
     def wrap(step):
         def call(*args):
             calls.append(1)
-            logits, pages = step(*args)
-            return _Logits(logits), pages
+            ids, pages = step(*args)
+            return _Ids(ids), pages
         return call
     for key, step in list(cells.items()):
         cells[key] = wrap(step)
@@ -216,7 +246,7 @@ def served_stub(monkeypatch):
     calls = []
     _counted(rt._decode, calls)
     _counted(rt._prefill, calls)
-    _Logits.reads = 0
+    _Ids.reads = 0
     syncs = []
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda *a, **k: syncs.append(a))
@@ -266,8 +296,8 @@ def test_engine_records_each_worked_iteration_in_o1_records(served_stub):
             <= tick.t1 <= stream.t0
     # as many records with one rider as with eight
     assert added[1] == added[8] == {4}
-    # one read of the logits a compiled call; no other device sync
-    assert calls and _Logits.reads == len(calls)
+    # one read of the ids a compiled call; no other device sync
+    assert calls and _Ids.reads == len(calls)
     assert not syncs
 
 

@@ -12,7 +12,8 @@ experts give add up to the uncut layer, the shared expert counted once;
 (d) ``latent_qkv`` without a bottleneck against the reference, with one
 bit for bit what it gave before; (e) the dense block's steps are what
 they were; (f) the allocator over a latent row shape; (g) greedy decode
-through the paged kernel (interpreted) is the gather's.
+through the paged kernel (interpreted) is the gather's; (h) the compiled
+steps hand back int32 ids, the first maximum of the forwards' logits.
 """
 import json
 import os
@@ -566,3 +567,137 @@ def test_greedy_decode_through_the_paged_kernel_is_the_gathers(monkeypatch):
     assert len(calls) == 2
     assert got_tokens == want_tokens
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+# -- (h) the steps choose the tokens on the device ---------------------
+def _runtime(block, name, slots, head=None):
+    """A served runtime of the dense toy or of the latent toy; ``head``
+    rewrites the head's matrix (the embedding, where tied) before it
+    goes to the device."""
+    if block == "dense":
+        cfg = TransformerConfig(vocab_size=64, n_layers=2, d_model=32,
+                                n_heads=2, d_ff=64)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+    else:
+        cfg = lm_config()
+        params = weights.make_all(SEED, ref.leaves(TOY), "float32")
+    if head is not None:
+        name_of_head = "embed" if cfg.tied_head else "head"
+        params = dict(params, **{name_of_head: head(
+            np.asarray(params[name_of_head]))})
+    return serving.GenerationRuntime(
+        name, params, cfg, slots=slots, block_tokens=8, max_prompt=16,
+        max_context=32, max_new=6, prefill_batch=slots)
+
+
+@pytest.mark.parametrize("block", ["dense", "latent"])
+def test_compiled_steps_hand_back_int32_ids(block):
+    """Both compiled steps return ``bb`` int32 ids, the first maximum
+    of the logits that the model's forward gives on the same
+    arguments, and ``compile()`` stamps what the largest decode cell
+    hands the host: ``bb`` times 4 bytes."""
+    # four slots: a batch ladder of 1, 2, 4; three: of 1, 2, 3
+    rt = _runtime(block, "gen_ids_" + block,
+                  slots=4 if block == "dense" else 3)
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    try:
+        rt.compile(warmup=False)
+    finally:
+        profiler.set_state("stop")
+    stamped = profiler.summary()["counters"]["counter"]
+    profiler.dumps(reset=True)
+    bb = max(rt.batch_plan)
+    assert stamped["gen.readback_bytes"]["max"] == bb * 4
+    assert stamped["gen.readback_bytes"]["count"] == 1
+    cfg, bt = rt.cfg, rt.block_tokens
+    rng = np.random.default_rng(11)
+    forwards = (
+        ("prefill", M.apply_prefill, (bb, 16),
+         lambda: (rng.integers(1, cfg.vocab_size, (bb, 16), np.int32),
+                  rng.integers(1, 17, bb).astype(np.int32),
+                  np.arange(1, 1 + 2 * bb, dtype=np.int32).reshape(bb, 2))),
+        ("decode", M.apply_decode, (bb, 32),
+         lambda: (rng.integers(1, cfg.vocab_size, bb).astype(np.int32),
+                  rng.integers(0, 32, bb).astype(np.int32),
+                  np.arange(1, 1 + 4 * bb, dtype=np.int32).reshape(bb, 4))))
+    for kind, forward, key, made in forwards:
+        tokens, lengths, tables = made()
+        logits, _ = jax.jit(lambda p, t, n, pg, tb: forward(
+            p, t, n, cfg, pages=pg, block_tables=tb, block_tokens=bt))(
+                rt._params, tokens, lengths, rt.kv.pages, tables)
+        logits = np.asarray(logits)
+        step = (rt._prefill if kind == "prefill" else rt._decode)[key]
+        ids, rt.kv.pages = step(rt._params, tokens, lengths, rt.kv.pages,
+                                tables)
+        ids = np.asarray(ids)
+        assert ids.dtype == np.int32 and ids.shape == (bb,), kind
+        top = np.sort(logits, axis=-1)
+        clear = top[:, -1] - top[:, -2] > 1e-4
+        assert clear.sum() >= bb - 1, kind
+        assert (ids == logits.argmax(-1))[clear].all(), kind
+
+
+def _halves_repeat(head):
+    """The head's rows repeat after the first half: token ``v`` and
+    ``v + vocab / 2`` score alike whatever the history."""
+    half = head.shape[0] // 2
+    return np.concatenate([head[:half], head[:half]])
+
+
+def _greedy_loop(rt, prompt, n_new):
+    """Greedy decode of one prompt alone through the model's own
+    forwards at the shapes the engine gives a lone rider (its prompt
+    and cache buckets, a batch of one), the token the first maximum of
+    the logits; every choice is a tie between the two halves."""
+    cfg, bt = rt.cfg, rt.block_tokens
+    counters = M.routed_shape(cfg)
+    kv = PagedKVCache(rows=M.cache_rows(cfg), num_blocks=8,
+                      block_tokens=bt, dtype=cfg.dtype,
+                      counters={"routed": counters} if counters else None)
+    tb = serving.bucket_for(rt.prompt_plan, len(prompt))
+    kv.alloc("s", len(prompt))
+    tokens = np.zeros((1, tb), np.int32)
+    tokens[0, :len(prompt)] = prompt
+    logits, kv.pages = M.apply_prefill(
+        rt._params, tokens, np.asarray([len(prompt)], np.int32), cfg,
+        pages=kv.pages, block_tables=kv.block_table("s", tb // bt)[None],
+        block_tokens=bt)
+    out, pos = [], len(prompt)
+    while True:
+        row = np.asarray(logits[0])
+        half = cfg.vocab_size // 2
+        np.testing.assert_array_equal(row[:half], row[half:])
+        out.append(int(np.argmax(row)))
+        if len(out) == n_new:
+            return out
+        kv.extend("s", pos + 1)
+        lb = serving.bucket_for(rt.cache_plan, pos + 1)
+        logits, kv.pages = M.apply_decode(
+            rt._params, np.asarray(out[-1:], np.int32),
+            np.asarray([pos], np.int32), cfg, pages=kv.pages,
+            block_tables=kv.block_table("s", lb // bt)[None],
+            block_tokens=bt)
+        pos += 1
+
+
+@pytest.mark.parametrize("block", ["dense", "latent"])
+def test_engine_serves_the_greedy_loops_tokens_first_maximum_on_ties(block):
+    """The engine's tokens, chosen inside the compiled steps, are a
+    greedy loop's over the logits of ``apply_prefill`` /
+    ``apply_decode`` with numpy's rule, on a head where every choice is
+    a tie: each token is the first of its pair, never the second."""
+    # one slot: each request is served alone, at the loop's shapes
+    rt = _runtime(block, "gen_ties_" + block, slots=1,
+                  head=_halves_repeat)
+    rt.compile(warmup=True)
+    prompts = [[3, 9, 1, 4, 7], list(range(2, 14)), [5]]
+    want = [_greedy_loop(rt, p, 6) for p in prompts]
+    reqs = [serving.GenRequest(rt.name, p, 6) for p in prompts]
+    for r in reqs:
+        rt.engine.enqueue(r)
+    while not rt.engine.idle():
+        rt.engine.step()
+    got = [r.wait(0.1)["tokens"] for r in reqs]
+    assert got == want
+    assert max(max(t) for t in got) < rt.cfg.vocab_size // 2

@@ -361,7 +361,7 @@ def test_compiled_step_writes_every_pool_in_place(grt2, kind):
     alias = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
     pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", alias.group(1))
     # flat arguments: the parameters' leaves, tokens, lengths or
-    # positions, then the pools; flat results: logits, then the pools
+    # positions, then the pools; flat results: the ids, then the pools
     first = len(jax.tree_util.tree_leaves(rt._params)) + 2
     pools = 2 * rt.cfg.n_layers
     assert sorted((int(o), int(i)) for o, i in pairs) == [
@@ -405,7 +405,7 @@ def _consumes_then_raises(step):
 
 def _result_unreadable(step):
     """As a run that fails on the device after its dispatch: the call
-    returns, and reading the logits raises."""
+    returns, and reading the ids raises."""
     class Unreadable:
         def __array__(self, *a, **kw):
             raise RuntimeError("planted: the run failed on the device")
